@@ -37,7 +37,10 @@ data-parallel, on ALL THREE histogram backends.  Three mechanisms:
    root_stats``): the resident ``_init_state`` derives the root sums
    from fixed ``STREAM_CHUNK``-sized chunk sums reduced by a fixed
    pairwise tree — partition-invariant, so this trainer reassembles
-   the identical scalars from per-block chunk sums.
+   the identical scalars from per-block chunk sums.  Where the folds
+   histogram int8 codes the root sums are the exact int32 sums of the
+   same codes (``root_code_sums`` / ``root_stats_q``): block results
+   add exactly, and each shard dequantizes its total once.
 3. **The fenced block body** (``gbdt._make_block_fn``): the serial
    scan body barriers gradients and the built tree and updates scores
    with the contraction-proof scale-then-gather shape (the PR 11 mesh
@@ -109,10 +112,11 @@ from ..io.device import DeviceData, feature_meta_np
 from ..learner.serial import (STREAM_CHUNK, BuiltTree, _WaveState,
                               _apply_wave, _empty_best, apply_hist_wave,
                               make_hist_fold_fn, reduce_chunk_sums,
-                              root_chunk_sums, scan_grid, stage_plan)
+                              root_chunk_sums, root_code_sums,
+                              root_stats_q, scan_grid, stage_plan)
 from ..obs import counter_add, event, span as obs_span
 from ..objective.objectives import create_objective
-from ..ops.pallas_histogram import bin_stride
+from ..ops.pallas_histogram import bin_stride, pack_values_q
 from ..ops.pallas_route import route_rows_xla
 from ..ops.split import leaf_output as _leaf_output
 from ..utils.log import log_info, log_warning
@@ -573,6 +577,25 @@ class StreamTrainer:
             return root_chunk_sums(grad, hess, mask)
         return self._jit("root_cs", root_cs)
 
+    def _root_codes_fn(self):
+        """Quantized folds: one block's in-bag int8 code sums ``[C]
+        int32`` under the shard's scales.  Block results add exactly, so
+        a shard's total is the resident ``_init_state``'s
+        (``learner.serial.root_code_sums``) whatever the block size."""
+        mode = self._fold.hist_mode
+
+        def root_codes(grad, hess, mask, scales):
+            vals, _ = pack_values_q(grad, hess, mode, scales=scales)
+            return root_code_sums(vals, mask)
+        return self._jit("root_codes", root_codes)
+
+    def _root_dequant_fn(self):
+        mode = self._fold.hist_mode
+
+        def root_dequant(code_sums, scales):
+            return jnp.stack(root_stats_q(code_sums, scales, mode))
+        return self._jit("root_dequant", root_dequant)
+
     def _score_update_fn(self):
         def update(scores_b, leaf_value, nl, row_leaf, lr, k):
             # the fenced body's update shape: stump-masked leaf values,
@@ -797,80 +820,87 @@ class StreamTrainer:
         update = self._score_update_fn()
         A = self.A_tail
 
-        # leaf2 carries on host between waves (the streaming traffic);
-        # root statistics fold per shard, reduce through the fixed
-        # pairwise tree, and shard scalars combine in device order
-        leaf2_host: List[np.ndarray] = []
-        shard_cs = [[] for _ in range(self.S)]
-        for (s, start, stop, m) in blocks:
-            mask = np.zeros(self.R, bool)
-            mask[:m] = True
-            gb = self._pad_block(grad[start:stop], m)
-            hb = self._pad_block(hess[start:stop], m)
-            cs = np.asarray(root_cs(jnp.asarray(gb), jnp.asarray(hb),
-                                    jnp.asarray(mask)))
-            shard_cs[s].append(cs)
-            l2 = np.full((2, self.R), -1, np.int32)
-            l2[0, :] = 0
-            l2[1, :m] = 0
-            leaf2_host.append(l2)
-
-        # in-memory chunk grids: serial = ceil(n/C); data-parallel =
-        # ceil(per/C) per shard (mesh padding rows are zero chunks)
-        exchange = (self.elastic is not None and self.elastic.world > 1)
-        if exchange:
-            # per-shard scalars reduce locally (the same fixed pairwise
-            # tree any owner would run), travel as [3] f32 arrays, and
-            # combine in SHARD order — bitwise what the single-process
-            # S-shard branch below computes
-            m_chunks = -(-self.per // STREAM_CHUNK)
-            payload = {}
-            for s in self.owned:
-                cs = np.concatenate(shard_cs[s], axis=1)
-                if cs.shape[1] < m_chunks:   # trailing mesh-pad chunks
-                    cs = np.concatenate(
-                        [cs, np.zeros((3, m_chunks - cs.shape[1]),
-                                      np.float32)], axis=1)
-                part = jnp.stack(reduce_chunk_sums(
-                    jnp.asarray(cs[:, :m_chunks])))
-                payload[str(s)] = np.asarray(part)
-            merged = self._exchange_arrays(payload,
-                                           site="elastic.root_stats")
-            parts = [jnp.asarray(merged[s]) for s in range(self.S)]
-            tot = parts[0] if self.S == 1 else combine(parts)
-            state = init_state(tot[:, None])   # [3, 1]: identity reduce
-        elif self.S == 1:
-            m_chunks = -(-self.n // STREAM_CHUNK)
-            cs_all = np.concatenate(shard_cs[0], axis=1)[:, :m_chunks]
-            state = init_state(jnp.asarray(cs_all))
-        else:
-            m_chunks = -(-self.per // STREAM_CHUNK)
-            parts = []
-            for cs_list in shard_cs:
-                cs = (np.concatenate(cs_list, axis=1) if cs_list
-                      else np.zeros((3, 0), np.float32))
-                if cs.shape[1] < m_chunks:   # trailing mesh-pad chunks
-                    cs = np.concatenate(
-                        [cs, np.zeros((3, m_chunks - cs.shape[1]),
-                                      np.float32)], axis=1)
-                parts.append(jnp.stack(reduce_chunk_sums(
-                    jnp.asarray(cs[:, :m_chunks]))))
-            tot = combine(parts)
-            state = init_state(tot[:, None])   # [3, 1]: identity reduce
-
         # per-(tree, shard) quantization scales for the kernel folds —
         # fixed across blocks AND waves, host-derived over the shard's
         # full row range (bitwise the device absmax the in-memory
         # kernels compute; an empty shard range clamps to 1e-30 on both
         # sides).  None on the float modes and the scatter path.
         fold = self._fold
+        quantized = fold is not None and fold.quantized
         scales_dev = {}
-        if fold is not None and fold.quantized:
+        if quantized:
             for s in self.owned:
                 lo, hi = self.ranges[s]
                 hi = min(hi, self.n)
                 scales_dev[s] = jnp.asarray(
                     _fold_scales(grad[lo:hi], hess[lo:hi]))
+
+        # leaf2 carries on host between waves (the streaming traffic);
+        # root statistics fold per shard and shard scalars combine in
+        # device order.  Float histograms: f32 chunk sums reduced through
+        # the fixed pairwise tree.  Quantized folds: exact int32 sums of
+        # the int8 codes the kernels histogram, dequantized once per
+        # shard — the totals a leaf's histogram sums are consistent with
+        root_codes = self._root_codes_fn() if quantized else None
+        leaf2_host: List[np.ndarray] = []
+        shard_cs = [[] for _ in range(self.S)]
+        for (s, start, stop, m) in blocks:
+            mask = np.zeros(self.R, bool)
+            mask[:m] = True
+            gb = jnp.asarray(self._pad_block(grad[start:stop], m))
+            hb = jnp.asarray(self._pad_block(hess[start:stop], m))
+            cs = (root_codes(gb, hb, jnp.asarray(mask), scales_dev[s])
+                  if quantized else root_cs(gb, hb, jnp.asarray(mask)))
+            shard_cs[s].append(np.asarray(cs))
+            l2 = np.full((2, self.R), -1, np.int32)
+            l2[0, :] = 0
+            l2[1, :m] = 0
+            leaf2_host.append(l2)
+
+        def shard_part(s: int, m_chunks: int):
+            """Shard ``s``'s root ``[g, h, count]`` as a ``[3]`` f32."""
+            if quantized:
+                if not shard_cs[s]:          # a shard of mesh padding
+                    return jnp.zeros(3, jnp.float32)
+                tot = np.sum(shard_cs[s], axis=0, dtype=np.int32)
+                return self._root_dequant_fn()(jnp.asarray(tot),
+                                               scales_dev[s])
+            cs = (np.concatenate(shard_cs[s], axis=1) if shard_cs[s]
+                  else np.zeros((3, 0), np.float32))
+            if cs.shape[1] < m_chunks:       # trailing mesh-pad chunks
+                cs = np.concatenate(
+                    [cs, np.zeros((3, m_chunks - cs.shape[1]),
+                                  np.float32)], axis=1)
+            return jnp.stack(reduce_chunk_sums(
+                jnp.asarray(cs[:, :m_chunks])))
+
+        # in-memory chunk grids: serial = ceil(n/C); data-parallel =
+        # ceil(per/C) per shard (mesh padding rows are zero chunks)
+        exchange = (self.elastic is not None and self.elastic.world > 1)
+        if exchange:
+            # per-shard scalars reduce locally (the same reduction any
+            # owner would run), travel as [3] f32 arrays, and combine in
+            # SHARD order — bitwise what the single-process S-shard
+            # branch below computes
+            m_chunks = -(-self.per // STREAM_CHUNK)
+            payload = {str(s): np.asarray(shard_part(s, m_chunks))
+                       for s in self.owned}
+            merged = self._exchange_arrays(payload,
+                                           site="elastic.root_stats")
+            parts = [jnp.asarray(merged[s]) for s in range(self.S)]
+            tot = parts[0] if self.S == 1 else combine(parts)
+            state = init_state(tot[:, None])   # [3, 1]: identity reduce
+        elif self.S == 1 and quantized:
+            state = init_state(shard_part(0, 0)[:, None])
+        elif self.S == 1:
+            m_chunks = -(-self.n // STREAM_CHUNK)
+            cs_all = np.concatenate(shard_cs[0], axis=1)[:, :m_chunks]
+            state = init_state(jnp.asarray(cs_all))
+        else:
+            m_chunks = -(-self.per // STREAM_CHUNK)
+            tot = combine([shard_part(s, m_chunks)
+                           for s in range(self.S)])
+            state = init_state(tot[:, None])   # [3, 1]: identity reduce
 
         pipelined = self._pipeline_on and len(blocks) > 1
         stager = self._ensure_stager() if pipelined else None

@@ -99,16 +99,10 @@ def _init_network(cfg: Config) -> None:
     own rank by finding its local endpoint in the list."""
     # already-meshed check WITHOUT touching the backend
     # (jax.process_count() would initialize XLA, and
-    # jax.distributed.initialize must come first).  The probe reads a
-    # private jax layout, so it is best-effort: on a jax whose internals
-    # moved, fall through and let initialize's own already-initialized
-    # error be the signal (ADVICE r4)
-    try:
-        from jax._src import distributed as _dist
-        if getattr(_dist.global_state, "client", None) is not None:
-            return                          # environment already meshed
-    except (ImportError, AttributeError):
-        pass
+    # jax.distributed.initialize must come first)
+    import jax
+    if jax.distributed.is_initialized():
+        return                              # environment already meshed
     from .parallel.mesh import init_distributed_from_machines
     machines = cfg.machines
     if not machines and cfg.machine_list_file:

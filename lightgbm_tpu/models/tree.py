@@ -302,7 +302,11 @@ class Tree:
                 self.num_cat + 1)
             arr("cat_threshold", np.asarray(self.cat_threshold, np.uint32),
                 len(self.cat_threshold))
-        lines.append(f"shrinkage={_fmt_float(self.shrinkage_rate)}")
+        # at full precision, unlike the other informational floats:
+        # DART keeps multiplying this after a snapshot is loaded, so a
+        # value rounded on the way through the text would make a resumed
+        # model differ from an uninterrupted one in the last digit
+        lines.append(f"shrinkage={_fmt_double(self.shrinkage_rate)}")
         lines.append("")
         return "\n".join(lines)
 

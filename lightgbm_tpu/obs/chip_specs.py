@@ -8,13 +8,10 @@ kind — kept here, jax-free except for the kind probe, so the trace
 parser and ``tools/perf_report.py`` can import it without touching a
 backend.
 
-Numbers are NAMEPLATE (vendor-published) peaks, not measured: the
-measured MXU ceiling on this bench device under-reads nameplate by
-~5-10% (``tests/data/north_star.json`` ``peak_bf16_tmacs`` = 87.0
-TMACs ~ 174 TFLOPs vs the 197 TFLOPs v5e nameplate — each chained
-step pays a clip+cast epilogue).  Roofline percentages computed
-against nameplate are therefore conservative; a program reading
-">90% of peak" genuinely has no headroom.
+Numbers are NAMEPLATE (vendor-published) peaks, not measured: what a
+chained matmul reaches of them is not measured on a local chip.
+Roofline percentages computed against nameplate are conservative; a
+program reading ">90% of peak" genuinely has no headroom.
 
 The ``cpu`` entry is an explicit SENTINEL: tier-1 runs the whole
 attribution pipeline on the CPU backend, where "% of peak" against a

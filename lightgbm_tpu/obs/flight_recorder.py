@@ -7,9 +7,9 @@ recorder attacks the same failure class from the same end).  The
 reference never needs it — its blocking socket collectives deadlock
 loudly and immediately on a schedule skew; XLA's async collectives on
 ICI/DCN instead hang minutes later or silently mis-reduce, with
-nothing naming the site that diverged (MULTICHIP_r05's ungated 1.63%
-row-leaf skew is exactly the signature this recorder exists to
-attribute).
+nothing naming the site that diverged (the ungated 1.63% row-leaf
+skew of a round-5 dry run on virtual CPU devices is exactly the
+signature this recorder exists to attribute).
 
 Mechanics:
 

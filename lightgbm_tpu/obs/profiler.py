@@ -3,7 +3,7 @@
 The host-side telemetry spans (``obs/telemetry.py``) time DISPATCH,
 not execution: a span around an async JAX dispatch closes when the
 host returns, while XLA is still running.  Every open perf question on
-the ROADMAP — per-iteration host latency on the mesh path, the 0.27x
+the ROADMAP — per-iteration host latency on the mesh path, the
 ranking regime, the never-captured 255-bin leg — needs the other half:
 where the DEVICE time goes, per phase.  This module is that layer.
 

@@ -3,13 +3,16 @@
 Why: per-row MXU work in the wide one-hot kernel
 (`ops/pallas_histogram.py`) scales with ``cols = round128(C *
 round8(A))`` — every row is contracted against the value columns of ALL
-``A`` active leaf slots even though it contributes to exactly one.
-``tests/data/north_star.json`` quantifies the collapse on the bench
-device: 1.08–1.13 ns/row at A <= 32 degrades to 2.55 at 64 and 8.79 at
-128 (MXU util 1.18 -> 0.61) — and 128-slot waves are the dominant
-regime of the reference's 255-leaf headline configs (the 0.27x ranking
-leg, README).  The reference solves the same problem on CPU with
-``DataPartition``'s leaf-contiguous row layout + ordered gradients
+``A`` active leaf slots even though it contributes to exactly one, and
+128-slot waves are the dominant regime of the reference's 255-leaf
+headline configs.  What that costs per row, and whether this module
+wins it back, is unverified on a local chip: the one comparison made
+there (PERF.md, PR 21, 1M x 28 x 63 bins x 255 leaves, int8h) ran the
+warm 32-iteration block in 8.14 s on this backend against 0.91 s with
+the wide kernel alone, so the plan below costs far more than the
+columns it saves at that shape.  The reference solves the same problem
+on CPU with ``DataPartition``'s leaf-contiguous row layout + ordered
+gradients
 (`/root/reference/src/treelearner/data_partition.hpp`,
 `serial_tree_learner.cpp` ordered-bin path): each leaf's histogram only
 ever touches that leaf's rows.
@@ -17,9 +20,9 @@ ever touches that leaf's rows.
 This module is the TPU-native analog, in three steps per deep wave:
 
 1. **plan** (:func:`compact_plan`, plain XLA): bucket every row by its
-   active-slot *group* (``COMPACT_GROUP = 32`` slots per group — the
-   measured flat-regime boundary), stable-sort rows by group, and pad
-   each group's segment to a whole number of row tiles.  Rows whose
+   active-slot *group* (``COMPACT_GROUP = 32`` slots per group),
+   stable-sort rows by group, and pad each group's segment to a whole
+   number of row tiles.  Rows whose
    leaf is not active (bagged-out ``-1`` included) sort into a trailing
    trash segment and are DROPPED from the compacted stream — deep
    waves histogram only the smaller children, so this alone removes
@@ -35,8 +38,7 @@ This module is the TPU-native analog, in three steps per deep wave:
 3. **grouped kernel** (:func:`hist_active_compact`): the one-hot matmul
    kernel runs over the compacted stream with a *per-tile* active set
    of ``COMPACT_GROUP`` slots — ``cols = round128(C * 32)`` instead of
-   ``round128(C * 128)`` — restoring the flat ~1.1 ns/row profile.
-   Each tile's group (and so its output block and its slice of the
+   ``round128(C * 128)``.  Each tile's group (and so its output block and its slice of the
    per-group active table) is selected by a scalar-prefetched
    ``tile_group`` vector (`pltpu.PrefetchScalarGridSpec`): segments
    are group-contiguous, so every output block is visited in one
@@ -47,10 +49,10 @@ This module is the TPU-native analog, in three steps per deep wave:
 Cost model: the wide kernel pays ``n * cols_wide`` MACs; the compacted
 path pays ``~n_active * cols_group`` MACs plus a stable segment-sort of
 an ``[n]`` int32 key and one bins/vals gather.  At A=128 / C=4 that is
-a 4x MAC reduction on <= ~half the rows; the sort+gather are measured
-per-device by the wave microbench (`bench.py` ``wave_kernel`` table),
-which records ns/row per active-slot bucket so this regression class
-stays visible in every ``BENCH_r*.json``.
+a 4x MAC reduction on <= ~half the rows; the sort+gather are what the
+wave microbench (`bench.py` ``wave_kernel`` table, ns/row per
+active-slot bucket) is there to weigh against it — not measured on the
+current tree.
 
 Exactness: identical quantized inputs accumulate in int32 exactly in
 both kernels, so the compacted path is BIT-identical to the wide
@@ -68,16 +70,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_histogram import (DEFAULT_ROW_TILE, _VMEM_BUDGET_BYTES,
-                               _cell_vmem_bytes, _col_layout, _feat_tile_cap,
-                               _onehot_bins, _pick_row_tile, _round_up,
-                               _weighted_cols, bin_stride, combine_hist_cols,
-                               is_quantized)
+from .pallas_histogram import (DEFAULT_ROW_TILE, _col_layout, _onehot_bins,
+                               _pick_row_tile, _weighted_cols, bin_stride,
+                               combine_hist_cols, is_quantized)
+from .vmem import feat_tiling
 
-# leaf slots per compacted tile group.  32 is the measured flat-regime
-# boundary of the wide kernel (north_star.json: 1.13 ns/row at 32 vs
-# 8.79 at 128) — and for the default C=4 int8h mode, C*32 = 128 fills
-# the lane dimension exactly, so no output column is wasted.
+# leaf slots per compacted tile group.  For the default C=4 int8h mode,
+# C*32 = 128 fills the lane dimension exactly, so no output column is
+# wasted; whether 32 is also where the wide kernel's per-row cost starts
+# to climb is unverified on a local chip.
 COMPACT_GROUP = 32
 
 
@@ -122,9 +123,13 @@ def compact_plan(hist_leaf: jnp.ndarray, active: jnp.ndarray,
       tile_group: ``[n_c // T]`` int32 — the group each row tile
         serves, non-decreasing; tiles past the used region map to the
         trailing trash group ``n_groups``.
-      group_active: ``[G, n_groups + 1]`` int32 — per-group active-leaf
-        table (column g = slots ``[g*G, (g+1)*G)``), ``-2`` padding so
-        neither real leaves nor the ``-1`` of padding rows match.
+      group_active: ``[n_groups + 1, G, 1]`` int32 — per-group
+        active-leaf table (page g = slots ``[g*G, (g+1)*G)`` as a
+        ``[G, 1]`` column), ``-2`` padding so neither real leaves nor
+        the ``-1`` of padding rows match.  One page per group because
+        the kernel's block must span the array's last two dimensions
+        (Mosaic's (8, 128) block rule refuses a ``(G, 1)`` window into
+        a ``[G, n_groups + 1]`` table).
     """
     n_pad = hist_leaf.shape[0]
     A = active.shape[0]
@@ -172,7 +177,7 @@ def compact_plan(hist_leaf: jnp.ndarray, active: jnp.ndarray,
     ga = jnp.full(((n_groups + 1) * G,), -2, jnp.int32)
     ga = jax.lax.dynamic_update_slice(
         ga, jnp.where(active >= 0, active, -2).astype(jnp.int32), (0,))
-    group_active = ga.reshape(n_groups + 1, G).T     # [G, n_groups + 1]
+    group_active = ga.reshape(n_groups + 1, G, 1)
     return src, tile_group, group_active
 
 
@@ -266,7 +271,8 @@ def hist_active_compact(bins_t: jnp.ndarray,
 
     Cc, Gp, cols = _col_layout(G, mode)
     assert Cc == C and Gp == G, (Cc, C, Gp)
-    T = _pick_row_tile(n_pad, B, cols, C, row_tile)
+    seeded = acc is not None
+    T = _pick_row_tile(n_pad, B, cols, C, row_tile, seeded)
     assert n_pad % T == 0, (n_pad, T)
     pad_cols = cols - C * Gp
 
@@ -284,20 +290,15 @@ def hist_active_compact(bins_t: jnp.ndarray,
 
     # feature tiling: identical VMEM model to the wide kernel, at the
     # group column count
-    ft_cap = max(1, _feat_tile_cap(B, cols, T, C))
-    if ft_cap >= F_pad:
-        feat_tile = F_pad
-    else:
-        feat_tile = max(8, (ft_cap // 8) * 8)
-    F_grid = _round_up(F_pad, feat_tile)
+    feat_tile, F_grid = feat_tiling(F_pad, B, cols, T, C, seeded)
     if F_grid != F_pad:
         bins_c = jnp.pad(bins_c, ((0, F_grid - F_pad), (0, 0)))
     nft = F_grid // feat_tile
     n_c = bins_c.shape[1]
 
-    seeded = acc is not None
     in_specs = [
-        pl.BlockSpec((G, 1), lambda j, i, tg: (0, tg[i]),
+        # the tile's group page, selected by the prefetched tile_group
+        pl.BlockSpec((None, G, 1), lambda j, i, tg: (tg[i], 0, 0),
                      memory_space=pltpu.VMEM),
         pl.BlockSpec((feat_tile, T), lambda j, i, tg: (j, i),
                      memory_space=pltpu.VMEM),
@@ -353,11 +354,8 @@ def compact_raw_layout(n_pad: int, num_active: int, num_features: int,
     G = COMPACT_GROUP
     n_groups = -(-num_active // G)
     C, Gp, cols = _col_layout(G, mode)
-    T = _pick_row_tile(n_pad, B, cols, C, row_tile)
-    ft_cap = max(1, _feat_tile_cap(B, cols, T, C))
-    F_pad = num_features
-    feat_tile = F_pad if ft_cap >= F_pad else max(8, (ft_cap // 8) * 8)
-    F_grid = _round_up(F_pad, feat_tile)
+    T = _pick_row_tile(n_pad, B, cols, C, row_tile, seeded=True)
+    _, F_grid = feat_tiling(num_features, B, cols, T, C, seeded=True)
     dtype = jnp.int32 if is_quantized(mode) else jnp.float32
     return ((n_groups + 1) * F_grid * B, cols), dtype
 

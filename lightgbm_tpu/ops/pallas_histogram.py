@@ -43,17 +43,17 @@ Memory layout notes:
   reference's GPU single-precision trade-off
   (`docs/GPU-Performance.rst:135-161`).  The default is the QUANTIZED
   path (``mode="int8h"``, :func:`pack_values_q`): int8 operands on the
-  MXU's 2.1x-throughput integer path with EXACT int32 accumulation.
+  MXU's integer path (twice the bf16 peak on a v5e by its published
+  figures) with EXACT int32 accumulation.
 
 On 4-bit bin packing (the reference's ``dense_nbits_bin.hpp`` /
-Feature4 DWORD lever, twice proposed as the HBM lever): measured
-against, deliberately not built.  Device traces of the fused kernel
-(r4) show the wave cost is MXU/VPU-bound at every bench shape — the
-bins stream is ~28 MB of a ~550 MB/wave total at 1M rows, under 10% of
-wave wall-clock even before the one-hot build's VPU cost; halving it at
-``max_bin<=15`` caps out at a few percent on a config the benchmarks
-don't use.  The lever that actually paid on this hardware is the int8
-MXU path above (34->42M row-iters/s measured at bench shapes).
+Feature4 DWORD lever, twice proposed as the HBM lever): deliberately
+not built.  By the kernel's own arithmetic the uint8 bins stream is a
+small part of what a wave moves (28 bytes a row at 28 features, next to
+the one-hot and value operands built in VMEM), and halving it at
+``max_bin<=15`` helps only a config the benchmarks do not use.  Whether
+the wave is MXU/VPU-bound, as that reasoning assumes, is unverified on a
+local chip: no device trace has been read there (PERF.md).
 """
 from __future__ import annotations
 
@@ -71,7 +71,8 @@ from jax.experimental.pallas import tpu as pltpu
 # stay bound here for the kernels and tests that grew up on them
 from .vmem import (VMEM_BUDGET_BYTES as _VMEM_BUDGET_BYTES,
                    cell_vmem_bytes as _cell_vmem_bytes,
-                   feat_tile_cap as _feat_tile_cap, hist_cell_ok,
+                   feat_tile_cap as _feat_tile_cap, feat_tiling,
+                   hist_cell_ok,
                    next_pow2 as _next_pow2,
                    pick_row_tile as _pick_row_tile,
                    round_up as _round_up)
@@ -171,7 +172,7 @@ def pack_values(grad: jnp.ndarray, hess: jnp.ndarray, mode: str,
     Rows-on-lanes layout: the row dimension is the minor (lane) axis both
     here and in the kernels, so the host-side pad/stack write dense
     ``[C, n]`` tiles (the previous ``[n, C]`` layout put C=3 on lanes —
-    a ~2.3 ms/iter pad+copy at 1M rows); padding rows carry 0.
+    a pad+copy per iteration); padding rows carry 0.
     """
     n = grad.shape[0]
     n_pad = _round_up(n, row_tile)
@@ -210,8 +211,9 @@ def pack_values_q(grad: jnp.ndarray, hess: jnp.ndarray, mode: str,
     [C, n_pad], scales f32 [2])``.
 
     The TPU answer to the reference 4.x quantized-training idea
-    (gradient discretization): the MXU's int8 path runs 2.1x the bf16
-    throughput on this hardware (370 vs 178 Tops/s measured), and the
+    (gradient discretization): the MXU's int8 path has twice the bf16
+    peak on a v5e (published figures; what the kernel reaches of either
+    is not measured on a local chip), and the
     one-hot operand is 0/1 so every histogram cell accumulates EXACTLY
     in int32 (<= n*127 < 2^31 for n <= 16M rows — no float rounding at
     all; the only error is the per-row quantization).
@@ -301,9 +303,9 @@ def _onehot_bins(bins_i32: jnp.ndarray, B: int,
     ONE rank-3 broadcast-compare ``[Ft, 1, T] == [1, B, T]`` reshaped to
     ``[Ft*B, T]`` (leading-dim merge, layout-free) — no matmul, no f32
     intermediate, and no per-feature concatenate: the concat of Ft
-    ``[B, T]`` slices re-copied the whole one-hot (~3.6 GB/wave of extra
-    VMEM traffic at 1M rows), which set the measured ~2.6 ms/wave floor
-    that dominated small waves."""
+    ``[B, T]`` slices re-copied the whole one-hot (extra VMEM traffic
+    that, on the chip the kernel was first tuned on, set the floor of
+    small waves; unverified on a local chip)."""
     Ft, T = bins_i32.shape
     iota_b = jax.lax.broadcasted_iota(jnp.int32, (1, B, T), 1)
     oh = bins_i32[:, None, :] == iota_b
@@ -442,20 +444,16 @@ def hist_active_pallas(bins_t: jnp.ndarray,
     B = bin_stride(max_bins)
 
     _, A_pad, cols = _col_layout(A, mode)
-    T = _pick_row_tile(n_pad, B, cols, C, row_tile)
+    seeded = acc is not None
+    T = _pick_row_tile(n_pad, B, cols, C, row_tile, seeded)
     assert n_pad % T == 0, (n_pad, T)
     pad_cols = cols - C * A_pad
     # feature tile: bounded by the per-grid-cell VMEM footprint (f32
     # accumulator + the bf16 one-hot + the bins tile — ADVICE r2: the
     # accumulator alone under-counts by the one-hot's tens of MB on wide
-    # low-bin datasets); when tiling, the block's sublane dim must be a
-    # multiple of 8 (Mosaic tiling constraint — full-array is exempt)
-    ft_cap = max(1, _feat_tile_cap(B, cols, T, C))
-    if ft_cap >= F_pad:
-        feat_tile = F_pad
-    else:
-        feat_tile = max(8, (ft_cap // 8) * 8)
-    F_grid = _round_up(F_pad, feat_tile)
+    # low-bin datasets; a seeded call also counts the carried
+    # accumulator it streams in)
+    feat_tile, F_grid = feat_tiling(F_pad, B, cols, T, C, seeded)
     if F_grid != F_pad:
         bins_t = jnp.pad(bins_t, ((0, F_grid - F_pad), (0, 0)))
 
@@ -469,7 +467,6 @@ def hist_active_pallas(bins_t: jnp.ndarray,
     # active padding so neither lands in a real column block; -1 actives
     # (wave padding) DO accumulate bagged-out rows, caller drops them.
     grid = (F_grid // feat_tile, n_pad // T)
-    seeded = acc is not None
     in_specs = [
         pl.BlockSpec((A_pad, 1), lambda f, r: (0, 0),
                      memory_space=pltpu.VMEM),
@@ -520,9 +517,10 @@ def hist_raw_layout(n_pad: int, num_active: int, num_features: int,
     accumulator for this config — the shape a streamed fold carries
     across blocks (``acc`` / ``raw=True`` in :func:`hist_active_pallas`).
 
-    Replicates the kernel's own tile arithmetic (row tile from the VMEM
-    model, feature tile from :func:`feat_tile_cap`), so the carry can be
-    allocated before the first call.  ``num_features`` must equal the
+    The kernel's own tile arithmetic for a SEEDED call (row tile and
+    feature tile from the shared VMEM model, ``ops/vmem.feat_tiling``),
+    so the carry can be allocated before the first call.
+    ``num_features`` must equal the
     bins' F_pad (streamed sources transpose with ``feat_tile=None``, so
     F_pad == F); ``n_pad`` is the per-block padded row count — every
     block of a stream uses the same one, which is what keeps the layout
@@ -530,11 +528,8 @@ def hist_raw_layout(n_pad: int, num_active: int, num_features: int,
     """
     B = bin_stride(max_bins)
     C, A_pad, cols = _col_layout(num_active, mode)
-    T = _pick_row_tile(n_pad, B, cols, C, row_tile)
-    ft_cap = max(1, _feat_tile_cap(B, cols, T, C))
-    F_pad = num_features
-    feat_tile = F_pad if ft_cap >= F_pad else max(8, (ft_cap // 8) * 8)
-    F_grid = _round_up(F_pad, feat_tile)
+    T = _pick_row_tile(n_pad, B, cols, C, row_tile, seeded=True)
+    _, F_grid = feat_tiling(num_features, B, cols, T, C, seeded=True)
     dtype = jnp.int32 if is_quantized(mode) else jnp.float32
     return (F_grid * B, cols), dtype
 

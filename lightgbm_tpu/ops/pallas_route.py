@@ -11,7 +11,7 @@ right.
 
 In XLA this is a chain of ``[n]``-sized gathers from small tables plus a
 ``take_along_axis`` over the ``[n, G]`` matrix — each of which lowers to
-a slow serialized gather on TPU (~3-25 ms per pass at 1M rows).  Here the
+a serialized gather on TPU (its cost is unverified on a local chip).  Here the
 whole decision runs in VMEM per row-tile:
 
 * leaf one-hot ``[L_pad, T]`` (compare against an iota — no gather),
@@ -29,7 +29,7 @@ Two leaf vectors ride together (``row_leaf`` for all rows, ``hist_leaf``
 with bagged-out rows parked at -1) so both are routed in one pass.
 
 Streams ``bins_t`` (uint8) + the leaf vectors once per wave — the whole
-route costs ~1 stream pass instead of ~50 ms of gathers.
+route costs ~1 stream pass instead of a chain of gathers.
 """
 from __future__ import annotations
 
@@ -51,7 +51,7 @@ _T_GROUP, _T_THR, _T_DL, _T_ISCAT, _T_SEL, _T_NEWID = 0, 1, 2, 3, 4, 5
 _T_OFF, _T_NB, _T_DB, _T_MT, _T_NANB = 6, 7, 8, 9, 10
 # per-leaf OUTPUT value as a hi+lo bf16 pair (exact to ~2^-17 through the
 # bf16 MXU pass) — used by the final per-tree route to emit each row's
-# leaf value, replacing the ~7ms/iter XLA gather lv[row_leaf]
+# leaf value, replacing the XLA gather lv[row_leaf]
 _T_LVH, _T_LVL = 11, 12
 _T_ROWS = 16
 
@@ -284,7 +284,7 @@ def route_rows_values_pallas(bins_t: jnp.ndarray,
     leaf value — ``-> (leaf2 [2, n_pad] i32, values [n_pad] f32)``.
 
     Replaces the score-update gather ``leaf_value[row_leaf]`` (an
-    XLA-serialized ~7 ms/iter op at 1M rows) with one extra table-row
+    XLA-serialized op; its cost is unverified on a local chip) with one extra table-row
     dot inside the route pass.  Values ride the MXU as hi+lo bf16 pairs
     (exact to ~2^-17); out-of-tree rows (leaf -1 / padding) emit 0.
     """

@@ -3,9 +3,8 @@
 The XLA expression of the split scan (`ops/split.py:find_best_splits`)
 is ~50 small ops per wave on `[2A, F, B, 3]` grids; at 9 waves per
 iteration the op-count overhead is row-independent and becomes the
-dominant per-iteration fixed cost on small-to-medium datasets (measured
-~6 ms/iteration at 1M rows vs a ~23 ms/iteration row-scaled cost —
-VERDICT r4 #4).  This kernel computes the whole numerical scan — both
+dominant per-iteration fixed cost on small-to-medium datasets (its size
+is unverified on a local chip).  This kernel computes the whole numerical scan — both
 missing-direction variants, constraint masking, and the joint
 (feature, bin, direction) argmax — in ONE Pallas call over a
 ``[leaves, F*B]`` lanes layout.
@@ -89,53 +88,6 @@ def _vmem_budget_bytes() -> int:
     return split_vmem_budget_bytes()
 
 
-# module-global kill switch: flipped by disable_on_compile_error when a
-# Mosaic/VMEM compile failure escapes the static gates anyway; every
-# later trace falls back to the XLA scan path (GBDT rebuilds its
-# programs — see _shared_serial_build's split_kernel cache key)
-_DISABLED = [False]
-
-# markers of a kernel-compile-class failure (vs a transient RPC fault,
-# which the retry layer owns)
-COMPILE_FAILURE_MARKERS = ("Mosaic", "mosaic", "VMEM", "vmem",
-                           "Failed to compile", "XLA compilation",
-                           "jellyfish", "INTERNAL: Compile")
-
-
-def split_kernel_disabled() -> bool:
-    return _DISABLED[0]
-
-
-def disable_split_kernel(reason: str = "") -> None:
-    if not _DISABLED[0]:
-        _DISABLED[0] = True
-        from ..utils.log import log_once
-        # deduped: tests re-arm via enable_split_kernel and retried
-        # dispatches can re-trip this every block — one line per process
-        log_once("pallas_split.disabled",
-                 "fused split kernel disabled for this process; "
-                 "falling back to the XLA scan path"
-                 + (f" ({reason})" if reason else ""))
-
-
-def enable_split_kernel() -> None:
-    """Re-arm (tests)."""
-    _DISABLED[0] = False
-
-
-def disable_on_compile_error(exc: BaseException) -> bool:
-    """If ``exc`` looks like a kernel compile failure, disable the
-    kernel process-wide and return True (caller should rebuild + retry
-    its program once)."""
-    if _DISABLED[0]:
-        return False
-    msg = str(exc)
-    if any(m in msg for m in COMPILE_FAILURE_MARKERS):
-        disable_split_kernel(msg[:200])
-        return True
-    return False
-
-
 def split_kernel_ok(num_features: int, B: int,
                     has_categorical: bool, num_rows: int = 0) -> bool:
     """Whether the fused split kernel can express this config (numerical
@@ -148,7 +100,7 @@ def split_kernel_ok(num_features: int, B: int,
     row-scaled kernels and the fused call adds its own per-wave cost).
     Default: on for datasets at/below the compile-lean row threshold,
     where op overhead rules; LGBM_TPU_SPLIT_KERNEL=1/0 forces."""
-    if has_categorical or _DISABLED[0]:
+    if has_categorical:
         return False
     env = os.environ.get("LGBM_TPU_SPLIT_KERNEL", "")
     if env in ("0", "false"):

@@ -75,33 +75,56 @@ def col_layout(A: int, mode: str) -> tuple[int, int, int]:
     return C, A_pad, cols
 
 
-def cell_vmem_bytes(ft: int, B: int, cols: int, T: int, C: int) -> int:
+def cell_vmem_bytes(ft: int, B: int, cols: int, T: int, C: int,
+                    seeded: bool = False) -> int:
     """VMEM footprint of one (feature-tile, row-tile) histogram grid
     cell: the f32 accumulator, the bf16 one-hot, the weighted value
-    block, the bins tile (double-buffered), and the packed values."""
-    return (ft * B * cols * 4        # accumulator (out block)
+    block, the bins tile (double-buffered), and the packed values.
+    ``seeded``: the streamed-fold variant also streams the carried
+    accumulator IN, double-buffered like every blocked operand — two
+    more accumulator-sized blocks (the v5e compiler refused the
+    28 x 63-bin x 128-slot seeded cell at 16.76 MB of scoped VMEM
+    before this was counted)."""
+    acc = ft * B * cols * 4          # accumulator (out block)
+    return (acc + (2 * acc if seeded else 0)
             + ft * B * T * 2         # one-hot bf16
             + T * cols * 2           # vw bf16
             + 2 * ft * T             # bins tile, double-buffered
             + 2 * T * C * 4)         # vals, double-buffered
 
 
-def feat_tile_cap(B: int, cols: int, T: int, C: int) -> int:
+def feat_tile_cap(B: int, cols: int, T: int, C: int,
+                  seeded: bool = False) -> int:
     """Largest feature tile whose grid cell fits the VMEM budget."""
     ft = max(1, VMEM_BUDGET_BYTES // (B * (cols * 4 + T * 2)))
-    while ft > 1 and cell_vmem_bytes(ft, B, cols, T, C) > VMEM_BUDGET_BYTES:
+    while ft > 1 and cell_vmem_bytes(ft, B, cols, T, C,
+                                     seeded) > VMEM_BUDGET_BYTES:
         ft -= 1
     return ft
 
 
+def feat_tiling(F_pad: int, B: int, cols: int, T: int, C: int,
+                seeded: bool = False) -> tuple[int, int]:
+    """``-> (feat_tile, F_grid)`` of a histogram kernel call: the whole
+    feature set in one tile when it fits, else the largest multiple of
+    8 that does (Mosaic's sublane rule — a full-array block is exempt),
+    and the feature count padded to a whole number of tiles.  Shared by
+    the wide and compacted kernels and their raw-layout twins, so a
+    fold's carry can never disagree with the kernel that fills it."""
+    ft_cap = feat_tile_cap(B, cols, T, C, seeded)
+    feat_tile = F_pad if ft_cap >= F_pad else max(8, (ft_cap // 8) * 8)
+    return feat_tile, round_up(F_pad, feat_tile)
+
+
 def pick_row_tile(n_pad: int, B: int, cols: int, C: int,
-                  requested: int) -> int:
+                  requested: int, seeded: bool = False) -> int:
     """Largest power-of-two tile <= ``requested`` that divides ``n_pad``
     and whose minimum-feature-tile grid cell fits the VMEM budget."""
     T = requested
     while T > 1024 and (
             n_pad % T != 0
-            or cell_vmem_bytes(8, B, cols, T, C) > VMEM_BUDGET_BYTES):
+            or cell_vmem_bytes(8, B, cols, T, C,
+                               seeded) > VMEM_BUDGET_BYTES):
         T //= 2
     return T
 
@@ -124,16 +147,15 @@ def hist_fold_cell_ok(max_bins: int, active_slots: int, mode: str,
     """Feasibility of the accumulator-SEEDED histogram cell (the
     out-of-core fold variant of the kernels): on top of
     :func:`hist_cell_ok`'s residents, the carried accumulator operand
-    adds one more ``[ft*B, cols]`` block (same element size as the
-    output; int32 on the quantized modes) fetched into VMEM for the
+    streams in as a double-buffered ``[ft*B, cols]`` block (same
+    element size as the output; int32 on the quantized modes) for the
     seed-load.  ``extra_bytes`` composes with kernel-specific residents
     exactly as in :func:`hist_cell_ok` (the compacted fold passes its
     group-active slice + leaf row through here)."""
     B = bin_stride(max_bins)
     C, _, cols = col_layout(active_slots, mode)
-    seed = 8 * B * cols * 4              # acc block at the min feat tile
-    return hist_cell_ok(max_bins, active_slots, mode, row_tile,
-                        extra_bytes + seed)
+    return (cell_vmem_bytes(8, B, cols, row_tile, C, seeded=True)
+            + extra_bytes <= VMEM_BUDGET_BYTES)
 
 
 def split_vmem_budget_bytes() -> int:

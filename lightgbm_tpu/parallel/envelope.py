@@ -4,9 +4,10 @@ The reference's distributed contract is bit-identical trees on every
 machine (`application.cpp:249-254`; the split sequence of
 `data_parallel_tree_learner.cpp:147-162` is identical by construction).
 The JAX port's data-parallel psum reassociates f32 adds per shard
-layout, so gain ties can flip split winners — MULTICHIP_r05 measured a
-1.63% row-leaf mismatch vs serial at bench shape with mse equal to 5
-decimals.  Documenting that envelope is not the same as GATING it
+layout, so gain ties can flip split winners — a round-5 dry run on
+virtual CPU devices measured a 1.63% row-leaf mismatch vs serial at
+bench shape with mse equal to 5 decimals.  Documenting that envelope
+is not the same as GATING it
 (VERDICT r5 Weak #4): nothing previously asserted that mismatched rows
 diverge only at NEAR-TIES, so a real histogram-merge corruption could
 hide inside the 1.63%.

@@ -57,17 +57,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..obs.flight_recorder import record as _fr_record
 
-# jax >= 0.6 exposes shard_map at top level (replication checking via
-# `check_vma`); 0.4.x ships it under experimental with `check_rep`.
-# The alias keeps the bare name `shard_map` so the static analyzers'
-# name-based root detection (tpulint callgraph, spmdcheck) still sees
-# the wrapped function as a traced entry point.
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-    _SM_CHECK_KW = "check_vma"
-else:                               # jax 0.4.x fallback
-    from jax.experimental.shard_map import shard_map
-    _SM_CHECK_KW = "check_rep"
+# the bare name `shard_map` is what the static analyzers' name-based
+# root detection (tpulint callgraph, spmdcheck) keys on to see the
+# wrapped function as a traced entry point
+shard_map = jax.shard_map
 
 from ..io.device import DeviceData
 from ..learner.serial import (BuiltTree, GrowthParams, apply_hist_wave,
@@ -411,7 +404,7 @@ def build_tree_distributed(mesh: Mesh, axis: str, learner_type: str,
                 vec, vec, vec, P())
 
     fn = shard_map(step, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_spec, **{_SM_CHECK_KW: False})
+                   out_specs=out_spec, check_vma=False)
     return fn(data.bins, data.bin_offsets, data.num_bins, data.default_bins,
               data.missing_types, data.is_categorical, data.nan_bins,
               data.feat_group, data.feat_offset,

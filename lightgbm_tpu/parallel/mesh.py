@@ -71,14 +71,11 @@ def init_distributed(coordinator_address: Optional[str] = None,
         finally:
             release_trace()
     except RuntimeError as exc:
-        # idempotent entry: the CLI's already-meshed probe reads private
-        # jax state and may miss on a future jax — double-initialize
-        # must then degrade to a no-op, not a crash (ADVICE r4).
-        # jax 0.9 phrases it "distributed.initialize should only be
-        # called once."; older builds say "already initialized"
-        msg = str(exc).lower()
-        if ("already initialized" not in msg
-                and "only be called once" not in msg):
+        # idempotent entry: a second initialize (a caller that does not
+        # ask jax.distributed.is_initialized() first, as the CLI does)
+        # is a no-op, not a crash (ADVICE r4).  jax 0.9 phrases it
+        # "distributed.initialize should only be called once."
+        if "only be called once" not in str(exc).lower():
             raise
 
 

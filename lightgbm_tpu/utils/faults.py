@@ -37,7 +37,7 @@ the seams where production faults actually strike:
   the training window / serve batch currently armed on the stall
   watchdog (``obs/health.py``, ``LGBM_TPU_WATCHDOG_S``) sleeps
   in-window past the deadline — simulating the hung-dispatch class
-  (wedged collective, dead tunnel) the watchdog must name in a
+  (wedged collective, dead runtime link) the watchdog must name in a
   ``health:stall`` event + kill-survivable forensic dump,
 * ``health.nan_grad`` — a SILENT fault: while armed, one gradient
   element is poisoned to NaN (``boosting/gbdt._gradients``) —

@@ -3,8 +3,8 @@ network-shaped seam.
 
 The reference survives flaky links by retrying at the socket layer
 (``linkers_socket.cpp``: blocking send/recv loops re-enter on partial
-writes); on a TPU pod the equivalent faults are RPC-flavored — tunnel
-resets, rendezvous races, DCN blips — and they surface from three
+writes); on a TPU pod the equivalent faults are RPC-flavored —
+connection resets, rendezvous races, DCN blips — and they surface from three
 places: jitted dispatch (``boosting/gbdt.py``), the multi-host
 rendezvous (``parallel/mesh.py``), and host collectives
 (``io/distributed.py``).  All three now share THIS policy instead of

@@ -173,8 +173,9 @@ def test_compact_plan_layout():
     tg = np.asarray(tile_group)
     assert (np.diff(tg) >= 0).all()
     ga = np.asarray(group_active)
-    np.testing.assert_array_equal(ga[:3, 0], [0, 5, 7])
-    assert (ga[3:, 0] == -2).all()                  # -2 pad: never matches
+    assert ga.shape == (2, COMPACT_GROUP, 1)        # group 0 + trash page
+    np.testing.assert_array_equal(ga[0, :3, 0], [0, 5, 7])
+    assert (ga[0, 3:, 0] == -2).all()               # -2 pad: never matches
 
 
 def test_compact_psum_data_parallel():
@@ -184,7 +185,7 @@ def test_compact_psum_data_parallel():
     wide kernel, so the spmdcheck/flight-recorder contract is
     untouched."""
     from jax.sharding import Mesh, PartitionSpec as P
-    from lightgbm_tpu.parallel.learners import _SM_CHECK_KW, shard_map
+    from lightgbm_tpu.parallel.learners import shard_map
 
     n, F, L, A, max_bins = 4096, 5, 255, 64, 63
     rng, bins, grad, hess, row_leaf = _dyadic_data(n, F, L, max_bins,
@@ -207,7 +208,7 @@ def test_compact_psum_data_parallel():
 
     fn = shard_map(step, mesh=mesh,
                    in_specs=(P(None, "d"), P(None, "d"), P(None, "d")),
-                   out_specs=P(), **{_SM_CHECK_KW: False})
+                   out_specs=P(), check_vma=False)
     out_p = np.asarray(fn(bt, vals, leaf_p))
     out_s = np.asarray(hist_active_scatter(
         jnp.asarray(bins), jnp.asarray(grad), jnp.asarray(hess),
@@ -336,3 +337,60 @@ def test_build_tree_compact_matches_pallas_int8h():
     np.testing.assert_array_equal(a.threshold_bin, b.threshold_bin)
     np.testing.assert_array_equal(a.leaf_value, b.leaf_value)
     np.testing.assert_array_equal(a.leaf_count, b.leaf_count)
+
+
+def test_resolve_backend_logs_each_substitution_once(caplog):
+    """Whenever the resolved backend is not the one asked for, the
+    choice and its ground are logged — once per distinct case, at info."""
+    from types import SimpleNamespace
+    from lightgbm_tpu.learner.serial import resolve_backend
+    from lightgbm_tpu.utils.log import reset_log_once
+    reset_log_once()
+    dd = SimpleNamespace(group_max_bins=63)
+    with caplog.at_level("INFO", logger="lightgbm_tpu"):
+        # 31 leaves never reach the compaction threshold
+        assert resolve_backend(dd, 31, "compact", "int8h") == "pallas"
+        assert resolve_backend(dd, 31, "compact", "int8h") == "pallas"
+        # > 256 bins is outside the kernel model altogether
+        assert resolve_backend(SimpleNamespace(group_max_bins=300), 255,
+                               "compact", "int8h") == "scatter"
+        # the backend asked for: nothing to say
+        assert resolve_backend(dd, 255, "compact", "int8h") == "compact"
+    msgs = [r.getMessage() for r in caplog.records
+            if "histogram backend" in r.getMessage()]
+    assert len(msgs) == 2, msgs
+    assert "pallas (asked for compact)" in msgs[0]
+    assert "scatter (asked for compact)" in msgs[1]
+
+
+def test_hist_fold_logs_each_substitution_once(caplog):
+    """The streamed fold seam's own substitutions (compact -> wide
+    kernel, kernel -> carried scatter fold) are logged like
+    resolve_backend's: once per distinct case, at info, with the
+    ground."""
+    from types import SimpleNamespace
+    from lightgbm_tpu.learner.serial import make_hist_fold_fn
+    from lightgbm_tpu.utils.log import reset_log_once
+    reset_log_once()
+    dd63 = SimpleNamespace(group_max_bins=63, num_groups=28, num_data=8192)
+    dd255 = SimpleNamespace(group_max_bins=255, num_groups=28,
+                            num_data=8192)
+    with caplog.at_level("INFO", logger="lightgbm_tpu"):
+        # the fold the stream asked for: nothing to say
+        fold = make_hist_fold_fn(dd63, 255, 128, 8192, "compact", "int8h")
+        assert fold.backend == "compact"
+        # float compact chains are inexact: the wide kernel folds instead
+        for _ in range(2):
+            fold = make_hist_fold_fn(dd63, 255, 128, 8192, "compact",
+                                     "hilo")
+            assert fold.backend == "pallas"
+        # the seeded wide cell at 255 bins x 128 slots is over the VMEM
+        # model (and the chip's compiler refuses it): scatter fold
+        assert make_hist_fold_fn(dd255, 255, 128, 8192, "pallas",
+                                 "hilo") is None
+    msgs = [r.getMessage() for r in caplog.records
+            if "streamed histogram fold" in r.getMessage()]
+    assert len(msgs) == 2, msgs
+    assert msgs[0].startswith("streamed histogram fold: pallas (resolved "
+                              "backend compact)")
+    assert msgs[1].startswith("streamed histogram fold: scatter")

@@ -436,7 +436,7 @@ def test_transient_dispatch_retry():
     def flaky(*args):
         calls["n"] += 1
         if calls["n"] == 1:
-            raise RuntimeError("UNAVAILABLE: tunnel hiccup")
+            raise RuntimeError("UNAVAILABLE: dispatch hiccup")
         return "ok"
 
     assert g._dispatch_retry(flaky) == "ok"

@@ -1,8 +1,8 @@
 """Multi-chip divergence envelope gate on the virtual 8-device CPU mesh
 (PR 4 satellite: VERDICT r5 Weak #4).
 
-The bench-shape run reproduces MULTICHIP_r05's 1.63% row-leaf mismatch
-bit-for-bit on the CPU mesh (seed 0), so the gate is exercised against
+The bench-shape run reproduces the 1.63% row-leaf mismatch of the
+round-5 virtual-CPU-device dry run on the CPU mesh (seed 0), so the gate is exercised against
 REAL divergence, not a synthetic stand-in: every mismatched row must
 classify as a near-tie artifact (flip within the measured gain margin,
 budget flip, or leaf renumbering with value agreement), under a hard
@@ -34,7 +34,7 @@ def eight_devices():
 def _bench_shape_pair():
     """Serial + 8-way data-parallel trees at the divergence-bearing
     bench shape (131072 x 28, 255 leaves) — the exact configuration
-    where MULTICHIP_r05 measured the ungated 1.63% mismatch."""
+    where the round-5 dry run measured the ungated 1.63% mismatch."""
     rng = np.random.RandomState(0)
     n, f, leaves = 131_072, 28, 255
     X = rng.normal(size=(n, f)).astype(np.float32)

@@ -29,8 +29,7 @@ from lightgbm_tpu.io.device import to_device
 from lightgbm_tpu.learner.serial import GrowthParams, build_tree
 from lightgbm_tpu.ops.overlap import _chunk_bounds, wave_psum
 from lightgbm_tpu.ops.split import SplitParams
-from lightgbm_tpu.parallel.learners import (_SM_CHECK_KW,
-                                            build_tree_distributed,
+from lightgbm_tpu.parallel.learners import (build_tree_distributed,
                                             shard_map)
 from lightgbm_tpu.parallel.mesh import make_mesh
 from lightgbm_tpu.obs import flight_recorder as fr
@@ -86,7 +85,7 @@ def test_chunked_psum_bit_identical_to_plain(two_devices):
     def run(fn):
         f = shard_map(fn, mesh=mesh, in_specs=(jax.sharding.PartitionSpec("data"),),
                       out_specs=jax.sharding.PartitionSpec(),
-                      **{_SM_CHECK_KW: False})
+                      check_vma=False)
         return np.asarray(f(x))
 
     plain = run(lambda s: jax.lax.psum(s[0], "data"))

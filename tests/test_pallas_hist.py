@@ -266,3 +266,54 @@ def test_route_kernel_matches_xla():
     np.testing.assert_allclose(vals[:n], expect, rtol=0, atol=2e-5)
     # padding rows (leaf -1) emit exactly 0
     assert (vals[n:] == 0.0).all()
+
+
+@pytest.mark.parametrize("mode,bagged", [("int8h", False), ("int8", False),
+                                         ("int8hh", False),
+                                         ("int8h", True)])
+def test_quantized_leaf_values_are_their_own_rows_sums(mode, bagged):
+    """A tree histogrammed in int8 codes takes its root totals from the
+    same codes: every leaf's value is then the value of its own rows'
+    sums, off by no more than those rows' rounding (``scale / 254``
+    each).  With the root totals from the exact f32 gradients the
+    rounding bias of ALL rows — two gradient values here, so one sign per
+    class, as in a binary model's first tree — went down the
+    ``parent - sibling`` side of every split and ended in one small
+    leaf (a value off by ~2 on this data; -8 and +113 at a million rows
+    on the chip)."""
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.io.dataset import BinnedDataset
+    from lightgbm_tpu.io.device import to_device
+    from lightgbm_tpu.learner.serial import GrowthParams, build_tree
+    from lightgbm_tpu.ops.split import SplitParams
+    rng = np.random.RandomState(0)
+    n = 8192
+    X = rng.normal(size=(n, 4)).astype(np.float32)
+    y = (X[:, 0] * 2 + X[:, 1] + rng.normal(size=n) > 0.8)
+    p0 = float(y.mean())
+    g = (p0 - y).astype(np.float32)
+    h = np.full(n, p0 * (1.0 - p0), np.float32)
+    bag = rng.rand(n) < 0.7 if bagged else np.ones(n, bool)
+    dd = to_device(BinnedDataset.from_raw(
+        X, Config.from_params({"max_bin": 63})))
+    gp = GrowthParams(num_leaves=31,
+                      split=SplitParams(min_data_in_leaf=20,
+                                        min_sum_hessian_in_leaf=1e-3))
+    t = jax.tree.map(np.asarray, build_tree(
+        dd, jnp.asarray(g), jnp.asarray(h), gp,
+        bag_mask=jnp.asarray(bag) if bagged else None,
+        hist_backend="pallas", hist_mode=mode))
+    nl = int(t.num_leaves)
+    assert nl == 31
+    sg, sh = float(np.abs(g).max()), float(np.abs(h).max())
+    for leaf in range(nl):
+        rows = (t.row_leaf == leaf) & bag
+        cnt = int(rows.sum())
+        assert cnt == t.leaf_count[leaf]
+        G = float(g[rows].astype(np.float64).sum())
+        H = float(h[rows].astype(np.float64).sum())
+        dG, dH = cnt * sg / 254.0, cnt * sh / 254.0
+        exact = -G / H
+        bound = (dG + abs(exact) * dH) / (H - dH) + 1e-5
+        assert abs(t.leaf_value[leaf] - exact) <= bound, (
+            leaf, cnt, t.leaf_value[leaf], exact, bound)

@@ -208,3 +208,35 @@ def test_end_to_end_data_parallel_training(eight_devices):
     p_d = bst.predict(X[:200], raw_score=True)
     p_s = bst_s.predict(X[:200], raw_score=True)
     np.testing.assert_allclose(p_d, p_s, rtol=1e-3, atol=1e-3)
+
+
+def test_parallel_learner_on_one_device_warns_and_trains_serially(
+        monkeypatch, caplog):
+    """tree_learner != serial with one visible device and no mesh_shape
+    builds no mesh — and says so, at warning level."""
+    one = jax.devices()[:1]
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: one)
+    X, y = _data(n=256, f=4)
+    with caplog.at_level("WARNING", logger="lightgbm_tpu"):
+        bst = lgb.train({"objective": "regression", "tree_learner": "data",
+                         "num_leaves": 7, "min_data_in_leaf": 5},
+                        lgb.Dataset(X, label=y), 2, verbose_eval=False,
+                        keep_training_booster=True)
+    assert bst._gbdt.mesh_ctx is None
+    assert any("training SERIALLY" in r.getMessage()
+               for r in caplog.records)
+
+
+def test_dryrun_multichip_raises_with_too_few_devices():
+    """The driver entry starts no child after touching JAX: with too few
+    devices it raises and names the variables to set."""
+    import os
+    import sys
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from __graft_entry__ import dryrun_multichip
+    need = len(jax.devices()) + 1
+    with pytest.raises(RuntimeError) as e:
+        dryrun_multichip(need)
+    assert "XLA_FLAGS" in str(e.value) and "JAX_PLATFORMS" in str(e.value)
+    assert f"device_count={need}" in str(e.value)

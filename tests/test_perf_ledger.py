@@ -3,9 +3,10 @@
 Unit half: synthetic BENCH histories prove the regression flag (>10%
 below the best prior round exits nonzero, naming metric and rounds)
 and the README figure-provenance rules.  Integration half: the ledger
-must render a trend row for EVERY committed BENCH_r*.json (unparsed
-driver-timeout rounds included) and the repo README's fenced measured
-figures must name source rounds that actually contain them — the
+must render a trend row for EVERY BENCH_r*.json of a history (unparsed
+driver-timeout rounds included), hold on an EMPTY committed history,
+and the repo README's fenced measured figures must name source rounds
+that actually contain them or say they were not measured — the
 mechanized TPL008 companion for ratio figures (ADVICE r5 #3).
 """
 import json
@@ -145,12 +146,25 @@ def test_readme_entry_groups_continuation_lines(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# integration over the COMMITTED repo history + README (tier-1 gates)
+# a five-round history shaped like a driver's (fixture artifacts), and
+# the COMMITTED repo history + README (tier-1 gates).  The committed
+# history is empty since PR 21 (the old rounds were runs of a backend
+# that no longer exists): every gate must hold on no artifact at all.
 # ---------------------------------------------------------------------------
-def test_committed_history_renders_every_round(capsys):
-    hist = load_history(REPO)
-    assert [h["round"] for h in hist][:5] == [1, 2, 3, 4, 5]
-    # r5 is the rc=124 driver-timeout artifact: visible, unparsed
+def _five_rounds(root):
+    _write(root, 1, {"value": 1e6})
+    _write(root, 2, {"value": 2e6})
+    _write(root, 3, {"value": 3e6})
+    _write(root, 4, {"value": 4e6, "full_row_iters_per_sec": 5e6})
+    _write(root, 5, None, rc=124)       # driver-timeout round
+
+
+def test_history_renders_every_round(tmp_path, capsys):
+    root = str(tmp_path)
+    _five_rounds(root)
+    hist = load_history(root)
+    assert [h["round"] for h in hist] == [1, 2, 3, 4, 5]
+    # r5 is an rc=124 driver-timeout artifact: visible, unparsed
     r5 = next(h for h in hist if h["round"] == 5)
     assert r5["parsed"] is None and r5["rc"] == 124
     render_table(hist)
@@ -160,12 +174,14 @@ def test_committed_history_renders_every_round(capsys):
     assert "parse:null" in out
 
 
-def test_committed_history_has_no_regression():
+def test_committed_history_has_no_regression(tmp_path):
     """The newest parsed round must sit within 10% of every metric's
-    best prior round — the standing cross-round perf gate.  If this
-    fails after a new driver round lands, the ledger is doing its job:
-    fix the regression or document the trade in the artifact."""
+    best prior round — the standing cross-round perf gate, which an
+    empty committed history passes and a five-round fixture history
+    with a monotone headline passes too."""
     assert check_regressions(load_history(REPO)) == []
+    _five_rounds(str(tmp_path))
+    assert check_regressions(load_history(str(tmp_path))) == []
 
 
 def test_repo_readme_figures_name_source_rounds():
@@ -173,3 +189,14 @@ def test_repo_readme_figures_name_source_rounds():
     source round that contains it (or carries an explicit
     not-captured marker) — ADVICE r5 #3, mechanized."""
     assert check_readme(REPO) == []
+
+
+def test_readme_not_measured_marker_skips(tmp_path):
+    """With no artifact at all, a fenced figure marked `not measured`
+    is clean and an unmarked one still flags."""
+    root = str(tmp_path)
+    _readme(root, "```\nleg:  not measured on the current tree "
+                  "(target 3.0x)\n```\n")
+    assert check_readme(root) == []
+    _readme(root, "```\nleg:  36.5M row-iters/s (1.66x)\n```\n")
+    assert len(check_readme(root)) == 1

@@ -1,0 +1,268 @@
+"""The main path's kernels and programs, compiled for the real chip.
+
+Every other Pallas test in this suite runs ``interpret=True`` on the CPU,
+which cannot see what the chip's compiler refuses: a block shape that
+breaks Mosaic's (8, 128) rule (the leaf-compacted kernel's group-active
+operand before PR 21), a grid cell that overflows scoped VMEM (the
+seeded wide fold at 128 slots before PR 21).  The TPU compiler is
+installed in this image and compiles for a chip that is DESCRIBED, not
+attached (``jax.experimental.topologies``), so these tests compile the
+kernels at their real widths for a ``v5e:2x2`` — no chip time, nothing
+runs, and a pass here is not a chip run.
+
+Rules this file keeps (on-chip-measurement guide, section 2): the
+topology is described inside a module-scoped fixture that skips where
+it cannot be — never while a module is imported, never ``autouse``,
+never in ``conftest.py``; everything built from it is built inside the
+test; compiles happen in the test's own process (it holds the TPU
+library's lock) with the persistent compile cache off around them (an
+entry written for a described chip cannot be read back without one);
+and all of it lives in this ONE file, so one xdist worker loads the
+library.  Code that asks ``jax.default_backend()`` is steered by
+monkeypatch in the test, not by an option of the program.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from lightgbm_tpu.io.device import DeviceData
+from lightgbm_tpu.learner.serial import GrowthParams, build_tree
+from lightgbm_tpu.ops.split import SplitParams
+from lightgbm_tpu.ops.vmem import bin_stride, hist_fold_cell_ok
+
+# the reference's HIGGS settings: the width chip_smoke.py trains at
+N, F, MAX_BIN, LEAVES = 1 << 20, 28, 63, 255
+N_TREE = 131_072            # whole-tree programs: same widths, fewer rows
+VALUE_ROWS = {"int8h": 4, "hilo": 5}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # noqa: BLE001 - any cause: cannot describe
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """The program's TPU branch (default backend, compiled kernels)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _shapes(sharding):
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+    return s
+
+
+def _compiled_text(lowered) -> str:
+    return lowered.compile().as_text()
+
+
+def _hist_args(s, n, mode, slots):
+    vdt = jnp.int8 if mode.startswith("int8") else jnp.float32
+    return [s((F, n), jnp.uint8), s((VALUE_ROWS[mode], n), vdt),
+            s((n,), jnp.int32), s((slots,), jnp.int32),
+            s((2,), jnp.float32)]
+
+
+def _split_tables(s):
+    """The per-leaf split tables + per-feature metadata the route
+    kernels take, in their positional order."""
+    i32, b = jnp.int32, jnp.bool_
+    leaf = (LEAVES,)
+    return [s(leaf, i32), s(leaf, i32), s(leaf, b), s(leaf, b),
+            s((LEAVES, bin_stride(MAX_BIN)), b), s(leaf, b),
+            s(leaf, i32)] + [s((F,), i32) for _ in range(6)]
+
+
+def _device_data(n, bins_sharding, meta_sharding):
+    s, m = _shapes(bins_sharding), _shapes(meta_sharding)
+    meta = lambda: m((F,), jnp.int32)                   # noqa: E731
+    return DeviceData(
+        bins=s((n, F), jnp.uint8), bin_offsets=meta(), num_bins=meta(),
+        default_bins=meta(), missing_types=meta(),
+        is_categorical=m((F,), jnp.bool_), nan_bins=meta(),
+        feat_group=meta(), feat_offset=meta(),
+        total_bins=F * MAX_BIN, max_bins=MAX_BIN, has_categorical=False,
+        max_group_bins=MAX_BIN, is_bundled=False, has_missing=False)
+
+
+def _growth():
+    return GrowthParams(num_leaves=LEAVES, max_depth=-1, wave_size=0,
+                        split=SplitParams(min_data_in_leaf=20,
+                                          min_sum_hessian_in_leaf=1e-3))
+
+
+# ---------------------------------------------------------------------------
+# histogram kernels
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("slots", [8, 32, 128])
+@pytest.mark.parametrize("mode", ["hilo", "int8h"])
+def test_wide_hist_kernel_compiles(one_chip, mode, slots):
+    from lightgbm_tpu.ops.pallas_histogram import hist_active_pallas
+    s = _shapes(one_chip)
+    text = _compiled_text(hist_active_pallas.lower(
+        *_hist_args(s, N, mode, slots), num_features=F, max_bins=MAX_BIN,
+        mode=mode))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("seeded", [False, True], ids=["plain", "seeded"])
+def test_compact_hist_kernel_compiles(one_chip, seeded):
+    """The default deep-wave kernel at the 1M x 28 x 63 shape, 128
+    slots in four 32-slot groups — refused before PR 21: its per-tile
+    group-active block was a (32, 1) window into a [32, n_groups + 1]
+    table."""
+    from lightgbm_tpu.ops.compact import (compact_raw_layout,
+                                          hist_active_compact)
+    s = _shapes(one_chip)
+    args = _hist_args(s, N, "int8h", 128)
+    kw = dict(num_features=F, max_bins=MAX_BIN, num_leaf_slots=LEAVES,
+              mode="int8h")
+    if seeded:
+        shape, dtype = compact_raw_layout(N, 128, F, MAX_BIN, "int8h")
+        args.append(s(shape, dtype))
+        kw["raw"] = True
+    text = _compiled_text(hist_active_compact.lower(*args, **kw))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("max_bin,slots,mode", [
+    (63, 128, "int8h"),     # 16.76 MB of scoped VMEM before PR 21
+    (63, 128, "hilo"),
+    (255, 64, "int8h"),
+    (255, 128, "int8h"),    # the gate refuses: so does the compiler
+])
+def test_seeded_wide_fold_gate_agrees_with_compiler(one_chip, max_bin,
+                                                    slots, mode):
+    """The streamed fold's seeded wide kernel: wherever the static gate
+    (`ops/vmem.hist_fold_cell_ok`) admits a cell the chip's compiler
+    takes it, and the cell the gate turns away is one the compiler
+    refuses — the gate is true, no runtime rescue is needed."""
+    from lightgbm_tpu.ops.pallas_histogram import (hist_active_pallas,
+                                                   hist_raw_layout)
+    s = _shapes(one_chip)
+    shape, dtype = hist_raw_layout(N, slots, F, max_bin, mode)
+    lowered = hist_active_pallas.lower(
+        *_hist_args(s, N, mode, slots), s(shape, dtype),
+        num_features=F, max_bins=max_bin, mode=mode, raw=True)
+    if hist_fold_cell_ok(max_bin, slots, mode):
+        assert "tpu_custom_call" in _compiled_text(lowered)
+    else:
+        with pytest.raises(Exception, match="vmem"):
+            lowered.compile()
+
+
+@pytest.mark.parametrize("mode", ["hilo", "int8h"])
+def test_fused_hist_route_kernel_compiles(one_chip, mode):
+    from lightgbm_tpu.ops.pallas_histogram import hist_route_pallas
+    s = _shapes(one_chip)
+    bins_t, vals, _, active, scales = _hist_args(s, N, mode, 32)
+    text = _compiled_text(hist_route_pallas.lower(
+        bins_t, vals, s((2, N), jnp.int32), active, *_split_tables(s),
+        scales, num_features=F, max_bins=MAX_BIN, mode=mode,
+        any_cat=False))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("values", [False, True], ids=["route", "values"])
+def test_route_kernel_compiles(one_chip, values):
+    from lightgbm_tpu.ops.pallas_route import (route_rows_pallas,
+                                               route_rows_values_pallas)
+    s = _shapes(one_chip)
+    args = [s((F, N), jnp.uint8), s((2, N), jnp.int32), *_split_tables(s)]
+    if values:
+        lowered = route_rows_values_pallas.lower(
+            *args, s((LEAVES,), jnp.float32), any_cat=False)
+    else:
+        lowered = route_rows_pallas.lower(*args, any_cat=False)
+    assert "tpu_custom_call" in _compiled_text(lowered)
+
+
+# ---------------------------------------------------------------------------
+# split kernel: the HIGGS-255 width and the caps the static gate admits
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("features,stride,leaf_tile", [
+    (28, 256, 16),          # 28 features x 256-bin stride
+    (64, 256, 8),           # F*B = 16384 = SPLIT_MAX_LANES, min leaf tile
+    (42, 128, 32),          # the widest F*B the 32-leaf tile is given
+])
+def test_split_kernel_compiles_at_cap(one_chip, features, stride,
+                                      leaf_tile):
+    from lightgbm_tpu.ops import pallas_split as ps
+    slots = 256                         # 2A changed slots of a deep wave
+    assert ps.split_kernel_ok(features, stride, False, num_rows=1000)
+    assert ps._leaf_tile(slots, features * stride) == leaf_tile
+    s = _shapes(one_chip)
+    split = _growth().split
+    fn = jax.jit(lambda g, a, b, c, nb, mt, db: ps.find_best_splits_pallas(
+        g, a, b, c, nb, mt, db, B=stride, params=split, any_missing=True))
+    leaf = s((slots,), jnp.float32)
+    feat = s((features,), jnp.int32)
+    text = _compiled_text(fn.lower(
+        s((slots, features, stride, 3), jnp.float32), leaf, leaf, leaf,
+        feat, feat, feat))
+    assert "tpu_custom_call" in text
+
+
+# ---------------------------------------------------------------------------
+# whole tree programs: the default backend, serial and on four chips
+# ---------------------------------------------------------------------------
+def test_build_tree_default_backend_compiles(one_chip, on_tpu):
+    """`build_tree` as `lgb.train` traces it on a TPU: default backend
+    (compact), default hist mode, staged waves, fused + compacted +
+    route kernels in one program."""
+    from lightgbm_tpu.learner.serial import resolve_backend
+    s = _shapes(one_chip)
+    dd = _device_data(N_TREE, one_chip, one_chip)
+    assert resolve_backend(dd, LEAVES, hist_mode="int8h") == "compact"
+    growth = _growth()
+    fn = jax.jit(lambda dd, g, h, bins_t: build_tree(dd, g, h, growth,
+                                                     bins_t=bins_t))
+    text = _compiled_text(fn.lower(
+        dd, s((N_TREE,), jnp.float32), s((N_TREE,), jnp.float32),
+        s((F, N_TREE), jnp.uint8)))
+    assert text.count("tpu_custom_call") >= 3
+
+
+def test_build_tree_distributed_data_compiles(topo, on_tpu):
+    """tree_learner=data as one SPMD program over the four described
+    chips: rows sharded, kernels per shard, the wave histograms
+    all-reduced."""
+    from lightgbm_tpu.parallel.learners import build_tree_distributed
+    mesh = Mesh(np.asarray(topo.devices), ("data",))
+    rows = NamedSharding(mesh, P("data"))
+    dd = _device_data(N_TREE, rows, NamedSharding(mesh, P()))
+    growth = _growth()
+    fn = jax.jit(lambda dd, g, h: build_tree_distributed(
+        mesh, "data", "data", dd, g, h, growth))
+    grad = jax.ShapeDtypeStruct((N_TREE,), jnp.float32, sharding=rows)
+    compiled = fn.lower(dd, grad, grad).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "all-reduce" in text
+    # rows are sharded: each device holds a quarter of the bins
+    per_device = compiled.memory_analysis().argument_size_in_bytes
+    assert per_device < N_TREE * F // 2

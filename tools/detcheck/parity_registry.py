@@ -229,8 +229,6 @@ EXEMPT_ENV: Dict[str, str] = {
                                 "independent",
     "LGBM_TPU_NO_NATIVE": "parser backend (native C++ vs python); "
                           "parse parity pinned by tests/test_native_parser.py",
-    "LGBM_TPU_COMPILE_CACHE": "persistent compile cache on/off; cached "
-                              "executables are content-addressed",
     "LGBM_TPU_RETRY_ATTEMPTS": "retry policy knob",
     "LGBM_TPU_RETRY_BASE_S": "retry policy knob",
     "LGBM_TPU_RETRY_MAX_S": "retry policy knob",

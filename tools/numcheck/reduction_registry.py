@@ -46,6 +46,11 @@ REDUCERS = (
      "module": "lightgbm_tpu/learner/serial.py",
      "why": "composition of the two canonical stages (the PR 14 "
             "retrofit that replaced the raw jnp.sum)"},
+    {"name": "root_code_sums",
+     "module": "lightgbm_tpu/learner/serial.py",
+     "why": "root totals of the quantized modes: int32 sums of int8 "
+            "codes (<= n*127 < 2^31 under effective_hist_mode's row "
+            "bound) are exact, so order-free and additive over blocks"},
 )
 
 # -- partition-independent contexts ----------------------------------------

@@ -30,7 +30,8 @@ backed which).  This tool mechanizes both:
 * **README figure provenance** (``--check-readme``) — every throughput
   or ratio figure inside the README's fenced measured-run blocks must
   either carry an explicit not-captured marker (``no citable``,
-  ``pending``, ``artifact lost``, ``projected``) or name its source
+  ``pending``, ``artifact lost``, ``projected``, ``not measured``) or
+  name its source
   round (``BENCH_rNN``) — and the named artifact must actually contain
   a number within 15% of the claim.  This is the ratio-figure
   complement of tpulint's TPL008 (which can only check absolute
@@ -75,7 +76,7 @@ _RATIO_RE = re.compile(r"(\d+(?:\.\d+)?)x\b")
 _MFIG_RE = re.compile(r"(\d+(?:\.\d+)?)\s*M\s+(?:row|doc)-iters/s")
 _ROUND_RE = re.compile(r"BENCH_r(\d+)")
 UNCAPTURED_MARKERS = ("no citable", "pending", "artifact lost",
-                      "projected", "uncaptured")
+                      "projected", "uncaptured", "not measured")
 FIGURE_TOLERANCE = 0.15
 
 

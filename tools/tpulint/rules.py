@@ -371,7 +371,7 @@ _BROAD = {"Exception", "BaseException"}
 _HANDLED_CALLS = {
     "log_warning", "log_once", "log_info", "log_error", "log_debug",
     "warn", "warning", "error", "exception", "event", "counter_add",
-    "disable_on_compile_error", "fail", "perror", "print_exc",
+    "fail", "perror", "print_exc",
 }
 
 
