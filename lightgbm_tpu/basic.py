@@ -69,6 +69,14 @@ class Dataset:
     def construct(self) -> "Dataset":
         if self._constructed is not None:
             return self
+        from .obs import span
+        with span("io.construct") as sp:
+            self._construct()
+            ds = self._constructed
+            sp["rows"], sp["features"] = ds.num_data, ds.num_total_features
+        return self
+
+    def _construct(self) -> None:
         if self.reference is not None:
             ref = self.reference.construct()._constructed
         else:
@@ -98,7 +106,7 @@ class Dataset:
                 pass
             self._constructed = ds
             self._apply_fields()
-            return self
+            return
         X, pd_info = _data_to_numpy(self.data)
         cat = []
         names = None
@@ -119,7 +127,6 @@ class Dataset:
         self._apply_fields()
         if self.free_raw_data:
             self.data = None
-        return self
 
     def _apply_fields(self):
         md = self._constructed.metadata

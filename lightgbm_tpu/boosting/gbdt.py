@@ -296,18 +296,21 @@ class GBDT:
                 log_warning(f"tree_learner={c.tree_learner} requested but "
                             f"only one device is visible and no mesh_shape "
                             f"is set: training SERIALLY on that device")
-        if self._pr is not None:
-            self.device_data = self._to_device_multiproc(train_set)
-        elif self._row_pad:
-            padded = BinnedDataset.__new__(BinnedDataset)
-            padded.__dict__.update(train_set.__dict__)
-            padded.bins = np.concatenate(
-                [train_set.bins,
-                 np.zeros((self._row_pad, train_set.bins.shape[1]),
-                          train_set.bins.dtype)])
-            self.device_data = to_device(padded)
-        else:
-            self.device_data = to_device(train_set)
+        # host side of the placement (the copy itself is asynchronous)
+        with obs_span("gbdt.upload", rows=n,
+                      features=train_set.bins.shape[1]):
+            if self._pr is not None:
+                self.device_data = self._to_device_multiproc(train_set)
+            elif self._row_pad:
+                padded = BinnedDataset.__new__(BinnedDataset)
+                padded.__dict__.update(train_set.__dict__)
+                padded.bins = np.concatenate(
+                    [train_set.bins,
+                     np.zeros((self._row_pad, train_set.bins.shape[1]),
+                              train_set.bins.dtype)])
+                self.device_data = to_device(padded)
+            else:
+                self.device_data = to_device(train_set)
         self.feature_names = train_set.feature_names
         self.max_feature_idx = train_set.num_total_features - 1
         if self.objective is None and c.objective != "none":
@@ -404,8 +407,8 @@ class GBDT:
             # config hist_mode wins; env var / bf16 default otherwise
             # (the gpu_use_dp analog — ADVICE r2)
             from ..learner.serial import effective_hist_mode
-            hist_mode = effective_hist_mode(
-                c.hist_mode or default_hist_mode(), self.num_data)
+            asked_mode = c.hist_mode or default_hist_mode()
+            hist_mode = effective_hist_mode(asked_mode, self.num_data)
             self._bins_t = None
             backend = resolve_backend(self.device_data, growth.num_leaves,
                                       hist_mode=hist_mode)
@@ -418,22 +421,25 @@ class GBDT:
             from ..learner.serial import uses_pallas
             self._block_backend_ok = (jax.default_backend() != "tpu"
                                       or uses_pallas(backend))
-            self._record_backend(backend, hist_mode)
+            self._record_backend(backend, hist_mode, asked_mode)
             if uses_pallas(backend):
                 bins_host = (self.train_set.bins
                              if self.train_set is not None else None)
-                if (bins_host is not None
-                        and bins_host.shape[0] <= 1 << 20):
-                    # small data: transpose on host and pay a second
-                    # host->device copy instead of the jitted
-                    # transpose's one-time compile.  The 2^20-row
-                    # threshold is unverified on a local chip
-                    from ..ops.pallas_histogram import transpose_bins_host
-                    self._bins_t = jax.device_put(
-                        transpose_bins_host(bins_host))
-                else:
-                    self._bins_t = jax.jit(transpose_bins)(
-                        self.device_data.bins)
+                with obs_span("gbdt.upload", rows=self.num_data,
+                              features=self.device_data.bins.shape[1]):
+                    if (bins_host is not None
+                            and bins_host.shape[0] <= 1 << 20):
+                        # small data: transpose on host and pay a second
+                        # host->device copy instead of the jitted
+                        # transpose's one-time compile.  The 2^20-row
+                        # threshold is unverified on a local chip
+                        from ..ops.pallas_histogram import \
+                            transpose_bins_host
+                        self._bins_t = jax.device_put(
+                            transpose_bins_host(bins_host))
+                    else:
+                        self._bins_t = jax.jit(transpose_bins)(
+                            self.device_data.bins)
             from ..utils.timetag import phases_enabled
             if phases_enabled():
                 # LGBM_TPU_TIMETAG=phases: unfused per-phase-timed waves
@@ -515,13 +521,13 @@ class GBDT:
             from ..learner.serial import (default_hist_mode,
                                           effective_hist_mode,
                                           resolve_backend, uses_pallas)
-            mesh_hist_mode = effective_hist_mode(
-                dist_hist_mode or default_hist_mode(), self.num_data)
+            asked_mode = dist_hist_mode or default_hist_mode()
+            mesh_hist_mode = effective_hist_mode(asked_mode, self.num_data)
             mesh_backend = resolve_backend(
                 self.device_data, growth.num_leaves, hist_mode=mesh_hist_mode)
             self._block_backend_ok = (jax.default_backend() != "tpu"
                                       or uses_pallas(mesh_backend))
-            self._record_backend(mesh_backend, mesh_hist_mode)
+            self._record_backend(mesh_backend, mesh_hist_mode, asked_mode)
         # serial path: already jitted at module level (shared cache);
         # mesh path: per-instance jit (mesh/axis closed over), with
         # grad/hess donated — they die with the build (every caller
@@ -569,15 +575,24 @@ class GBDT:
         self._block_cap = max(1, int(_os.environ.get("LGBM_TPU_BLOCK_CAP",
                                                      self._BLOCK_CAP)))
 
-    def _record_backend(self, backend: str, hist_mode: str) -> None:
+    def _record_backend(self, backend: str, hist_mode: str,
+                        asked_mode: str) -> None:
         """The RESOLVED histogram backend and accumulation mode of the
         build program, on the instance and in the run summary's gauges
-        — what a run on the chip checks to know which kernels it ran."""
+        — what a run on the chip checks to know which kernels it ran.
+        Where the mode that runs is not the one asked for
+        (``effective_hist_mode``: a quantized mode past the exact-int32
+        row bound), the summary says so: gauge
+        ``gbdt.hist_mode_requested`` and event ``degrade:hist_mode``."""
         from ..obs import gauge_set
         self.hist_backend = backend
         self.hist_mode = hist_mode
         gauge_set("gbdt.hist_backend", backend)
         gauge_set("gbdt.hist_mode", hist_mode)
+        if hist_mode != asked_mode:
+            gauge_set("gbdt.hist_mode_requested", asked_mode)
+            obs_event("degrade", "hist_mode", requested=asked_mode,
+                      effective=hist_mode, rows=int(self.num_data))
 
     def _setup_metrics(self) -> None:
         c = self.config
@@ -1215,11 +1230,16 @@ class GBDT:
                 scores, vscores = carry
                 active = it - it0 < n_active
                 scores_in, vscores_in = scores, vscores
-                if K == 1:
-                    g, h = obj.get_gradients(scores[:, 0])
-                    G, H = g[:, None], h[:, None]
-                else:
-                    G, H = obj.get_gradients(scores)
+                # the scopes of this body and of the tree build carry
+                # the span names of the unfused path: they are metadata
+                # on the operations (a device trace names them), and
+                # nothing in the compiled program
+                with jax.named_scope("obj.grad"):
+                    if K == 1:
+                        g, h = obj.get_gradients(scores[:, 0])
+                        G, H = g[:, None], h[:, None]
+                    else:
+                        G, H = obj.get_gradients(scores)
                 # sampling derived on device, pure in iteration — the
                 # same functions the per-iteration path uses, so bagged
                 # (and GOSS: _block_sample override) configs stay on
@@ -1262,17 +1282,18 @@ class GBDT:
                         # identical last-ulp rounding in any fusion
                         # context
                         bt = jax.lax.optimization_barrier(bt)
-                        lv_s = lr * bt.leaf_value            # [L]
-                        scores = scores.at[:, k].add(
-                            lv_s[bt.row_leaf[:scores.shape[0]]])
-                        bts = bt._replace(leaf_value=lv_s)
-                        vscores = tuple(
-                            vs.at[:, k].add(
-                                predict_built_tree(bts, vd, vd.bins)
-                                if vd.has_categorical else
-                                predict_built_tree_matmul(bts, vd,
-                                                          vd.bins))
-                            for vs, vd in zip(vscores, vds))
+                        with jax.named_scope("gbdt.score_update"):
+                            lv_s = lr * bt.leaf_value            # [L]
+                            scores = scores.at[:, k].add(
+                                lv_s[bt.row_leaf[:scores.shape[0]]])
+                            bts = bt._replace(leaf_value=lv_s)
+                            vscores = tuple(
+                                vs.at[:, k].add(
+                                    predict_built_tree(bts, vd, vd.bins)
+                                    if vd.has_categorical else
+                                    predict_built_tree_matmul(bts, vd,
+                                                              vd.bins))
+                                for vs, vd in zip(vscores, vds))
                     else:
                         # serial branch fenced like the mesh branch
                         # since the out-of-core round: the barrier
@@ -1284,27 +1305,29 @@ class GBDT:
                         # (boosting/streaming.py) reproduce the same
                         # last-ulp rounding in any fusion context
                         bt = jax.lax.optimization_barrier(bt)
-                        lv_s = lr * bt.leaf_value            # [L]
-                        if bt.row_value.shape[0]:
-                            # emitted by the final route kernel (already
-                            # stump-masked); avoids the 1M-row gather
-                            scores = scores.at[:, k].add(
-                                lr * bt.row_value)
-                        else:
-                            scores = scores.at[:, k].add(
-                                lv_s[bt.row_leaf])
-                        # valid-set scoring per tree, on device: the
-                        # path-agreement matmul (MXU) for numerical
-                        # valid sets, the node walk where categorical
-                        # splits need the bitset decision
-                        bts = bt._replace(leaf_value=lv_s)
-                        vscores = tuple(
-                            vs.at[:, k].add(
-                                predict_built_tree(bts, vd, vd.bins)
-                                if vd.has_categorical else
-                                predict_built_tree_matmul(bts, vd,
-                                                          vd.bins))
-                            for vs, vd in zip(vscores, vds))
+                        with jax.named_scope("gbdt.score_update"):
+                            lv_s = lr * bt.leaf_value            # [L]
+                            if bt.row_value.shape[0]:
+                                # emitted by the final route kernel
+                                # (already stump-masked); avoids the
+                                # 1M-row gather
+                                scores = scores.at[:, k].add(
+                                    lr * bt.row_value)
+                            else:
+                                scores = scores.at[:, k].add(
+                                    lv_s[bt.row_leaf])
+                            # valid-set scoring per tree, on device: the
+                            # path-agreement matmul (MXU) for numerical
+                            # valid sets, the node walk where categorical
+                            # splits need the bitset decision
+                            bts = bt._replace(leaf_value=lv_s)
+                            vscores = tuple(
+                                vs.at[:, k].add(
+                                    predict_built_tree(bts, vd, vd.bins)
+                                    if vd.has_categorical else
+                                    predict_built_tree_matmul(bts, vd,
+                                                              vd.bins))
+                                for vs, vd in zip(vscores, vds))
                     outs.append(bt._replace(row_leaf=bt.row_leaf[:0],
                                             row_value=bt.row_value[:0]))
                 stacked = (outs[0] if K == 1 else
@@ -1662,12 +1685,16 @@ class GBDT:
 
     # -- dispatch-gap accounting (ROADMAP item 1) -----------------------
     def _gap_dispatch_start(self) -> None:
-        """Called right before a training dispatch: the time since the
-        PREVIOUS dispatch returned is host gap — objective/bookkeeping
-        work the device spends idle waiting on.  Summed into the
-        ``gbdt.dispatch_gap_s`` counter (mean gauge at end of train),
-        so the per-iteration host-latency signal exists on every
-        telemetry run, not just profiled ones."""
+        """Called right before a training dispatch: the host time since
+        the PREVIOUS dispatch returned, summed into the
+        ``gbdt.dispatch_gap_s`` counter (mean gauge at end of train).
+        NOT device idle time: a dispatch returns as soon as the block
+        is enqueued, so wherever the caller waits on the result between
+        blocks (the stump check at the end of ``train_block``, a
+        ``block_until_ready``, an eval) the gap holds the device's whole
+        run time of the block.  On the chip it read 2.22 s a gap beside
+        14 ms of idle in the same two steps' trace (PERF.md, PR 26);
+        idle time is read from a profiler trace."""
         from ..obs import enabled as obs_enabled
         t = self._t_dispatch_ret
         if t is not None and obs_enabled():

@@ -23,6 +23,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ..config import Config
+from ..obs import span as obs_span
 from ..utils.log import log_info, log_warning, check
 from .binning import (BIN_CATEGORICAL, BIN_NUMERICAL, MISSING_NAN,
                       MISSING_NONE, MISSING_ZERO, BinMapper)
@@ -367,10 +368,11 @@ class BinnedDataset:
             # prediction mode: unbundled columns + sentinel categorical
             # miss bins (raw-value CategoricalDecision semantics)
             ds.bundle = None if prediction_mode else reference.bundle
-            cols = []
-            for f in ds.used_features:
-                cols.append(ds.mappers[f].value_to_bin(
-                    X[:, f], prediction_mode=prediction_mode))
+            with obs_span("io.value_to_bin", rows=n,
+                          features=len(ds.used_features)):
+                cols = [ds.mappers[f].value_to_bin(
+                    X[:, f], prediction_mode=prediction_mode)
+                    for f in ds.used_features]
             if ds.bundle is not None and ds.bundle.is_bundled:
                 ds.bins = pack_group_columns(cols, ds.feature_info, ds.bundle)
             else:
@@ -438,8 +440,10 @@ class BinnedDataset:
             log_warning("all features are trivial (constant); nothing to train on")
         # 3. bin every row (vectorized per column)
         if cols is None:
-            cols = [mappers[f].value_to_bin(X[:, f])
-                    for f in ds.used_features]
+            with obs_span("io.value_to_bin", rows=n,
+                          features=len(ds.used_features)):
+                cols = [mappers[f].value_to_bin(X[:, f])
+                        for f in ds.used_features]
         ds.feature_info = cls._build_feature_info(
             [mappers[f] for f in ds.used_features])
         # 4. EFB: bundle sufficiently sparse features into shared columns

@@ -17,13 +17,13 @@ machine / stall watchdog / numerics sentinels (``obs/health.py``):
 ``from ..obs import health, ops_plane``.
 """
 from .telemetry import (counter_add, disable, enable, enabled, event,
-                        gauge_set, merged_summary, reset, set_annotator,
-                        set_clock_offset, set_rank, set_section, set_sink,
+                        gauge_set, merged_summary, reset, set_clock_offset,
+                        set_rank, set_section, set_sink,
                         span, summary, trace_path, write_summary)
 
 __all__ = [
     "enabled", "enable", "disable", "reset", "span", "counter_add",
     "gauge_set", "event", "summary", "merged_summary", "write_summary",
-    "trace_path", "set_section", "set_annotator", "set_sink",
+    "trace_path", "set_section", "set_sink",
     "set_clock_offset", "set_rank",
 ]

@@ -13,13 +13,11 @@ where the DEVICE time goes, per phase.  This module is that layer.
   ``LGBM_TPU_PROFILE_WINDOWS`` windows of ``LGBM_TPU_PROFILE_ITERS``
   iterations each, so the trace stays bounded inside bench runs),
   then stops, parses, and drops the result into the telemetry summary
-  as the ``device_attribution`` section.  While a capture is live,
-  every telemetry span additionally emits a
-  ``jax.profiler.TraceAnnotation`` with the same name (installed via
-  :func:`telemetry.set_annotator` — one module-attribute read per span
-  when inactive), so XLA ops attribute to the existing span tree
-  without a second instrumentation pass.  Works on the CPU backend —
-  tier-1 gates the whole pipeline without TPU hardware.
+  as the ``device_attribution`` section.  Every telemetry span enters
+  a ``jax.profiler.TraceAnnotation`` of its own name
+  (``obs/telemetry.py``), so XLA ops attribute to the existing span
+  tree without a second instrumentation pass.  Works on the CPU
+  backend — tier-1 gates the whole pipeline without TPU hardware.
 
 * **Parse** — :func:`parse_capture` reads the profiler's chrome-trace
   JSON (``plugins/profile/<ts>/*.trace.json.gz``; stdlib only) and
@@ -112,11 +110,6 @@ _active_dir: Optional[str] = None
 _program_costs: Dict[str, Dict[str, Any]] = {}
 
 
-def _annotate(name: str):
-    import jax
-    return jax.profiler.TraceAnnotation(name)
-
-
 class _NoopCtx:
     __slots__ = ()
 
@@ -141,9 +134,9 @@ def step(name: str, num: int):
 
 
 def _start_capture(out_dir: str) -> bool:
-    """Start the global jax profiler into ``out_dir``; install the span
-    annotator.  Returns False (and logs once) when the profiler cannot
-    start — the caller degrades to no capture."""
+    """Start the global jax profiler into ``out_dir``.  Returns False
+    (and logs once) when the profiler cannot start — the caller
+    degrades to no capture."""
     global _active_dir
     if _active_dir is not None:
         return False                    # one capture at a time
@@ -163,7 +156,6 @@ def _start_capture(out_dir: str) -> bool:
                  f"continuing unprofiled", level="warning")
         return False
     _active_dir = out_dir
-    telemetry.set_annotator(_annotate)
     return True
 
 
@@ -173,7 +165,6 @@ def _stop_capture(sync=None) -> Optional[str]:
     Returns the capture dir, or None when nothing was live."""
     global _active_dir
     out, _active_dir = _active_dir, None
-    telemetry.set_annotator(None)
     if out is None:
         return None
     if sync is not None:

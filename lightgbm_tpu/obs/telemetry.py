@@ -13,7 +13,11 @@ subsystem:
   Host-side wall-clock only, nestable (thread-local stack), NO implicit
   device syncs: a span around an async JAX dispatch times the host cost
   of that dispatch; callers that want device time must block first (the
-  jit-adjacent block-loop boundaries already do).
+  jit-adjacent block-loop boundaries already do).  Every span also
+  enters a ``jax.profiler.TraceAnnotation`` of its name, so a profiler
+  trace, whoever started it, holds the spans on the device's clock
+  (device time is read there, by ``jax.named_scope`` name: the block
+  program's scopes carry the ``tree.*`` / ``obj.*`` span names).
 * **Counters / gauges** — ``counter_add("retry.dispatch.retries")``,
   ``gauge_set("hbm_bytes", n)``.  Counters accumulate (floats allowed:
   backoff seconds ride the same channel), gauges overwrite.
@@ -53,7 +57,7 @@ from typing import Any, Dict, IO, Optional
 __all__ = [
     "enabled", "enable", "disable", "reset", "span", "counter_add",
     "gauge_set", "event", "summary", "merged_summary", "write_summary",
-    "trace_path", "set_section", "set_annotator", "set_sink",
+    "trace_path", "set_section", "set_sink",
     "set_clock_offset", "set_rank",
 ]
 
@@ -81,11 +85,6 @@ _events: Dict[str, int] = {}
 # stored even while telemetry is disabled — a contract check the user
 # explicitly enabled must not vanish because tracing is off
 _sections: Dict[str, Any] = {}
-# span annotator hook (obs/profiler.py): while a device-time capture
-# is live, every span ALSO enters a jax.profiler.TraceAnnotation of
-# the same name, so XLA ops attribute to the span tree.  None (the
-# default) costs one module-attribute read per span
-_annotator = None
 # live-metrics sink (obs/ops_plane.py MetricsRegistry): while the ops
 # plane is mounted, every counter/gauge/event update and span close is
 # mirrored into the scrapeable registry.  None (the default) costs one
@@ -117,13 +116,6 @@ def set_rank(rank: int, world: int) -> None:
     coordinator, not from jax.distributed."""
     global _rank_override
     _rank_override = (int(rank), max(int(world), 1))
-
-
-def set_annotator(fn) -> None:
-    """Install/remove the per-span annotation factory (``fn(name)`` ->
-    context manager).  Owned by ``obs/profiler.py``."""
-    global _annotator
-    _annotator = fn
 
 
 def set_sink(sink) -> None:
@@ -200,12 +192,11 @@ def reset() -> None:
     """Clear the run summary and forget any requested trace (tests).
     Also rewinds the collective flight recorder — a fresh run must not
     inherit the previous run's schedule digest."""
-    global _trace_requested, _held, _annotator, _clk_off, _rank_override
+    global _trace_requested, _held, _clk_off, _rank_override
     with _lock:
         disable()
         _trace_requested = None
         _held = None
-        _annotator = None
         _clk_off = None
         _rank_override = None
         _spans.clear()
@@ -341,17 +332,21 @@ class _Span:
             stack = _tls.stack = []
         self.depth = len(stack)
         stack.append(self.name)
-        ann = _annotator
-        if ann is not None:
+        # the span on the profiler's clock, whoever started the trace:
+        # a TraceAnnotation costs tens of nanoseconds while no profiler
+        # session is live.  A process that never imported jax has no
+        # session to annotate
+        self.ann = None
+        jx = sys.modules.get("jax")
+        if jx is not None:
             try:
-                self.ann = ann(self.name)
-                self.ann.__enter__()
+                ann = jx.profiler.TraceAnnotation(self.name)
+                ann.__enter__()
+                self.ann = ann
             # tpulint: disable=TPL006 -- annotation is best-effort; a
             # profiler hiccup must not take the training span down
             except Exception:           # noqa: BLE001
-                self.ann = None
-        else:
-            self.ann = None
+                pass
         self.ts = time.time()
         self.t0 = time.perf_counter()
         return self.attrs

@@ -276,23 +276,29 @@ def hist_active_compact(bins_t: jnp.ndarray,
     assert n_pad % T == 0, (n_pad, T)
     pad_cols = cols - C * Gp
 
-    src, tile_group, group_active = compact_plan(
-        row_leaf.astype(jnp.int32), active.astype(jnp.int32),
-        num_leaf_slots, T)
-    sc = jnp.maximum(src, 0)
-    # the regroup gather: one pass over the bins/value streams applies
-    # the leaf-contiguous permutation (the DataPartition::Split +
-    # ordered-gradients analog in one shot)
-    bins_c = jnp.take(bins_t, sc, axis=1)            # [F_pad, n_c]
-    vals_c = jnp.take(vals, sc, axis=1)              # [C, n_c]
-    leaf_c = jnp.where(src >= 0, row_leaf.astype(jnp.int32)[sc],
-                       -1)[None, :]                  # [1, n_c]
-
+    # the plan's index arithmetic and the regroup of the data by it are
+    # named for the device trace.  The kernel and its unpack stay bare:
+    # the TPU compiler names a custom call after the scope right around
+    # it (here the jitted wrapper, which the benchmark's class `hist`
+    # matches), so their scope, tree.hist, is the caller's
+    with jax.named_scope("tree.compact.plan"):
+        src, tile_group, group_active = compact_plan(
+            row_leaf.astype(jnp.int32), active.astype(jnp.int32),
+            num_leaf_slots, T)
     # feature tiling: identical VMEM model to the wide kernel, at the
     # group column count
     feat_tile, F_grid = feat_tiling(F_pad, B, cols, T, C, seeded)
-    if F_grid != F_pad:
-        bins_c = jnp.pad(bins_c, ((0, F_grid - F_pad), (0, 0)))
+    with jax.named_scope("tree.compact.regroup"):
+        sc = jnp.maximum(src, 0)
+        # the regroup gather: one pass over the bins/value streams
+        # applies the leaf-contiguous permutation (the
+        # DataPartition::Split + ordered-gradients analog in one shot)
+        bins_c = jnp.take(bins_t, sc, axis=1)            # [F_pad, n_c]
+        vals_c = jnp.take(vals, sc, axis=1)              # [C, n_c]
+        leaf_c = jnp.where(src >= 0, row_leaf.astype(jnp.int32)[sc],
+                           -1)[None, :]                  # [1, n_c]
+        if F_grid != F_pad:
+            bins_c = jnp.pad(bins_c, ((0, F_grid - F_pad), (0, 0)))
     nft = F_grid // feat_tile
     n_c = bins_c.shape[1]
 

@@ -1,0 +1,200 @@
+"""The names the program puts where the work happens (ISSUE 26).
+
+Device half: ``jax.named_scope`` inside the fused block program, under
+the span names of the unfused path.  They are metadata on the
+operations: the lowered text carries each, and the compiled program,
+metadata stripped, is the same with them and without.  Host half: every
+telemetry span enters a ``jax.profiler.TraceAnnotation``, so a profiler
+trace that anybody started holds the program's spans; ingest and upload
+have spans of their own; a histogram mode the program replaced shows in
+the summary.
+"""
+import contextlib
+import glob
+import json
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import lightgbm_tpu as lgb
+from lightgbm_tpu import obs
+
+SCOPES = ["obj.grad", "tree.pack", "tree.init", "tree.route", "tree.hist",
+          "tree.compact.plan", "tree.compact.regroup", "tree.split_find",
+          "tree.update", "gbdt.score_update"]
+
+
+@pytest.fixture(autouse=True)
+def _clean_obs():
+    obs.reset()
+    yield
+    obs.reset()
+
+
+def _data(n=3000, f=6, seed=0):
+    rng = np.random.RandomState(seed)
+    X = rng.normal(size=(n, f)).astype(np.float32)
+    y = (X[:, 0] + X[:, 1] * X[:, 2] > 0).astype(np.float32)
+    return X, y
+
+
+def _lower_block():
+    """The length-1 block program of a booster on the kernel path
+    (interpreted off-TPU), 80 leaves so that its waves are compacted."""
+    X, y = _data()
+    params = {"objective": "binary", "num_leaves": 80, "max_bin": 15,
+              "min_data_in_leaf": 2, "verbose": -1}
+    ds = lgb.Dataset(X, label=y, params={"max_bin": 15})
+    g = lgb.Booster(params=params, train_set=ds)._gbdt
+    assert g.hist_backend == "compact"
+    return g._make_block_fn(1).lower(
+        g.device_data, g._bins_t, tuple(g._valid_device), g.scores,
+        tuple(g._valid_scores), jnp.float32(0.1), jnp.int32(0),
+        jnp.int32(1))
+
+
+@pytest.fixture(scope="module")
+def lowered():
+    """``(with the scopes, with jax.named_scope a no-op)``."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("LGBM_TPU_HIST_BACKEND", "compact")
+        jax.clear_caches()
+        scoped = _lower_block()
+        jax.clear_caches()              # the jitted wrappers' traces too
+        mp.setattr(jax, "named_scope",
+                   lambda name: contextlib.nullcontext())
+        plain = _lower_block()
+    jax.clear_caches()
+    return scoped, plain
+
+
+@pytest.mark.parametrize("scope", SCOPES)
+def test_block_program_names_its_parts(lowered, scope):
+    scoped, plain = lowered
+    pattern = r'[/"]' + re.escape(scope) + r'[/"]'
+    assert re.search(pattern, scoped.as_text(debug_info=True))
+    assert not re.search(pattern, plain.as_text(debug_info=True))
+
+
+def test_scopes_leave_the_compiled_block_as_it_was(lowered):
+    def stripped(low):
+        return re.sub(r", metadata=\{[^}]*\}", "", low.compile().as_text())
+    assert "tree.compact.plan" in lowered[0].compile().as_text()
+    scoped, plain = (stripped(low) for low in lowered)
+    assert "tree.compact.plan" not in scoped and "metadata=" not in scoped
+    assert scoped == plain
+
+
+def _host_events(trace_dir) -> set:
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    return {e.name for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events}
+
+
+@pytest.mark.parametrize("enabled", [True, False])
+def test_spans_reach_a_trace_somebody_else_started(tmp_path, enabled):
+    if enabled:
+        obs.enable()
+    X, y = _data(400, 5)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        bst = lgb.train({"objective": "binary", "verbose": -1},
+                        lgb.Dataset(X, label=y), num_boost_round=2,
+                        keep_training_booster=True)
+        bst._gbdt.train(2)              # the cached program: gbdt.block
+        jax.block_until_ready(bst._gbdt.scores)
+    finally:
+        jax.profiler.stop_trace()
+    ours = {n for n in _host_events(str(tmp_path))
+            if n.startswith(("gbdt.", "io.", "engine."))}
+    if enabled:
+        assert {"gbdt.block", "gbdt.block_compile", "gbdt.train",
+                "io.construct", "io.value_to_bin", "gbdt.upload"} <= ours
+    else:
+        assert not ours
+
+
+def test_ingest_and_upload_have_one_span_each():
+    obs.enable()
+    X, y = _data(500, 7)
+    lgb.train({"objective": "binary", "verbose": -1},
+              lgb.Dataset(X, label=y), num_boost_round=2)
+    spans = obs.summary()["spans"]
+    assert spans["io.find_bin"]["count"] == 7          # one a feature
+    for name in ("io.construct", "io.value_to_bin", "gbdt.upload"):
+        assert spans[name]["count"] == 1, name
+    assert spans["io.value_to_bin"]["total_s"] <= \
+        spans["io.construct"]["total_s"]
+
+
+def test_span_attributes_carry_rows_and_features(tmp_path):
+    path = tmp_path / "trace.jsonl"
+    obs.enable(trace_path=str(path))
+    X, y = _data(500, 7)
+    lgb.train({"objective": "binary", "verbose": -1},
+              lgb.Dataset(X, label=y), num_boost_round=1)
+    obs.disable()
+    recs = [json.loads(line) for line in path.read_text().splitlines()]
+    for name in ("io.construct", "io.value_to_bin", "gbdt.upload"):
+        rec = next(r for r in recs if r["name"] == name)
+        assert (rec["rows"], rec["features"]) == (500, 7), name
+
+
+@pytest.mark.parametrize("degraded", [True, False])
+def test_a_replaced_hist_mode_shows_in_the_summary(monkeypatch, degraded):
+    from lightgbm_tpu.learner import serial
+    if degraded:
+        monkeypatch.setattr(serial, "_INT8_ROW_LIMIT", 100)
+    obs.enable()
+    X, y = _data(500, 5)
+    bst = lgb.Booster(params={"objective": "binary", "hist_mode": "int8h",
+                              "verbose": -1},
+                      train_set=lgb.Dataset(X, label=y))
+    s = obs.summary()
+    if degraded:
+        assert bst._gbdt.hist_mode == "hhilo"
+        assert s["gauges"]["gbdt.hist_mode"] == "hhilo"
+        assert s["gauges"]["gbdt.hist_mode_requested"] == "int8h"
+        assert s["events"]["degrade:hist_mode"] == 1
+    else:
+        assert s["gauges"]["gbdt.hist_mode"] == "int8h"
+        assert "gbdt.hist_mode_requested" not in s["gauges"]
+        assert "degrade:hist_mode" not in s["events"]
+
+
+def test_no_scope_stands_between_a_kernel_and_its_jitted_wrapper():
+    """The TPU compiler names a custom call after the name-stack
+    component right around it: ``jit(hist_active_compact)`` gives
+    ``%hist_active_compact.N``, which the benchmark's class ``hist``
+    matches.  A scope inside the wrapper around the ``pallas_call`` would
+    rename the kernel (it did: ``%tree.hist.N``), so the kernel's scope
+    is the caller's."""
+    from lightgbm_tpu.ops import compact
+    from lightgbm_tpu.ops.pallas_histogram import (pack_values_q,
+                                                   transpose_bins)
+    n, F = 2048, 4
+    rng = np.random.RandomState(0)
+    bins_t = transpose_bins(jnp.asarray(rng.randint(0, 15, size=(n, F)),
+                                        jnp.uint8))
+    vals, scales = pack_values_q(jnp.asarray(rng.normal(size=n), jnp.float32),
+                                 jnp.ones(n, jnp.float32), "int8h")
+    leaf = jnp.asarray(rng.randint(0, 40, size=bins_t.shape[1]), jnp.int32)
+    jaxpr = jax.make_jaxpr(lambda *a: compact.hist_active_compact(
+        *a, num_features=F, max_bins=15, num_leaf_slots=80, mode="int8h",
+        interpret=True))(bins_t, vals, leaf, jnp.arange(40, dtype=jnp.int32),
+                         scales)
+    (wrapper,) = jaxpr.jaxpr.eqns
+    stacks = {e.primitive.name: str(e.source_info.name_stack)
+              for e in wrapper.params["jaxpr"].jaxpr.eqns}
+    assert stacks["pallas_call"] == ""
+    assert {"tree.compact.plan", "tree.compact.regroup"} <= set(
+        stacks.values())
