@@ -189,8 +189,8 @@ def phase_train(jax, lgb, obs, X, y):
     backend = after["gauges"].get("gbdt.hist_backend")
     mode = after["gauges"].get("gbdt.hist_mode")
     say(f"resolved backend: {backend}  hist mode: {mode}")
-    check(backend == "compact" and g.hist_backend == "compact",
-          f"resolved histogram backend is {backend!r}, not 'compact'")
+    check(backend == "pallas" and g.hist_backend == "pallas",
+          f"resolved histogram backend is {backend!r}, not 'pallas'")
     blocks = sum(span_count(after, k) - span_count(before, k)
                  for k in ("gbdt.block", "gbdt.block_compile"))
     iters = (span_count(after, "gbdt.iteration")
@@ -319,7 +319,7 @@ def phase_reference(lgb, obs, X, y, full_width_leaf: float):
         say(f"reference, {name}: resolved {bst._gbdt.hist_backend}, "
             f"{REF_ROWS} rows x {REF_ITERS} iterations in {t:.2f} s "
             f"(compile included), train AUC {auc:.5f}")
-    check(out["default"][0] == "compact",
+    check(out["default"][0] == "pallas",
           f"default-backend reference resolved {out['default'][0]}")
     check(out["scatter"][0] == "scatter",
           f"scatter reference resolved {out['scatter'][0]}")
@@ -440,7 +440,7 @@ def run_four_chips(jax, seed: int) -> None:
                 f"{time.perf_counter() - t0:.2f} s (compile included), "
                 f"backend {g.hist_backend}, hist mode {g.hist_mode}, train "
                 f"AUC {auc:.5f}")
-            check(g.hist_backend == "compact", f"backend {g.hist_backend}")
+            check(g.hist_backend == "pallas", f"backend {g.hist_backend}")
             if learner == "serial":
                 check(g.mesh_ctx is None, "serial run built a mesh")
             else:

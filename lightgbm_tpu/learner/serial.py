@@ -312,7 +312,8 @@ def _pallas_interpret() -> bool:
 def wave_uses_compact(backend: str, num_slots: int) -> bool:
     """THE per-wave dispatch predicate: a wave whose active-slot count
     exceeds the compaction threshold takes the leaf-compacted kernel on
-    the "compact" backend.  Slot counts are static per wave (stage_plan
+    the "compact" backend (asked for by name; "auto" is the wide kernel
+    in every wave).  Slot counts are static per wave (stage_plan
     unrolled stages + the fixed-width tail), so this resolves at trace
     time — shallow waves keep the wide (fused) kernel with zero runtime
     branching."""
@@ -320,7 +321,7 @@ def wave_uses_compact(backend: str, num_slots: int) -> bool:
     return backend == "compact" and num_slots > compact_slot_threshold()
 
 
-def wave_backend_plan(L: int, wave_size: int = 0, backend: str = "compact",
+def wave_backend_plan(L: int, wave_size: int = 0, backend: str = "pallas",
                       fused_ok: bool = True):
     """Per-wave kernel choice for a stage plan: ``-> (choices, tail)``
     with entries "compact" / "fused" / "<backend>".  Pure mirror of the
@@ -893,8 +894,9 @@ def build_tree(data: DeviceData,
                                  mode))
     fused_fn = (make_fused_fn(data, grad, hess, mode, bins_t)
                 if fused else None)
-    # the "compact" backend needs the strategy (route + compacted hist)
-    # for its deep waves even when the shallow waves run fused
+    # the "compact" backend (by name only; "auto" never resolves to it)
+    # needs the strategy (route + compacted hist) for its deep waves
+    # even when the shallow waves run fused
     if strategy is None and (not fused or backend == "compact"):
         strategy = make_serial_strategy(data, grad, hess, params,
                                         feature_mask, psum_fn=psum_fn,
@@ -917,11 +919,12 @@ def build_tree(data: DeviceData,
         # --- 0-3: apply last wave's pending splits to the rows, then
         # histogram the active leaves, subtract siblings, rescan.  The
         # fused kernel does the route inside the histogram's bins stream.
-        # stage_plan-aware dispatch: the wave's slot count is static, so
-        # deep waves (> compaction threshold on the "compact" backend)
-        # trace the route + leaf-compacted grouped kernel while shallow
-        # waves keep the wide fused kernel (wave_uses_compact — the same
-        # predicate make_hist_fn applies inside the strategy)
+        # Every wave of the default ("pallas") backend is a wide-kernel
+        # wave.  Asked for by name, "compact" sends its deep waves (slot
+        # count static, > compaction threshold) through the route + the
+        # leaf-compacted grouped kernel and keeps the wide fused kernel
+        # for the shallow ones (wave_uses_compact — the same predicate
+        # make_hist_fn applies inside the strategy)
         if fused and not wave_uses_compact(backend,
                                            s.act_small.shape[0]):
             new_h, leaf2 = fused_fn(s.leaf2, s.best, s.pend_sel,

@@ -1,16 +1,24 @@
 """Leaf-compacted deep-wave histograms — the TPU ``DataPartition`` analog.
 
-Why: per-row MXU work in the wide one-hot kernel
+NOT THE DEFAULT (PR 27): "auto" resolves to the wide kernel in every
+wave (``ops/pallas_histogram.py default_backend``).  This module is
+reachable by name only (``hist_backend="compact"`` /
+``LGBM_TPU_HIST_BACKEND=compact``) and stays, with its tests, until a
+row compaction that beats the wide deep waves rewrites it (ROADMAP
+S1(b)) or ROADMAP D1 deletes it.
+
+Why it exists: per-row MXU work in the wide one-hot kernel
 (`ops/pallas_histogram.py`) scales with ``cols = round128(C *
 round8(A))`` — every row is contracted against the value columns of ALL
 ``A`` active leaf slots even though it contributes to exactly one, and
-128-slot waves are the dominant regime of the reference's 255-leaf
-headline configs.  What that costs per row, and whether this module
-wins it back, is unverified on a local chip: the one comparison made
-there (PERF.md, PR 21, 1M x 28 x 63 bins x 255 leaves, int8h) ran the
-warm 32-iteration block in 8.14 s on this backend against 0.91 s with
-the wide kernel alone, so the plan below costs far more than the
-columns it saves at that shape.  The reference solves the same problem
+128-slot waves are the deepest two of the reference's 255-leaf headline
+configs.  Why it lost: on a v5e at 13.28M x 67 x 63 bins x 255 leaves,
+int8h (PERF.md, PR 27 and ledger PR 26), the plan and regroup below cost
+1,731 ms an iteration of XLA gathers, scatters and sorts over all rows
+(~65 ns a row and wave) to feed two grouped-kernel calls of 51 ms each,
+where the wide kernel runs those two waves in 116 and 170 ms (5 and
+9 ns a row more than a 32-slot wave); at 255 bins 337 and 683 ms, still
+under the plan's cost.  The reference solves the same problem
 on CPU with ``DataPartition``'s leaf-contiguous row layout + ordered
 gradients
 (`/root/reference/src/treelearner/data_partition.hpp`,
@@ -49,10 +57,10 @@ This module is the TPU-native analog, in three steps per deep wave:
 Cost model: the wide kernel pays ``n * cols_wide`` MACs; the compacted
 path pays ``~n_active * cols_group`` MACs plus a stable segment-sort of
 an ``[n]`` int32 key and one bins/vals gather.  At A=128 / C=4 that is
-a 4x MAC reduction on <= ~half the rows; the sort+gather are what the
-wave microbench (`bench.py` ``wave_kernel`` table, ns/row per
-active-slot bucket) is there to weigh against it — not measured on the
-current tree.
+a 4x MAC reduction; the kernel's grid is the static bound ``n_pad +
+n_groups * T`` rows, so it streams every row tile, trash tiles included
+(it saves columns, not rows), and the sort+gathers cost several times
+the columns saved at every shape on record (head of this file).
 
 Exactness: identical quantized inputs accumulate in int32 exactly in
 both kernels, so the compacted path is BIT-identical to the wide

@@ -612,18 +612,16 @@ def hist_active_scatter(bins: jnp.ndarray,
 
 
 def default_backend() -> str:
-    """"compact" (the wide MXU kernel + leaf-compacted deep waves,
-    ``ops/compact.py``) on TPU, "scatter" elsewhere.  The compact
-    backend degrades to plain "pallas" per-config via
-    ``learner.serial.resolve_backend`` (small trees never reach the
-    slot threshold; VMEM-infeasible groups fall back), so forcing
-    ``LGBM_TPU_NO_COMPACT=1`` only matters for A/B on deep trees."""
+    """What "auto" means: "pallas" (the wide MXU kernel in every wave of
+    a tree) on TPU, "scatter" elsewhere; ``LGBM_TPU_HIST_BACKEND`` names
+    another.  "compact" (``ops/compact.py``: the wide kernel + leaf-
+    compacted deep waves) is reachable by name only: on the chip its
+    plan and regroup cost 1,731 ms an iteration at 13.28M x 67 x 63 bins
+    to save 184 ms of wide-kernel columns (PERF.md, PR 27)."""
     forced = os.environ.get("LGBM_TPU_HIST_BACKEND", "")
     if forced:
         return forced
-    if jax.default_backend() != "tpu":
-        return "scatter"
-    return "pallas" if os.environ.get("LGBM_TPU_NO_COMPACT") else "compact"
+    return "pallas" if jax.default_backend() == "tpu" else "scatter"
 
 
 # ---------------------------------------------------------------------------

@@ -33,8 +33,9 @@ All three return bit-identical trees on every shard (the reference's
 distributed-determinism requirement, `application.cpp:249-254`).
 
 Deep-wave compaction threads through all three learners via the shared
-``make_hist_fn`` seam: on the "compact" backend (the TPU default for
-deep trees) each shard regroups ITS OWN rows leaf-contiguously and runs
+``make_hist_fn`` seam: on the "compact" backend (by name only; the TPU
+default is the wide kernel in every wave, whose output contract is the
+same) each shard regroups ITS OWN rows leaf-contiguously and runs
 the grouped kernel (`ops/compact.py`) for waves above the slot
 threshold.  The collective schedule is untouched — the data-parallel
 ``psum`` still reduces the same ``[A, F, B, 3]`` active-leaf block (the

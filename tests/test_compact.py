@@ -242,6 +242,34 @@ def test_wave_backend_plan_selects_compact_above_threshold():
     assert tail_lw == "fused"
 
 
+@pytest.mark.parametrize("leaves", [255, 31])
+def test_auto_on_a_tpu_is_the_wide_kernel_in_every_wave(monkeypatch, leaves):
+    """"auto" never compacts (PR 27: on the chip the plan and regroup
+    cost several times the columns they save): on a TPU it resolves to
+    "pallas", whose stage plan names no "compact" wave, the 64- and
+    128-slot waves and the tail included.  "compact" is reachable by
+    name and still compacts exactly those."""
+    from types import SimpleNamespace
+    from lightgbm_tpu.learner.serial import (resolve_backend, stage_plan,
+                                             wave_backend_plan)
+    from lightgbm_tpu.ops.pallas_histogram import default_backend
+    monkeypatch.delenv("LGBM_TPU_HIST_BACKEND", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert default_backend() == "pallas"
+    dd = SimpleNamespace(group_max_bins=63)
+    assert resolve_backend(dd, leaves, "auto", "int8h") == "pallas"
+    plan, _ = stage_plan(leaves)
+    choices, tail = wave_backend_plan(leaves, backend="pallas")
+    assert "compact" not in choices + [tail]
+    by_name, tail_by_name = wave_backend_plan(leaves, backend="compact")
+    compacted = [A for A, ch in zip(plan, by_name) if ch == "compact"]
+    if leaves == 255:
+        assert compacted == [64, 128] and tail_by_name == "compact"
+        assert resolve_backend(dd, leaves, "compact", "int8h") == "compact"
+    else:
+        assert not compacted and tail_by_name == "fused"
+
+
 def test_resolve_backend_compact():
     from lightgbm_tpu.config import Config
     from lightgbm_tpu.io.dataset import BinnedDataset

@@ -42,15 +42,16 @@ def _data(n=3000, f=6, seed=0):
     return X, y
 
 
-def _lower_block():
+def _lower_block(backend="compact"):
     """The length-1 block program of a booster on the kernel path
-    (interpreted off-TPU), 80 leaves so that its waves are compacted."""
+    (interpreted off-TPU), 80 leaves so that ``compact`` has waves to
+    compact."""
     X, y = _data()
     params = {"objective": "binary", "num_leaves": 80, "max_bin": 15,
               "min_data_in_leaf": 2, "verbose": -1}
     ds = lgb.Dataset(X, label=y, params={"max_bin": 15})
     g = lgb.Booster(params=params, train_set=ds)._gbdt
-    assert g.hist_backend == "compact"
+    assert g.hist_backend == backend
     return g._make_block_fn(1).lower(
         g.device_data, g._bins_t, tuple(g._valid_device), g.scores,
         tuple(g._valid_scores), jnp.float32(0.1), jnp.int32(0),
@@ -87,6 +88,55 @@ def test_scopes_leave_the_compiled_block_as_it_was(lowered):
     scoped, plain = (stripped(low) for low in lowered)
     assert "tree.compact.plan" not in scoped and "metadata=" not in scoped
     assert scoped == plain
+
+
+def test_default_block_program_does_not_compact(monkeypatch):
+    """What ``auto`` is on a TPU (PR 27: the wide kernel in every wave)
+    traces no compaction: the block program names ``tree.hist`` and no
+    ``tree.compact.*`` scope, and holds no ``hist_active_compact``."""
+    from lightgbm_tpu.ops.pallas_histogram import default_backend
+    monkeypatch.delenv("LGBM_TPU_HIST_BACKEND", raising=False)
+    with monkeypatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        on_tpu = default_backend()
+    assert on_tpu == "pallas"
+    # off the TPU the same kernels run interpreted, under the same names
+    monkeypatch.setenv("LGBM_TPU_HIST_BACKEND", on_tpu)
+    text = _lower_block(on_tpu).as_text(debug_info=True)
+    assert re.search(r'[/"]tree\.hist[/"]', text)
+    # the wide kernel, here fused with the route (the shape is small)
+    assert re.search(r"jit\(hist_(route|active)_pallas\)", text)
+    assert "tree.compact." not in text
+    assert "hist_active_compact" not in text
+
+
+@pytest.mark.parametrize("recording", ["recorded.xplane.pb.gz",
+                                       "recorded_scoped.xplane.pb.gz"])
+def test_the_benchmark_counts_the_compacted_waves_by_name(recording):
+    """``kernels.hist_compact_calls_per_iter`` (PR 27) counts the grouped
+    kernel's calls by its jitted wrapper's name: 2 an iteration on both
+    traces recorded while ``auto`` still compacted (two steps each); the
+    default program has no such wrapper (above), so there it reads 0."""
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    try:
+        from benchmark.readers import scope_time
+    finally:
+        sys.path.remove(root)
+    name = "kernels.hist_compact_calls_per_iter"
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        (entry,) = [m for m in json.load(f)["per_layer"]
+                    if m["name"] == name]
+    assert entry["moves"] == "train.row_iters_per_s"
+    with open(os.path.join(root, "benchmark", "metrics",
+                           name + ".json")) as f:
+        spec = json.load(f)
+    assert spec.pop("reader") == "scope_time" and spec["count"]
+    path = os.path.join(root, "benchmark", "tests", recording)
+    calls = [short for stack, short, _s in scope_time.stacked_self_times(path)
+             if scope_time.matches(stack, short, spec)]
+    assert len(calls) / 2 == 2
 
 
 def _host_events(trace_dir) -> set:

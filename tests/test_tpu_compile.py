@@ -232,12 +232,12 @@ def test_split_kernel_compiles_at_cap(one_chip, features, stride,
 # ---------------------------------------------------------------------------
 def test_build_tree_default_backend_compiles(one_chip, on_tpu):
     """`build_tree` as `lgb.train` traces it on a TPU: default backend
-    (compact), default hist mode, staged waves, fused + compacted +
-    route kernels in one program."""
+    (the wide kernel in every wave), default hist mode, staged waves,
+    histogram + route kernels in one program."""
     from lightgbm_tpu.learner.serial import resolve_backend
     s = _shapes(one_chip)
     dd = _device_data(N_TREE, one_chip, one_chip)
-    assert resolve_backend(dd, LEAVES, hist_mode="int8h") == "compact"
+    assert resolve_backend(dd, LEAVES, hist_mode="int8h") == "pallas"
     growth = _growth()
     fn = jax.jit(lambda dd, g, h, bins_t: build_tree(dd, g, h, growth,
                                                      bins_t=bins_t))

@@ -81,11 +81,6 @@ PROGRAM_PAIRS: Tuple[Dict, ...] = (
                   "resident per backend pinned by "
                   "tests/test_streaming.py)"),
      "test": "tests/test_compact.py"},
-    {"name": "compact-vs-wide-kernel",
-     "env": "LGBM_TPU_NO_COMPACT",
-     "programs": ("leaf-compacted deep-wave histograms",
-                  "wide fused route+hist kernel"),
-     "test": "tests/test_compact.py"},
     {"name": "hist-mode-precision",
      "env": "LGBM_TPU_HIST_MODE",
      "programs": ("f32 histogram accumulation",
