@@ -30,7 +30,8 @@ from ..config import Config
 from ..io.binning import MISSING_NAN
 from ..io.dataset import BinnedDataset
 from ..io.device import DeviceData, to_device
-from ..learner.serial import BuiltTree, GrowthParams, build_tree, predict_built_tree
+from ..learner.serial import (F32_EXACT_ROWS, BuiltTree, GrowthParams,
+                              build_tree, predict_built_tree)
 from ..metric.metrics import Metric, create_metric, default_metric_for_objective
 from ..models.tree import Tree, stack_trees, predict_binned
 from ..obs import counter_add, event as obs_event, span as obs_span
@@ -231,6 +232,7 @@ class GBDT:
         self.num_tree_per_iteration = config.num_tree_per_iteration
         self.mesh_ctx = None
         self._row_pad = 0
+        self.num_data = 0       # no resident train set (streamed, loaded)
         # early-stopping bookkeeping lives on the INSTANCE (not train()
         # locals) so snapshots capture it and a resumed run keeps
         # counting stall rounds from where the dead run stood
@@ -292,6 +294,8 @@ class GBDT:
                     else:
                         n_pad = self.mesh_ctx.pad_rows(n)
                         self._row_pad = n_pad - n
+                        # no padding rows: the scores live with the rows
+                        self.mesh_ctx.scores_sharded = n_pad == n
             else:
                 log_warning(f"tree_learner={c.tree_learner} requested but "
                             f"only one device is visible and no mesh_shape "
@@ -421,7 +425,8 @@ class GBDT:
             from ..learner.serial import uses_pallas
             self._block_backend_ok = (jax.default_backend() != "tpu"
                                       or uses_pallas(backend))
-            self._record_backend(backend, hist_mode, asked_mode)
+            self._record_backend(backend, hist_mode, asked_mode,
+                                 self.num_data)
             if uses_pallas(backend):
                 bins_host = (self.train_set.bins
                              if self.train_set is not None else None)
@@ -522,12 +527,21 @@ class GBDT:
                                           effective_hist_mode,
                                           resolve_backend, uses_pallas)
             asked_mode = dist_hist_mode or default_hist_mode()
-            mesh_hist_mode = effective_hist_mode(asked_mode, self.num_data)
+            # the mode that runs is judged on what sums in int32: a
+            # SHARD's rows where the learner shards rows (the build
+            # judges it on its own bins inside the shard_map), all rows
+            # where it replicates them
+            shard_rows = (
+                self.mesh_ctx.pad_rows(self.num_data)
+                // self.mesh_ctx.num_data_shards
+                if self.mesh_ctx.row_sharded else self.num_data)
+            mesh_hist_mode = effective_hist_mode(asked_mode, shard_rows)
             mesh_backend = resolve_backend(
                 self.device_data, growth.num_leaves, hist_mode=mesh_hist_mode)
             self._block_backend_ok = (jax.default_backend() != "tpu"
                                       or uses_pallas(mesh_backend))
-            self._record_backend(mesh_backend, mesh_hist_mode, asked_mode)
+            self._record_backend(mesh_backend, mesh_hist_mode, asked_mode,
+                                 shard_rows)
         # serial path: already jitted at module level (shared cache);
         # mesh path: per-instance jit (mesh/axis closed over), with
         # grad/hess donated — they die with the build (every caller
@@ -576,14 +590,16 @@ class GBDT:
                                                      self._BLOCK_CAP)))
 
     def _record_backend(self, backend: str, hist_mode: str,
-                        asked_mode: str) -> None:
+                        asked_mode: str, rows: int) -> None:
         """The RESOLVED histogram backend and accumulation mode of the
         build program, on the instance and in the run summary's gauges
         — what a run on the chip checks to know which kernels it ran.
         Where the mode that runs is not the one asked for
         (``effective_hist_mode``: a quantized mode past the exact-int32
         row bound), the summary says so: gauge
-        ``gbdt.hist_mode_requested`` and event ``degrade:hist_mode``."""
+        ``gbdt.hist_mode_requested`` and event ``degrade:hist_mode``
+        with the ``rows`` the bound was held against (a shard's under a
+        row-sharded learner)."""
         from ..obs import gauge_set
         self.hist_backend = backend
         self.hist_mode = hist_mode
@@ -592,7 +608,7 @@ class GBDT:
         if hist_mode != asked_mode:
             gauge_set("gbdt.hist_mode_requested", asked_mode)
             obs_event("degrade", "hist_mode", requested=asked_mode,
-                      effective=hist_mode, rows=int(self.num_data))
+                      effective=hist_mode, rows=int(rows))
 
     def _setup_metrics(self) -> None:
         c = self.config
@@ -965,6 +981,15 @@ class GBDT:
         t.leaf_value[:nl] = np.asarray(bt.leaf_value)[:nl]
         t.leaf_count[:nl] = np.asarray(bt.leaf_count)[:nl]
         t.leaf_depth[:nl] = np.asarray(bt.leaf_depth)[:nl]
+        if self.num_data > F32_EXACT_ROWS:
+            # the growth's float32 counts are rounded past 2^24 rows; the
+            # learner recounted the leaves in integers, and a node holds
+            # its children's rows (children come after their parent)
+            for node in range(m - 1, -1, -1):
+                t.internal_count[node] = sum(
+                    t.leaf_count[~c] if c < 0 else t.internal_count[c]
+                    for c in (int(t.left_child[node]),
+                              int(t.right_child[node])))
         for node in range(m):
             inner = int(feat_inner[node])
             orig = ds.used_features[inner]
@@ -1224,6 +1249,9 @@ class GBDT:
         # one hist_psum fingerprint per wave — is identical on both
         # paths; only the dispatch count changes (one per window)
         mesh_build = self._raw_build if self.mesh_ctx is not None else None
+        scores_ns = (self.mesh_ctx.sharding_for("scores")
+                     if self.mesh_ctx is not None and self._pr is None
+                     else None)
 
         def block(dd, bins_t, vds, scores, vscores, lr, it0, n_active):
             def body(carry, it):
@@ -1284,8 +1312,15 @@ class GBDT:
                         bt = jax.lax.optimization_barrier(bt)
                         with jax.named_scope("gbdt.score_update"):
                             lv_s = lr * bt.leaf_value            # [L]
-                            scores = scores.at[:, k].add(
-                                lv_s[bt.row_leaf[:scores.shape[0]]])
+                            if bt.row_value.shape[0]:
+                                # emitted by each shard's final route
+                                # kernel, as on the serial path: no
+                                # gather over the shard's rows
+                                scores = scores.at[:, k].add(
+                                    lr * bt.row_value[:scores.shape[0]])
+                            else:
+                                scores = scores.at[:, k].add(
+                                    lv_s[bt.row_leaf[:scores.shape[0]]])
                             bts = bt._replace(leaf_value=lv_s)
                             vscores = tuple(
                                 vs.at[:, k].add(
@@ -1338,8 +1373,14 @@ class GBDT:
                 vscores = tuple(jnp.where(active, vs, vi)
                                 for vs, vi in zip(vscores, vscores_in))
                 return (scores, vscores), stacked
-            return jax.lax.scan(body, (scores, vscores),
-                                it0 + jnp.arange(cap))
+            (scores, vscores), trees = jax.lax.scan(
+                body, (scores, vscores), it0 + jnp.arange(cap))
+            if scores_ns is not None:
+                # the scores leave as they came (the registry's rule):
+                # left to the partitioner they came back in another
+                # layout, and the next block compiled a second program
+                scores = jax.lax.with_sharding_constraint(scores, scores_ns)
+            return (scores, vscores), trees
 
         from ..learner.serial import _COMPILE_LEAN_ROWS
         jit_kw = {}

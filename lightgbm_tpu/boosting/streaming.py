@@ -39,8 +39,8 @@ data-parallel, on ALL THREE histogram backends.  Three mechanisms:
    pairwise tree — partition-invariant, so this trainer reassembles
    the identical scalars from per-block chunk sums.  Where the folds
    histogram int8 codes the root sums are the exact int32 sums of the
-   same codes (``root_code_sums`` / ``root_stats_q``): block results
-   add exactly, and each shard dequantizes its total once.
+   same codes (``root_code_sums`` / ``root_stats_q``): block and shard
+   results add exactly, and the total is dequantized once.
 3. **The fenced block body** (``gbdt._make_block_fn``): the serial
    scan body barriers gradients and the built tree and updates scores
    with the contraction-proof scale-then-gather shape (the PR 11 mesh
@@ -67,8 +67,11 @@ fold.
 (``parallel/mesh.py shard_row_ranges``): each shard's blocks fold into
 a per-shard accumulator and the shard partials combine in device order
 — elementwise adds, exactly what the wave ``psum`` lowers to — so the
-streamed model equals the in-memory 2-shard mesh model bitwise.  The
-in-memory data-parallel psum schedule itself is untouched.
+streamed model equals the in-memory 2-shard mesh model bitwise.  On the
+quantized folds the shards round against one pair of scales (the
+largest magnitudes over all rows) and their raw int32 accumulators add
+exactly (``sum_code_limbs``) before the one dequantization, as the
+mesh's ``psum_codes`` exchange does.
 
 Supported: gbdt boosting, row-wise objectives (regression / binary /
 multiclass / xentropy families), ``feature_fraction``, weights, serial
@@ -116,7 +119,9 @@ from ..learner.serial import (STREAM_CHUNK, BuiltTree, _WaveState,
                               root_stats_q, scan_grid, stage_plan)
 from ..obs import counter_add, event, span as obs_span
 from ..objective.objectives import create_objective
-from ..ops.pallas_histogram import bin_stride, pack_values_q
+from ..ops.pallas_histogram import (bin_stride, pack_values_q,
+                                    sum_code_limbs)
+from ..ops.vmem import col_layout
 from ..ops.pallas_route import route_rows_xla
 from ..ops.split import leaf_output as _leaf_output
 from ..utils.log import log_info, log_warning
@@ -143,10 +148,11 @@ _SCALE_CHUNK = 1 << 24
 
 
 def _fold_scales(grad: np.ndarray, hess: np.ndarray) -> np.ndarray:
-    """Per-(tree, shard) global quantization scales for the seeded
-    kernel folds: ``[|g|max, |h|max]`` clamped to 1e-30, f32.
+    """The largest magnitudes ``[|g|max, |h|max]`` of a row range,
+    clamped to 1e-30, f32: the largest over all ranges is a tree's
+    quantization scale pair for the seeded kernel folds.
 
-    Every block of a shard must quantize against ONE scale pair or the
+    Every block must quantize against ONE scale pair or the
     int8 codes (and therefore the int32 accumulator) stop being a pure
     function of the data partition.  The in-memory kernels derive the
     same scalars on device as ``max(|x|)`` over the shard's rows —
@@ -606,6 +612,13 @@ class StreamTrainer:
             return scores_b.at[:, k].add(lv_s[row_leaf])
         return self._jit("score_update", update, static_argnames=("k",))
 
+    def _combine_codes_fn(self, nparts: int):
+        """Quantized folds: the shards' raw int32 accumulators (or root
+        code sums) added exactly, as the limb pair the one unpack /
+        dequantization takes — what the mesh's ``psum_codes`` hands the
+        in-memory data-parallel learner."""
+        return self._jit(f"combine_codes{nparts}", sum_code_limbs)
+
     def _combine_fn(self, nparts: int):
         def combine(parts):
             # shard partials combine in device order — the elementwise
@@ -820,20 +833,26 @@ class StreamTrainer:
         update = self._score_update_fn()
         A = self.A_tail
 
-        # per-(tree, shard) quantization scales for the kernel folds —
-        # fixed across blocks AND waves, host-derived over the shard's
-        # full row range (bitwise the device absmax the in-memory
-        # kernels compute; an empty shard range clamps to 1e-30 on both
-        # sides).  None on the float modes and the scatter path.
+        # per-tree quantization scales for the kernel folds — ONE pair
+        # for every block, wave and shard: the largest magnitudes over
+        # all rows (bitwise the in-memory learners' absmax; the mesh
+        # takes the same pair by a pmax over its shards, an empty shard
+        # range clamps to 1e-30 on both sides).  None on the float
+        # modes and the scatter path.
         fold = self._fold
         quantized = fold is not None and fold.quantized
-        scales_dev = {}
+        exchange = (self.elastic is not None and self.elastic.world > 1)
+        combine_codes = self._combine_codes_fn(self.S)
+        scales = None
         if quantized:
+            own = {}
             for s in self.owned:
                 lo, hi = self.ranges[s]
                 hi = min(hi, self.n)
-                scales_dev[s] = jnp.asarray(
-                    _fold_scales(grad[lo:hi], hess[lo:hi]))
+                own[str(s)] = _fold_scales(grad[lo:hi], hess[lo:hi])
+            if exchange:
+                own = self._exchange_arrays(own, site="elastic.scales")
+            scales = jnp.asarray(np.max(list(own.values()), axis=0))
 
         # leaf2 carries on host between waves (the streaming traffic);
         # root statistics fold per shard and shard scalars combine in
@@ -849,7 +868,7 @@ class StreamTrainer:
             mask[:m] = True
             gb = jnp.asarray(self._pad_block(grad[start:stop], m))
             hb = jnp.asarray(self._pad_block(hess[start:stop], m))
-            cs = (root_codes(gb, hb, jnp.asarray(mask), scales_dev[s])
+            cs = (root_codes(gb, hb, jnp.asarray(mask), scales)
                   if quantized else root_cs(gb, hb, jnp.asarray(mask)))
             shard_cs[s].append(np.asarray(cs))
             l2 = np.full((2, self.R), -1, np.int32)
@@ -858,13 +877,15 @@ class StreamTrainer:
             leaf2_host.append(l2)
 
         def shard_part(s: int, m_chunks: int):
-            """Shard ``s``'s root ``[g, h, count]`` as a ``[3]`` f32."""
+            """Shard ``s``'s root ``[g, h, count]`` as a ``[3]`` f32
+            (quantized folds: its ``[C]`` int32 code sums, for
+            ``root_total`` to add exactly and dequantize once)."""
             if quantized:
-                if not shard_cs[s]:          # a shard of mesh padding
-                    return jnp.zeros(3, jnp.float32)
-                tot = np.sum(shard_cs[s], axis=0, dtype=np.int32)
-                return self._root_dequant_fn()(jnp.asarray(tot),
-                                               scales_dev[s])
+                return jnp.asarray(
+                    np.sum(shard_cs[s], axis=0, dtype=np.int32)
+                    if shard_cs[s]           # else: a shard of mesh padding
+                    else np.zeros(col_layout(1, fold.hist_mode)[0],
+                                  np.int32))
             cs = (np.concatenate(shard_cs[s], axis=1) if shard_cs[s]
                   else np.zeros((3, 0), np.float32))
             if cs.shape[1] < m_chunks:       # trailing mesh-pad chunks
@@ -874,9 +895,16 @@ class StreamTrainer:
             return jnp.stack(reduce_chunk_sums(
                 jnp.asarray(cs[:, :m_chunks])))
 
+        def root_total(parts):
+            """The shards' root parts as one ``[3]`` f32."""
+            if quantized:
+                return self._root_dequant_fn()(
+                    parts[0] if self.S == 1 else combine_codes(parts),
+                    scales)
+            return parts[0] if self.S == 1 else combine(parts)
+
         # in-memory chunk grids: serial = ceil(n/C); data-parallel =
         # ceil(per/C) per shard (mesh padding rows are zero chunks)
-        exchange = (self.elastic is not None and self.elastic.world > 1)
         if exchange:
             # per-shard scalars reduce locally (the same reduction any
             # owner would run), travel as [3] f32 arrays, and combine in
@@ -887,19 +915,19 @@ class StreamTrainer:
                        for s in self.owned}
             merged = self._exchange_arrays(payload,
                                            site="elastic.root_stats")
-            parts = [jnp.asarray(merged[s]) for s in range(self.S)]
-            tot = parts[0] if self.S == 1 else combine(parts)
+            tot = root_total([jnp.asarray(merged[s])
+                              for s in range(self.S)])
             state = init_state(tot[:, None])   # [3, 1]: identity reduce
         elif self.S == 1 and quantized:
-            state = init_state(shard_part(0, 0)[:, None])
+            state = init_state(root_total([shard_part(0, 0)])[:, None])
         elif self.S == 1:
             m_chunks = -(-self.n // STREAM_CHUNK)
             cs_all = np.concatenate(shard_cs[0], axis=1)[:, :m_chunks]
             state = init_state(jnp.asarray(cs_all))
         else:
             m_chunks = -(-self.per // STREAM_CHUNK)
-            tot = combine([shard_part(s, m_chunks)
-                           for s in range(self.S)])
+            tot = root_total([shard_part(s, m_chunks)
+                              for s in range(self.S)])
             state = init_state(tot[:, None])   # [3, 1]: identity reduce
 
         pipelined = self._pipeline_on and len(blocks) > 1
@@ -930,7 +958,7 @@ class StreamTrainer:
                     l2, acc = wave_block(
                         bins_d, jnp.asarray(leaf2_host[bi]), state.best,
                         state.pend_sel, state.pend_new, accs[s], gd, hd,
-                        state.act_small, scales_dev.get(s))
+                        state.act_small, scales)
                 accs[s] = acc
                 if fut is not None:
                     # block k+1's staging wait + upload land here —
@@ -946,12 +974,13 @@ class StreamTrainer:
                     # serial escape hatch: stage + upload only after
                     # the fold is awaited (the reference schedule)
                     dev = self._upload_block(_staged(bi + 1))
-            if fold is not None:
-                # finalize each owned chain ONCE per wave — BEFORE the
-                # shard exchange/combine, so the elastic protocol moves
-                # the same f32 [A, F, B, 3] partials on every backend
+            if fold is not None and not quantized:
+                # float folds: finalize each owned chain ONCE per wave,
+                # BEFORE the shard exchange/combine of f32 [A, F, B, 3]
+                # partials.  Quantized folds exchange and add the raw
+                # int32 accumulators and dequantize once, below
                 for s in self.owned:
-                    accs[s] = fold.unpack(accs[s], scales_dev.get(s))
+                    accs[s] = fold.unpack(accs[s])
             if exchange:
                 # per-shard wave partials are rank-independent (each
                 # shard's carried fold is the same program any owner
@@ -960,8 +989,10 @@ class StreamTrainer:
                 merged = self._exchange_arrays(
                     {str(s): np.asarray(accs[s]) for s in self.owned},
                     site="elastic.wave_hist")
-                parts = [jnp.asarray(merged[s]) for s in range(self.S)]
-                new_h = parts[0] if self.S == 1 else combine(parts)
+                accs = [jnp.asarray(merged[s]) for s in range(self.S)]
+            if quantized:
+                new_h = fold.unpack(
+                    accs[0] if self.S == 1 else combine_codes(accs), scales)
             else:
                 new_h = accs[0] if self.S == 1 else combine(accs)
             hist_state, ids, res = wave_scan(state, new_h, fmask)

@@ -17,6 +17,8 @@ weights, query boundaries, init scores.
 from __future__ import annotations
 
 import json
+import os
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -220,6 +222,47 @@ def find_mappers_from_sample(sample: np.ndarray, config: Config,
     return mappers
 
 
+# rows a task of the concurrent ingest takes: a block of one column is
+# 2 MB of float64, so a task's working set stays in a core's cache
+BIN_BLOCK_ROWS = 1 << 18
+
+
+def _over_row_blocks(n: int, task) -> None:
+    """Run ``task(lo, hi)`` for every block of ``BIN_BLOCK_ROWS`` rows
+    of ``[0, n)``, on as many threads as the host has cores.  The tasks
+    write disjoint rows; numpy's conversions, searches and copies run
+    outside the interpreter lock."""
+    blocks = [(lo, min(lo + BIN_BLOCK_ROWS, n))
+              for lo in range(0, n, BIN_BLOCK_ROWS)]
+    threads = min(os.cpu_count() or 1, len(blocks))
+    if threads <= 1:
+        for block in blocks:
+            task(*block)
+        return
+    with ThreadPoolExecutor(threads) as pool:
+        list(pool.map(lambda block: task(*block), blocks))
+
+
+def bin_rows(mappers: Sequence[BinMapper], X: np.ndarray,
+             features: Sequence[int],
+             prediction_mode: bool = False) -> List[np.ndarray]:
+    """The bins of every row of ``X`` for each of ``features``: ``->
+    [int32 [n], ...]``.  Row blocks are binned concurrently, each
+    column of a block by its own ``BinMapper.value_to_bin``; a row's bin
+    depends on its value alone, so the columns are those of one pass
+    over all rows (``tests/test_binning.py``)."""
+    n = X.shape[0]
+    cols = [np.empty(n, np.int32) for _ in features]
+
+    def task(lo: int, hi: int) -> None:
+        for col, f in zip(cols, features):
+            col[lo:hi] = mappers[f].value_to_bin(
+                X[lo:hi, f], prediction_mode=prediction_mode)
+
+    _over_row_blocks(n, task)
+    return cols
+
+
 @dataclass
 class BundleInfo:
     """EFB group layout (our own encoding, replacing the reference's
@@ -370,9 +413,8 @@ class BinnedDataset:
             ds.bundle = None if prediction_mode else reference.bundle
             with obs_span("io.value_to_bin", rows=n,
                           features=len(ds.used_features)):
-                cols = [ds.mappers[f].value_to_bin(
-                    X[:, f], prediction_mode=prediction_mode)
-                    for f in ds.used_features]
+                cols = bin_rows(ds.mappers, X, ds.used_features,
+                                prediction_mode)
             if ds.bundle is not None and ds.bundle.is_bundled:
                 ds.bins = pack_group_columns(cols, ds.feature_info, ds.bundle)
             else:
@@ -442,8 +484,7 @@ class BinnedDataset:
         if cols is None:
             with obs_span("io.value_to_bin", rows=n,
                           features=len(ds.used_features)):
-                cols = [mappers[f].value_to_bin(X[:, f])
-                        for f in ds.used_features]
+                cols = bin_rows(mappers, X, ds.used_features)
         ds.feature_info = cls._build_feature_info(
             [mappers[f] for f in ds.used_features])
         # 4. EFB: bundle sufficiently sparse features into shared columns
@@ -514,8 +555,12 @@ class BinnedDataset:
         dtype = (np.int32 if force_int32 or info.max_num_bins > 256
                  else np.uint8)
         out = np.empty((len(cols[0]), len(cols)), dtype=dtype)
-        for j, c in enumerate(cols):
-            out[:, j] = c.astype(dtype)
+
+        def task(lo: int, hi: int) -> None:
+            for j, c in enumerate(cols):
+                out[lo:hi, j] = c[lo:hi]
+
+        _over_row_blocks(out.shape[0], task)
         return out
 
     # -- views / accessors ----------------------------------------------

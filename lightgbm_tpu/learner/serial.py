@@ -89,8 +89,9 @@ class BuiltTree(NamedTuple):
     num_leaves: jnp.ndarray      # scalar i32
     row_leaf: jnp.ndarray        # [n] i32 final leaf per row (ALL rows)
     row_value: jnp.ndarray       # [n] f32 leaf_value[row_leaf] (emitted by
-    #   the final route kernel on the Pallas path; empty [0] otherwise —
-    #   the score update falls back to a gather)
+    #   the final route kernel on the Pallas path, serial and
+    #   data-parallel; empty [0] otherwise — the score update falls back
+    #   to a gather)
 
 
 class _WaveState(NamedTuple):
@@ -383,6 +384,30 @@ def resolve_backend(data: DeviceData, num_leaf_slots: int,
 _INT8_ROW_LIMIT = ((1 << 31) - 1) // 127
 
 
+# a float32 holds every integer up to here: the growth carries its row
+# counts in float32 (histogram cells, the split scan's prefix sums, the
+# leaves' counts), so with more rows than this in all a tree's counts are
+# rounded, and build_tree counts the finished leaves' rows in integers
+F32_EXACT_ROWS = 1 << 24
+
+
+def leaf_row_counts(leaf: jnp.ndarray, num_leaves: int) -> jnp.ndarray:
+    """``[n] int32`` leaf of each row (negative: not counted) ``->
+    [num_leaves] int32`` rows a leaf, exact.  The id is cut in two
+    halves of its bits, and the count of ``(high, low)`` is one int8
+    matrix product of the halves' one-hots with the rows contracted
+    (int32 sums): 2 * sqrt(L) comparisons a row, not L."""
+    low_bits = max(1, (num_leaves - 1).bit_length()) // 2
+    P = 1 << low_bits
+    Q = -(-num_leaves // P)
+    high = (leaf >> low_bits)[None, :] == jnp.arange(Q)[:, None]   # [Q, n]
+    low = (leaf & (P - 1))[None, :] == jnp.arange(P)[:, None]      # [P, n]
+    cnt = jax.lax.dot_general(
+        high.astype(jnp.int8), low.astype(jnp.int8),
+        (((1,), (1,)), ((), ())), preferred_element_type=jnp.int32)
+    return cnt.reshape(-1)[:num_leaves]
+
+
 def effective_hist_mode(mode: str, n: int) -> str:
     """Downgrade quantized modes past the exact-int32 row bound (the
     root leaf can concentrate every row in one cell) to the closest
@@ -437,7 +462,9 @@ def _pack(grad, hess, hist_mode: str, scales=None):
 
 def make_hist_fn(data: DeviceData, grad, hess, num_leaf_slots: int,
                  backend: str = "auto", hist_mode: Optional[str] = None,
-                 bins_t: Optional[jnp.ndarray] = None):
+                 bins_t: Optional[jnp.ndarray] = None,
+                 scales: Optional[jnp.ndarray] = None,
+                 codes: bool = False):
     """Build the per-wave active-leaf histogram closure
     ``(hist_leaf, active) -> [A, F, B, 3]``.
 
@@ -445,6 +472,13 @@ def make_hist_fn(data: DeviceData, grad, hess, num_leaf_slots: int,
     "scatter" = XLA scatter-add (CPU tests / oracle).  The two are
     cross-checked by ``tests/test_pallas_hist.py`` the way the reference
     checks GPU vs CPU histograms (`gpu_tree_learner.cpp:1020-1043`).
+
+    ``scales``: the quantized modes round against these and not against
+    the largest magnitudes of ``grad`` / ``hess`` (a row-sharded learner
+    hands every shard the largest over all shards).  ``codes`` (quantized
+    kernel modes only): the closure returns the ``[A, F, B, C]`` int32
+    code sums, not dequantized, for the caller to sum over the shards
+    and dequantize once.
     """
     if hist_mode is None:
         hist_mode = default_hist_mode()
@@ -453,7 +487,9 @@ def make_hist_fn(data: DeviceData, grad, hess, num_leaf_slots: int,
     if uses_pallas(backend):
         if bins_t is None:
             bins_t = transpose_bins(data.bins)
-        vals, scales = _pack(grad, hess, hist_mode)
+        vals, scales = _pack(grad, hess, hist_mode, scales)
+        if codes:
+            scales = None      # combine_hist_cols: the code sums as they are
         n_pad = bins_t.shape[1]
         n = data.bins.shape[0]
         interp = _pallas_interpret()
@@ -709,10 +745,11 @@ def apply_hist_wave(hist_state, new_h, act_small, act_parent, act_sibling,
 
 
 def make_fused_fn(data: DeviceData, grad, hess, hist_mode: str,
-                  bins_t: jnp.ndarray):
+                  bins_t: jnp.ndarray,
+                  scales: Optional[jnp.ndarray] = None):
     """Fused route+hist closure ``(leaf2, best, sel, new_id, active) ->
     (new_h, leaf2_new)`` — one bins stream per wave instead of two."""
-    vals, scales = _pack(grad, hess, hist_mode)
+    vals, scales = _pack(grad, hess, hist_mode, scales)
     interp = _pallas_interpret()
 
     def fused(leaf2, best: SplitResult, sel, new_id, active):
@@ -734,7 +771,8 @@ def make_serial_strategy(data: DeviceData, grad, hess, params: GrowthParams,
                          feature_mask, psum_fn=None, backend: str = "auto",
                          hist_mode: Optional[str] = None,
                          bins_t: Optional[jnp.ndarray] = None,
-                         psum_axis: Optional[str] = None):
+                         psum_axis: Optional[str] = None,
+                         scales: Optional[jnp.ndarray] = None):
     """The serial (and data-parallel, via `psum_fn`) wave strategy:
     histogram the active leaves, subtract siblings, rescan changed leaves.
 
@@ -745,22 +783,40 @@ def make_serial_strategy(data: DeviceData, grad, hess, params: GrowthParams,
     (`ops/overlap.py`): the same logical reduction issued as column
     chunks whose sibling-subtract/state-scatter consumers double-buffer
     against the chunks still in flight — bit-identical values, identical
-    logical schedule."""
+    logical schedule.
+
+    Where the kernels histogram quantized values, what crosses the
+    shards is the cells' integer code sums (``codes``): rounded against
+    the one ``scales`` of all shards, summed exactly by ``psum_fn``, and
+    dequantized once after, as one chip dequantizes its own — so the
+    histograms, and the tree, are those of the rows however they are
+    cut (``tests/test_parallel.py``)."""
     L = params.num_leaves
-    hist_fn = make_hist_fn(data, grad, hess, L, backend, hist_mode, bins_t)
+    mode = effective_hist_mode(hist_mode or default_hist_mode(),
+                               data.num_data)
+    backend = resolve_backend(data, L, backend, mode)
+    codes = (psum_fn is not None and uses_pallas(backend)
+             and is_quantized(mode))
+    if codes and scales is None:
+        raise ValueError("a quantized histogram exchange needs the scales "
+                         "of all shards (parallel/learners.py global_scales)")
+    hist_fn = make_hist_fn(data, grad, hess, L, backend, mode, bins_t,
+                           scales=scales, codes=codes)
+    dequant = ((lambda h: dequant_hist(h, scales, mode)) if codes
+               else (lambda h: h))
 
     def wave(hist_state, hist_leaf, act_small, act_parent, act_sibling,
              lsg, lsh, lc):
-        new_h = hist_fn(hist_leaf, act_small)            # [A, G, Bg, 3]
+        new_h = hist_fn(hist_leaf, act_small)   # [A, G, Bg, 3 | C codes]
         if psum_axis is not None:
             from ..ops.overlap import reduce_apply_overlapped
             hist_state, ids, grid = reduce_apply_overlapped(
                 hist_state, new_h, act_small, act_parent, act_sibling, L,
-                psum_axis)
+                psum_axis, psum_fn.reduce, dequant)
             return scan_grid(data, params, feature_mask, hist_state, ids,
                              grid, lsg, lsh, lc)
         if psum_fn is not None:
-            new_h = psum_fn(new_h)
+            new_h = dequant(psum_fn(new_h))
         return rescan_changed(data, params, feature_mask, hist_state, new_h,
                               act_small, act_parent, act_sibling,
                               lsg, lsh, lc)
@@ -845,7 +901,8 @@ def build_tree(data: DeviceData,
                num_hist_features: Optional[int] = None,
                bins_t: Optional[jnp.ndarray] = None,
                hist_mode: Optional[str] = None,
-               psum_axis: Optional[str] = None) -> BuiltTree:
+               psum_axis: Optional[str] = None,
+               scales: Optional[jnp.ndarray] = None) -> BuiltTree:
     """Grow one tree.  Jittable; `psum_fn` lets the data-parallel learner
     inject a collective over active-leaf histograms; `strategy` replaces
     the whole wave procedure (feature/voting-parallel,
@@ -854,7 +911,10 @@ def build_tree(data: DeviceData,
     `bins_t` is the once-per-dataset transposed bins (computed here when
     absent); `psum_axis` routes the data-parallel wave reduction through
     the overlapped chunked lowering (`ops/overlap.py`) — `psum_fn` is
-    still used for the root-statistics reduction either way."""
+    still used for the root-statistics reduction either way; `scales`
+    are the quantized modes' ``[2]`` rounding scales where they are not
+    to be taken from this call's own rows (a row-sharded learner's are
+    the largest over all shards)."""
     n = data.bins.shape[0]
     L = params.num_leaves
 
@@ -880,10 +940,10 @@ def build_tree(data: DeviceData,
         plan, A_tail = [], _round8(max(1, L // 2))
     wave_cap = params.wave_size if params.wave_size > 0 else L
     # the final route can emit per-row leaf values (gather-free score
-    # update) on any serial Pallas path — captured BEFORE the serial
-    # strategy closure is assigned below
-    emit_values = (strategy is None and psum_fn is None
-                   and uses_pallas(backend))
+    # update) on the serial Pallas path and, a shard's own rows, on the
+    # data-parallel one (the leaf values are the same on every shard) —
+    # captured BEFORE the serial strategy closure is assigned below
+    emit_values = emits_row_values(strategy is None, backend)
     # fused route+hist: one bins stream per wave (serial Pallas path with
     # every stored column in a single kernel tile);
     # LGBM_TPU_NO_FUSED=1 forces the unfused path (A/B debugging)
@@ -892,7 +952,7 @@ def build_tree(data: DeviceData,
              and not _os.environ.get("LGBM_TPU_NO_FUSED")
              and fused_config_ok(bins_t.shape[0], data.group_max_bins, L,
                                  mode))
-    fused_fn = (make_fused_fn(data, grad, hess, mode, bins_t)
+    fused_fn = (make_fused_fn(data, grad, hess, mode, bins_t, scales)
                 if fused else None)
     # the "compact" backend (by name only; "auto" never resolves to it)
     # needs the strategy (route + compacted hist) for its deep waves
@@ -902,7 +962,7 @@ def build_tree(data: DeviceData,
                                         feature_mask, psum_fn=psum_fn,
                                         backend=backend, bins_t=bins_t,
                                         hist_mode=hist_mode,
-                                        psum_axis=psum_axis)
+                                        psum_axis=psum_axis, scales=scales)
     route_fn = make_route_fn(data, backend, bins_t)
 
     def scan_changed(hist_state, new_h, s, lsg, lsh, lc):
@@ -913,7 +973,8 @@ def build_tree(data: DeviceData,
     A0 = plan[0] if plan else A_tail
     with jax.named_scope("tree.init"):
         state = _init_state(data, grad, hess, params, bag_mask, psum_fn,
-                            backend, bins_t, num_hist_features, A0, mode)
+                            backend, bins_t, num_hist_features, A0, mode,
+                            scales)
 
     def body(s: _WaveState, A_out: int) -> _WaveState:
         # --- 0-3: apply last wave's pending splits to the rows, then
@@ -975,9 +1036,19 @@ def build_tree(data: DeviceData,
                                    final.pend_new)
             row_value = jnp.zeros(0, jnp.float32)   # empty: caller gathers
     final = final._replace(leaf2=leaf2_final)
+    leaf_count = final.leaf_count.astype(jnp.int32)
+    if n * (psum_fn.num_shards if psum_fn is not None
+            else 1) > F32_EXACT_ROWS:
+        # more rows than float32 counts exactly: the model's leaf counts
+        # are the rows routed there, counted in integers (in-bag rows,
+        # as the growth's own), summed over the shards
+        with jax.named_scope("tree.count"):
+            leaf_count = leaf_row_counts(final.leaf2[1, :n], L)
+            if psum_fn is not None:
+                leaf_count = psum_fn.counts(leaf_count)
     return final.tree._replace(
         leaf_value=final.leaf_value,
-        leaf_count=final.leaf_count.astype(jnp.int32),
+        leaf_count=leaf_count,
         leaf_depth=final.leaf_depth,
         num_leaves=final.nl,
         row_leaf=final.leaf2[0, :n],
@@ -985,13 +1056,24 @@ def build_tree(data: DeviceData,
     )
 
 
+def emits_row_values(serial_strategy: bool, backend: str) -> bool:
+    """Whether :func:`build_tree` returns ``row_value`` (``[n]``, from
+    the final route kernel) or leaves it empty: the serial wave strategy
+    (with or without the data-parallel ``psum_fn``) on a Pallas
+    backend."""
+    return serial_strategy and uses_pallas(backend)
+
+
 def _init_state(data: DeviceData, grad, hess, params: GrowthParams,
                 bag_mask, psum_fn, backend: str, bins_t,
                 num_hist_features: Optional[int], A0: int,
-                hist_mode: str) -> _WaveState:
+                hist_mode: str,
+                scales: Optional[jnp.ndarray] = None) -> _WaveState:
     """Initial wave state: empty tree, root leaf stats, root wave active
     set.  Shared by :func:`build_tree` and :func:`build_tree_phases`.
-    ``hist_mode`` is the effective mode the waves histogram in."""
+    ``hist_mode`` is the effective mode the waves histogram in,
+    ``scales`` what its quantized values are rounded against where that
+    is not this call's own rows' largest."""
     n = data.bins.shape[0]
     L = params.num_leaves
     Lm = max(L - 1, 1)
@@ -1033,16 +1115,19 @@ def _init_state(data: DeviceData, grad, hess, params: GrowthParams,
     # (boosting/streaming.py; the old jnp.sum reduction tree could not
     # be reassembled from block partials).  Where the kernels histogram
     # quantized values the totals come from the same int8 codes
-    # (root_stats_q: exact integer sums, partition-invariant too)
+    # (root_stats_q: exact integer sums, partition-invariant too: the
+    # shards' code sums are summed exactly and dequantized once)
     bag = (leaf2[1] == 0)
     if uses_pallas(backend) and is_quantized(hist_mode):
-        vals, scales = _pack(grad, hess, hist_mode)
-        sum_g, sum_h, cnt = root_stats_q(root_code_sums(vals, bag[:n]),
-                                         scales, hist_mode)
+        vals, scales = _pack(grad, hess, hist_mode, scales)
+        code_sums = root_code_sums(vals, bag[:n])
+        if psum_fn is not None:
+            code_sums = psum_fn(code_sums, "root_psum")
+        sum_g, sum_h, cnt = root_stats_q(code_sums, scales, hist_mode)
     else:
         sum_g, sum_h, cnt = root_stats(grad, hess, bag[:n])
-    if psum_fn is not None:
-        sum_g, sum_h, cnt = psum_fn((sum_g, sum_h, cnt))
+        if psum_fn is not None:
+            sum_g, sum_h, cnt = psum_fn((sum_g, sum_h, cnt), "root_psum")
 
     from ..ops.split import leaf_output as _leaf_out
     root_out = _leaf_out(sum_g, sum_h, params.split.lambda_l1,

@@ -384,10 +384,13 @@ def unpack_hist_compact_raw(out: jnp.ndarray, num_active: int,
     G = COMPACT_GROUP
     n_groups = -(-A // G)
     C, Gp, cols = _col_layout(G, mode)
-    F_grid = out.shape[0] // ((n_groups + 1) * B)
-    out = out.reshape(n_groups + 1, F_grid, B, cols)[
-        :n_groups, :, :, :C * Gp]
-    out = out.reshape(n_groups, F_grid, B, C, Gp)
-    out = out.transpose(0, 4, 1, 2, 3).reshape(n_groups * Gp, F_grid, B, C)
-    out = out[:A, :num_features]
-    return combine_hist_cols(out, mode, scales)
+
+    def cells(o):
+        F_grid = o.shape[0] // ((n_groups + 1) * B)
+        o = o.reshape(n_groups + 1, F_grid, B, cols)[
+            :n_groups, :, :, :C * Gp]
+        o = o.reshape(n_groups, F_grid, B, C, Gp)
+        o = o.transpose(0, 4, 1, 2, 3).reshape(n_groups * Gp, F_grid, B, C)
+        return o[:A, :num_features]
+    # `out` may be the limb pair of several shards' accumulators
+    return combine_hist_cols(jax.tree.map(cells, out), mode, scales)

@@ -40,11 +40,20 @@ every rank issues the identical physical sequence too.
 Knobs: ``LGBM_TPU_OVERLAP=0`` disables (plain single-psum schedule);
 ``LGBM_TPU_OVERLAP_CHUNKS`` sets the chunk count (default 2; clamped to
 the column count).
+
+MEASURED (four v5e chips, 4 x 13,281,250 rows x 67 x 63 bins x 255
+leaves, int8h; `PERF.md` §5, PR 28): there is nothing to hide.  The
+wave reductions take 1.21 ms an iteration chunked and 0.99 ms plain, and
+the chunked bookkeeping costs 46 ms an iteration more than the plain
+one's (each per-chunk ``.at[...].set`` of the ``[255, 67, 64, 3]``
+histogram state copies it: six copies of 6.1 ms): 0.708 s a step with
+overlap on, 0.663 s with it off.  Recorded, not acted on: the default
+stays on until a ``perf_opt`` PR judges it (ROADMAP S6).
 """
 from __future__ import annotations
 
 import os
-from typing import List, Optional, Tuple
+from typing import Callable, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -79,15 +88,17 @@ def wave_psum(x: jnp.ndarray, axis: str,
     if chunks is None:
         chunks = overlap_chunks()
     bounds = _chunk_bounds(x.shape[1], chunks)
-    if len(bounds) <= 1:
-        return jax.lax.psum(x, axis)
-    return jnp.concatenate(
-        [jax.lax.psum(x[:, lo:hi], axis) for lo, hi in bounds], axis=1)
+    with jax.named_scope("collective.hist_psum"):
+        if len(bounds) <= 1:
+            return jax.lax.psum(x, axis)
+        return jnp.concatenate(
+            [jax.lax.psum(x[:, lo:hi], axis) for lo, hi in bounds], axis=1)
 
 
 def reduce_apply_overlapped(hist_state: jnp.ndarray, new_h: jnp.ndarray,
                             act_small: jnp.ndarray, act_parent: jnp.ndarray,
                             act_sibling: jnp.ndarray, L: int, axis: str,
+                            reduce: Callable, dequant: Callable,
                             chunks: Optional[int] = None):
     """Double-buffered reduce + per-wave histogram bookkeeping: the
     overlapped drop-in for ``psum`` followed by
@@ -97,6 +108,13 @@ def reduce_apply_overlapped(hist_state: jnp.ndarray, new_h: jnp.ndarray,
     parent-minus-child subtraction, and persist both children into the
     per-leaf state — so each chunk's subtract/scatter consumes its
     reduction as it lands while later chunks are still on the wire.
+    ``reduce`` is the learner's reduction of one chunk over ``axis``
+    (`parallel/learners.py` ``Psum.reduce``: under the scope
+    ``collective.hist_psum``, float32 histograms by ``psum``, the
+    quantized modes' int32 code sums exactly) and ``dequant`` what turns
+    a reduced chunk into ``[A, gc, B, 3]`` float32 (dequantizing the
+    code sums; the identity for float32 histograms): both elementwise
+    over the columns, so the chunks are those of the whole block.
     Returns ``(hist_state, ids [2A], grid [2A, G, B, 3])`` with values
     bit-identical to the unoverlapped path (see module docstring).
     """
@@ -105,21 +123,26 @@ def reduce_apply_overlapped(hist_state: jnp.ndarray, new_h: jnp.ndarray,
     # the LOGICAL schedule entry: one reduction per wave, full operand —
     # identical fingerprint to the unoverlapped `_psum` record
     _fr_record("parallel.learners.hist_psum", "psum", axis, new_h)
-    parent_safe = jnp.clip(act_parent, 0, L - 1)
-    small_slot = jnp.where(act_small >= 0, act_small, L)
-    sib_slot = jnp.where(act_sibling >= 0, act_sibling, L)
-    h_parts: List[jnp.ndarray] = []
-    sib_parts: List[jnp.ndarray] = []
-    for lo, hi in _chunk_bounds(new_h.shape[1], chunks):
-        h_c = jax.lax.psum(new_h[:, lo:hi], axis)        # [A, gc, B, 3]
-        parent_c = hist_state[parent_safe, lo:hi]
-        sib_c = parent_c - h_c
-        hist_state = hist_state.at[small_slot, lo:hi].set(h_c, mode="drop")
-        hist_state = hist_state.at[sib_slot, lo:hi].set(sib_c, mode="drop")
-        h_parts.append(h_c)
-        sib_parts.append(sib_c)
-    new_h_red = jnp.concatenate(h_parts, axis=1)
-    sib_h = jnp.concatenate(sib_parts, axis=1)
-    ids = jnp.concatenate([act_small, act_sibling])      # [2A]
-    grid = jnp.concatenate([new_h_red, sib_h], axis=0)   # [2A, G, B, 3]
+    # the bookkeeping under the scope `apply_hist_wave` gives the same
+    # work on the unoverlapped path; the reductions under their own
+    with jax.named_scope("tree.hist"):
+        parent_safe = jnp.clip(act_parent, 0, L - 1)
+        small_slot = jnp.where(act_small >= 0, act_small, L)
+        sib_slot = jnp.where(act_sibling >= 0, act_sibling, L)
+        h_parts: List[jnp.ndarray] = []
+        sib_parts: List[jnp.ndarray] = []
+        for lo, hi in _chunk_bounds(new_h.shape[1], chunks):
+            h_c = dequant(reduce(new_h[:, lo:hi]))        # [A, gc, B, 3]
+            parent_c = hist_state[parent_safe, lo:hi]
+            sib_c = parent_c - h_c
+            hist_state = hist_state.at[small_slot, lo:hi].set(h_c,
+                                                              mode="drop")
+            hist_state = hist_state.at[sib_slot, lo:hi].set(sib_c,
+                                                            mode="drop")
+            h_parts.append(h_c)
+            sib_parts.append(sib_c)
+        new_h_red = jnp.concatenate(h_parts, axis=1)
+        sib_h = jnp.concatenate(sib_parts, axis=1)
+        ids = jnp.concatenate([act_small, act_sibling])      # [2A]
+        grid = jnp.concatenate([new_h_red, sib_h], axis=0)   # [2A, G, B, 3]
     return hist_state, ids, grid

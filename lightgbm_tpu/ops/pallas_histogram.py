@@ -203,6 +203,23 @@ def pack_values(grad: jnp.ndarray, hess: jnp.ndarray, mode: str,
     return jnp.stack(rows, axis=0)
 
 
+# a code's step is its scale times one of these: written as products, as
+# a compiler writes a division by a constant anyway (an eager division,
+# whose divisor is an argument, came out an ulp off the jitted one)
+_INV_127 = 1.0 / 127.0
+_INV_16129 = 1.0 / 16129.0
+
+
+def quant_scales(grad: jnp.ndarray, hess: jnp.ndarray) -> jnp.ndarray:
+    """``[2] f32 (sg, sh)``: the largest magnitudes of the rows given,
+    the scales :func:`pack_values_q` rounds them against.  A maximum is
+    exact in any order, so the largest over the shards' results
+    (``pmax``, `parallel/learners.py`) is that of all the rows."""
+    sg = jnp.maximum(jnp.max(jnp.abs(grad.astype(jnp.float32))), 1e-30)
+    sh = jnp.maximum(jnp.max(jnp.abs(hess.astype(jnp.float32))), 1e-30)
+    return jnp.stack([sg, sh])
+
+
 def pack_values_q(grad: jnp.ndarray, hess: jnp.ndarray, mode: str,
                   row_tile: int = DEFAULT_ROW_TILE,
                   key: jnp.ndarray | None = None,
@@ -233,9 +250,11 @@ def pack_values_q(grad: jnp.ndarray, hess: jnp.ndarray, mode: str,
     ``scales``: optional precomputed ``[2] f32 (sg, sh)`` — the streamed
     fold path (``boosting/streaming.py``) quantizes each BLOCK of a tree
     with the tree's GLOBAL absmax scales (host-computed over every
-    block), so per-row int8 codes — and therefore the exact int32 bin
-    sums — are bitwise what the monolithic in-memory pack produces.
-    When omitted, scales are derived from this call's rows as before.
+    block), and the row-sharded learners each SHARD with the largest
+    over all shards (`parallel/learners.py`), so per-row int8 codes —
+    and therefore the exact int32 bin sums — are bitwise what the
+    monolithic in-memory pack produces.  When omitted, scales are
+    derived from this call's rows (:func:`quant_scales`).
     """
     n = grad.shape[0]
     n_pad = _round_up(n, row_tile)
@@ -243,10 +262,8 @@ def pack_values_q(grad: jnp.ndarray, hess: jnp.ndarray, mode: str,
     g = grad.astype(jnp.float32)
     h = hess.astype(jnp.float32)
     if scales is None:
-        sg = jnp.maximum(jnp.max(jnp.abs(g)), 1e-30)
-        sh = jnp.maximum(jnp.max(jnp.abs(h)), 1e-30)
-    else:
-        sg, sh = scales[0], scales[1]
+        scales = quant_scales(g, h)
+    sg, sh = scales[0], scales[1]
 
     def q(x, scale, sub):
         t = x * (127.0 / scale)
@@ -258,7 +275,18 @@ def pack_values_q(grad: jnp.ndarray, hess: jnp.ndarray, mode: str,
 
     def hilo8(x, scale, sub):
         hi = jnp.clip(jnp.round(x * (127.0 / scale)), -127, 127)
-        lo = q(x - hi * (scale / 127.0), scale / 127.0, sub)
+        # the residual x - hi * step by two exact products: the step's
+        # upper 12 mantissa bits and its rest, each times a 7-bit code,
+        # fit float32.  The one inexact product hi * step a compiler
+        # contracts into the subtraction or not (an FMA, as the program
+        # around it fuses), which moved the residual's last bit: rows
+        # near a half took another low code in the sharded program than
+        # in the serial one
+        step = scale * _INV_127
+        top = jax.lax.bitcast_convert_type(
+            jax.lax.bitcast_convert_type(step, jnp.uint32)
+            & jnp.uint32(0xFFFFF000), jnp.float32)
+        lo = q((x - hi * top) - hi * (step - top), step, sub)
         return hi, lo
 
     if mode == "int8hh":
@@ -274,24 +302,79 @@ def pack_values_q(grad: jnp.ndarray, hess: jnp.ndarray, mode: str,
     return vals.astype(jnp.int8), jnp.stack([sg, sh])
 
 
-def dequant_hist(out_i32: jnp.ndarray, scales: jnp.ndarray,
-                 mode: str) -> jnp.ndarray:
+def code_limbs(x: jnp.ndarray):
+    """An int32 code sum as two 16-bit limbs ``(hi, lo)``: ``x = hi *
+    2^16 + lo`` with ``lo`` in ``[0, 2^16)`` (``>>`` is arithmetic, so
+    this holds for negative ``x`` too)."""
+    return x >> 16, x & 0xFFFF
+
+
+def sum_code_limbs(parts):
+    """Several shards' int32 code sums (arrays of one shape) added
+    exactly: ``-> (hi, lo)`` limbs of each cell's total, ``lo`` in ``[0,
+    2^16)``.  The streamed trainer's host-side twin of the mesh
+    exchange (`parallel/learners.py` ``psum_codes``, whose bounds hold
+    here: at most 511 parts)."""
+    his, los = zip(*(code_limbs(p) for p in parts))
+    return carry_limbs(sum(his[1:], his[0]), sum(los[1:], los[0]))
+
+
+def carry_limbs(hi: jnp.ndarray, lo: jnp.ndarray):
+    """Limbs whose ``lo`` holds a sum of low limbs ``->`` the pair of
+    the same total with ``lo`` back in ``[0, 2^16)``: the one pair a
+    total has, whatever sums it was made of."""
+    return hi + (lo >> 16), lo & 0xFFFF
+
+
+def _limbs_f32(hi: jnp.ndarray, lo: jnp.ndarray) -> jnp.ndarray:
+    """``hi * 2^16 + lo`` (int32 limbs, ``lo`` of any size that fits) as
+    float32.  ``lo``'s carry moves to ``hi`` first; then both
+    conversions and the product by 2^16 are exact while ``|hi| <= 2^24``
+    and the one addition rounds the total to nearest, as a conversion of
+    an integer holding it would.  No product here is inexact, so a
+    compiler that contracts a multiply into the add (an FMA, formed or
+    not with the program around) gives the same float: serial and
+    sharded programs dequantize a cell alike."""
+    hi, lo = carry_limbs(hi, lo)
+    return hi.astype(jnp.float32) * 65536.0 + lo.astype(jnp.float32)
+
+
+def dequant_hist(out, scales: jnp.ndarray, mode: str) -> jnp.ndarray:
     """``[A, F, B, C] int32 (+ scales) -> [A, F, B, 3] f32`` — undo
-    :func:`pack_values_q` after exact integer accumulation."""
+    :func:`pack_values_q` after exact integer accumulation.
+
+    ``out`` is one chip's int32 code sums or, from the row-sharded
+    exchange, their sums over all shards as a pair of int32 limbs
+    (:func:`code_limbs`; `parallel/learners.py` ``psum_codes``): one
+    chip's sums go through the same limbs, so the same total gives the
+    same floats either way.  A hi/lo pair of value columns is combined
+    as integers, ``127 * hi + lo`` in units of ``scale / 16129`` (limb
+    by limb: ``|127 * hi_limb + lo_limb|`` stays under 2^31 for the 511
+    shards ``psum_codes`` admits), and rounded to float32 once: no
+    sum of two inexact products, which a compiler may or may not
+    contract into an FMA as the program around it fuses."""
     sg, sh = scales[0], scales[1]
-    out = out_i32.astype(jnp.float32)
+    hi, lo = out if isinstance(out, tuple) else code_limbs(out)
+
+    def col(k):
+        return _limbs_f32(hi[..., k], lo[..., k])
+
+    def pair(k):
+        return _limbs_f32(127 * hi[..., k] + hi[..., k + 1],
+                          127 * lo[..., k] + lo[..., k + 1])
+
     if mode == "int8hh":
-        g = out[..., 0] * (sg / 127.0) + out[..., 1] * (sg / 16129.0)
-        h = out[..., 2] * (sh / 127.0) + out[..., 3] * (sh / 16129.0)
-        cnt = out[..., 4]
+        g = pair(0) * (sg * _INV_16129)
+        h = pair(2) * (sh * _INV_16129)
+        cnt = col(4)
     elif mode == "int8h":
-        g = out[..., 0] * (sg / 127.0)
-        h = out[..., 1] * (sh / 127.0) + out[..., 2] * (sh / 16129.0)
-        cnt = out[..., 3]
+        g = col(0) * (sg * _INV_127)
+        h = pair(1) * (sh * _INV_16129)
+        cnt = col(3)
     else:
-        g = out[..., 0] * (sg / 127.0)
-        h = out[..., 1] * (sh / 127.0)
-        cnt = out[..., 2]
+        g = col(0) * (sg * _INV_127)
+        h = col(1) * (sh * _INV_127)
+        cnt = col(2)
     return jnp.stack([g, h, cnt], axis=-1)
 
 
@@ -548,21 +631,27 @@ def unpack_hist_raw(out: jnp.ndarray, num_active: int, num_features: int,
 
 def _unpack_hist(out, B, cols, C, A_pad, A, num_features, mode, scales):
     """``[F_grid*B, cols] -> [A, F, B, 3] f32``: undo the kernel's
-    c-major column layout and combine hi/lo (or dequantize) columns."""
-    F_grid = out.shape[0] // B
-    out = out.reshape(F_grid, B, cols)[:, :, :C * A_pad]
-    out = out.reshape(F_grid, B, C, A_pad)
-    out = out.transpose(3, 0, 1, 2)[:A, :num_features]       # [A, F, B, C]
-    return combine_hist_cols(out, mode, scales)
+    c-major column layout and combine hi/lo (or dequantize) columns.
+    ``out`` may be the limb pair of several shards' accumulators
+    (:func:`sum_code_limbs`)."""
+    def cells(o):
+        F_grid = o.shape[0] // B
+        o = o.reshape(F_grid, B, cols)[:, :, :C * A_pad]
+        o = o.reshape(F_grid, B, C, A_pad)
+        return o.transpose(3, 0, 1, 2)[:A, :num_features]    # [A, F, B, C]
+    return combine_hist_cols(jax.tree.map(cells, out), mode, scales)
 
 
 def combine_hist_cols(out, mode, scales):
     """``[..., C]`` raw kernel value columns -> ``[..., 3]`` f32
     ``(sum_grad, sum_hess, count)``: combine hi/lo pairs or dequantize.
     Shared by the wide kernel's unpack and the leaf-compacted kernel
-    (``ops/compact.py``), so the two paths cannot drift."""
+    (``ops/compact.py``), so the two paths cannot drift.  A quantized
+    mode with ``scales`` None leaves the ``[..., C]`` int32 code sums as
+    they are: the data-parallel learner sums them over the shards
+    exactly and dequantizes once after (:func:`dequant_hist`)."""
     if is_quantized(mode):
-        return dequant_hist(out, scales, mode)
+        return out if scales is None else dequant_hist(out, scales, mode)
     C = out.shape[-1]
     if C == 5:
         g = out[..., 0] + out[..., 1]

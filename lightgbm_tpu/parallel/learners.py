@@ -30,7 +30,26 @@ collectives on ICI/DCN:
   the merged columns.
 
 All three return bit-identical trees on every shard (the reference's
-distributed-determinism requirement, `application.cpp:249-254`).
+distributed-determinism requirement, `application.cpp:249-254`), and the
+data-parallel learner for every shard count, in the int8 modes: the
+shards round their rows against ONE pair of scales (a ``pmax`` of two
+scalars a tree, :func:`global_scales`), what crosses them is the cells'
+integer code sums in two 16-bit limbs (:func:`psum_codes`: exact past
+2^31, where float32 partials lost the integers above 2^24), and the sum
+is dequantized once, as one chip dequantizes its own.  The mode that
+runs is judged on what sums in int32, a SHARD's rows.  So
+``tree_learner=data`` on 1, 2, 4 or 128 shards grows the serial
+learner's trees bit for bit
+(``tests/test_parallel.py::test_quantised_data_parallel_grows_the_serial_tree``;
+on four v5e chips ``chip_smoke.py --chips 4``: 32 identical trees at
+1,048,576 rows, PR 28; before, the first flip came at tree 1).  With
+more rows in all than float32 counts exactly (2^24), the finished
+leaves' rows are counted in integers (``leaf_row_counts``).  On a Pallas
+backend each shard's final route kernel emits its rows' leaf values, so
+the mesh block's score update is the serial path's multiply-add and no
+gather.  Measured on four v5e chips at 4 x 13,281,250 rows x 67 x 63
+bins x 255 leaves (`PERF.md` §5, PR 28): the exchange is 1.0-1.2 ms of a
+0.66-0.71 s iteration.
 
 Deep-wave compaction threads through all three learners via the shared
 ``make_hist_fn`` seam: on the "compact" backend (by name only; the TPU
@@ -65,24 +84,98 @@ shard_map = jax.shard_map
 
 from ..io.device import DeviceData
 from ..learner.serial import (BuiltTree, GrowthParams, apply_hist_wave,
-                              build_tree, make_hist_fn,
-                              split_cache_enabled)
-from ..ops.pallas_histogram import bin_stride
+                              build_tree, default_hist_mode,
+                              effective_hist_mode, emits_row_values,
+                              make_hist_fn,
+                              resolve_backend, split_cache_enabled,
+                              uses_pallas)
+from ..ops.pallas_histogram import (bin_stride, carry_limbs, code_limbs,
+                                    is_quantized, quant_scales)
 from ..ops.split import (K_MIN_SCORE, SplitParams, SplitResult,
                          find_best_splits)
 
 
-def _psum(axis):
-    def psum_fn(x):
-        # trace-time fingerprint: each process traces its own program,
-        # so THIS is where a rank-divergent schedule would be born.
-        # The named_scope stamps the flight-recorder site name into the
-        # HLO op metadata, so profiler captures and HLO dumps name the
-        # collective by the same site the runtime digest uses
-        _fr_record("parallel.learners.hist_psum", "psum", axis, x)
-        with jax.named_scope("collective.hist_psum"):
-            return jax.lax.psum(x, axis)
-    return psum_fn
+# the most shards whose code sums :func:`psum_codes` adds exactly
+MAX_CODE_SHARDS = 511
+
+
+def psum_codes(x: jnp.ndarray, axis: str, num_shards: int):
+    """The sum over ``axis`` of int32 code sums, exact whatever the
+    data: ``-> (hi, lo)`` int32 limbs of each cell's total, ``hi * 2^16
+    + lo`` with ``lo`` in ``[0, 2^16)``, for ``dequant_hist`` to round
+    to float32 once.
+
+    A shard's cell is exact in int32 while its rows are at most
+    ``_INT8_ROW_LIMIT`` (`learner/serial.py`), but the total of 4 x
+    13.28M rows x 127 is past 2^31, and float32 partial sums would lose
+    the integers above 2^24 that the one-chip path keeps.  So a cell
+    crosses the shards as two 16-bit limbs (``code_limbs``: ``hi`` in
+    ``[-2^15, 2^15)``, ``lo`` in ``[0, 2^16)``), each summed in int32:
+    over ``S`` shards ``|sum hi| <= S * 2^15`` and ``sum lo < S *
+    2^16``, nowhere near 2^31.  The carry of ``sum lo`` moves to the
+    high limb, which leaves ``|hi| <= S * (2^15 + 1)``: under the 2^24
+    to which ``dequant_hist`` converts exactly, and under the 2^31 / 127
+    its hi/lo pairs need, for ``S <= 511``.  The limbs of a total are
+    unique, so a total that one chip can hold in int32 dequantizes to
+    the same floats whether one chip summed it or many did:
+    `tests/test_parallel.py` holds 1, 2 and 4 shards to the serial
+    learner's trees, bit for bit."""
+    if num_shards > MAX_CODE_SHARDS:
+        raise ValueError(
+            f"the exact exchange of quantized histograms holds for at most "
+            f"{MAX_CODE_SHARDS} row shards, not {num_shards}")
+    return carry_limbs(*jax.lax.psum(code_limbs(x), axis))
+
+
+class Psum:
+    """The row-sharded learners' sum over the shards of ``axis``:
+    float32 arrays by ``lax.psum``, int32 code sums (the quantized
+    modes' histograms and root totals) exactly by :func:`psum_codes`,
+    as the limb pairs ``dequant_hist`` takes.
+
+    A call records the collective's trace-time fingerprint — each
+    process traces its own program, so THIS is where a rank-divergent
+    schedule would be born — and reduces under the scope
+    ``collective.<what>``, which stamps the flight-recorder site's name
+    into the HLO op metadata: profiler captures and HLO dumps name the
+    collective by the same site the runtime digest uses.  ``reduce`` is
+    the reduction alone, for the overlapped lowering's chunks
+    (`ops/overlap.py` records the one logical reduction itself)."""
+
+    def __init__(self, axis: str, num_shards: int):
+        self.axis, self.num_shards = axis, num_shards
+
+    def reduce(self, x, what: str = "hist_psum"):
+        def one(a):
+            if jnp.issubdtype(a.dtype, jnp.integer):
+                return psum_codes(a, self.axis, self.num_shards)
+            return jax.lax.psum(a, self.axis)
+        with jax.named_scope("collective." + what):
+            return jax.tree.map(one, x)
+
+    def __call__(self, x, what: str = "hist_psum"):
+        _fr_record("parallel.learners." + what, "psum", self.axis, x)
+        return self.reduce(x, what)
+
+    def counts(self, x: jnp.ndarray) -> jnp.ndarray:
+        """The sum over the shards of int32 row counts, in int32 (a
+        count is at most the rows of all shards)."""
+        _fr_record("parallel.learners.count_psum", "psum", self.axis, x)
+        with jax.named_scope("collective.count_psum"):
+            return jax.lax.psum(x, self.axis)
+
+
+def global_scales(grad, hess, axis: str) -> jnp.ndarray:
+    """The quantized modes' rounding scales ``[2] (max|g|, max|h|)``
+    over ALL shards' rows: every shard rounds a row to the code the
+    serial learner gives it, so the code sums add up to the serial
+    histogram whatever the number of shards (a shard's own largest
+    magnitude, `pack_values_q`'s default, makes the tree depend on how
+    the rows were cut)."""
+    local = quant_scales(grad, hess)
+    _fr_record("parallel.learners.scale_pmax", "pmax", axis, local)
+    with jax.named_scope("collective.scale_pmax"):
+        return jax.lax.pmax(local, axis)
 
 
 def _sync_global_best(best: SplitResult, axis: str) -> SplitResult:
@@ -194,13 +287,14 @@ def make_voting_parallel_strategy(data: DeviceData, grad, hess,
                                   params: GrowthParams, feature_mask,
                                   axis: str, num_shards: int, top_k: int,
                                   hist_backend: str = "auto",
-                                  hist_mode=None):
+                                  hist_mode=None, scales=None):
     """PV-Tree: local active-leaf hists -> local vote -> global top-2k
     features -> psum only their histogram columns -> final scan."""
     F = data.num_features
     L = params.num_leaves
     k2 = min(2 * top_k, F)
-    hist_fn = make_hist_fn(data, grad, hess, L, hist_backend, hist_mode)
+    hist_fn = make_hist_fn(data, grad, hess, L, hist_backend, hist_mode,
+                           scales=scales)
     # local constraints scaled 1/S like the reference
     # (voting_parallel_tree_learner.cpp:55-56)
     local_params = params.split._replace(
@@ -346,6 +440,14 @@ def build_tree_distributed(mesh: Mesh, axis: str, learner_type: str,
     fingerprints), with the reduction tail hidden behind the per-chunk
     sibling-subtract/state-scatter.  The root-statistics psum and the
     feature/voting collectives are untouched either way.
+
+    Where the kernels histogram quantized values (an int8 mode that a
+    SHARD's rows keep exact in int32: the mode is judged on
+    ``bins.shape[0]`` inside the shard), the row-sharded learners round
+    against the scales of all shards (:func:`global_scales`) and the
+    data-parallel learner exchanges integer code sums (:class:`Psum`):
+    its trees are the serial learner's, bit for bit, for any number of
+    shards.
     """
     from ..ops.overlap import overlap_enabled
     if overlap is None:
@@ -365,15 +467,26 @@ def build_tree_distributed(mesh: Mesh, axis: str, learner_type: str,
     # silently drift out of sync with DeviceData
     statics = data.tree_flatten()[1]
 
+    # what build_tree will resolve inside the shard_map: the mode on the
+    # rows that sum in int32 (a shard's where rows are sharded), the
+    # backend on shapes that do not depend on the rows
+    mode = effective_hist_mode(hist_mode or default_hist_mode(),
+                               n // num_shards if row_shard else n)
+    backend = resolve_backend(data, params.num_leaves, hist_backend, mode)
+    quantized_kernels = (row_shard and uses_pallas(backend)
+                         and is_quantized(mode))
+
     def step(bins, offs, nb, db, mt, ic, nanb, fg, fo, grad_l, hess_l,
              bag_l, fmask_l):
         data_l = DeviceData(bins, offs, nb, db, mt, ic, nanb, fg, fo,
                             *statics)
         nhf = None
         psum_axis = None
+        scales = (global_scales(grad_l, hess_l, axis) if quantized_kernels
+                  else None)
         if learner_type == "data":
             strategy = None        # serial strategy + histogram psum
-            psum_fn = _psum(axis)
+            psum_fn = Psum(axis, num_shards)
             if overlap:
                 psum_axis = axis   # overlapped wave reduction
         elif learner_type == "feature":
@@ -384,22 +497,27 @@ def build_tree_distributed(mesh: Mesh, axis: str, learner_type: str,
         elif learner_type == "voting":
             strategy = make_voting_parallel_strategy(
                 data_l, grad_l, hess_l, params, fmask_l, axis, num_shards,
-                top_k, hist_backend, hist_mode)
-            psum_fn = _psum(axis)
+                top_k, hist_backend, hist_mode, scales)
+            psum_fn = Psum(axis, num_shards)
         else:
             raise ValueError(learner_type)
         return build_tree(data_l, grad_l, hess_l, params, bag_mask=bag_l,
                           feature_mask=fmask_l, strategy=strategy,
                           psum_fn=psum_fn, hist_backend=hist_backend,
                           num_hist_features=nhf, hist_mode=hist_mode,
-                          psum_axis=psum_axis)
+                          psum_axis=psum_axis, scales=scales)
 
+    # the data-parallel learner on a Pallas backend emits each shard's
+    # rows' leaf values from its final route kernel, as the serial
+    # learner does (no gather in the score update); elsewhere row_value
+    # is empty [0]
+    emits = emits_row_values(learner_type == "data", backend)
     out_spec = BuiltTree(
         feature=P(), threshold_bin=P(), default_left=P(), is_categorical=P(),
         cat_mask=P(), left_child=P(), right_child=P(), gain=P(),
         internal_value=P(), internal_count=P(), leaf_value=P(),
         leaf_count=P(), leaf_depth=P(), num_leaves=P(), row_leaf=vec,
-        row_value=P())   # distributed path scores via gather (empty [0])
+        row_value=vec if emits else P())
 
     in_specs = (vec, P(), P(), P(), P(), P(), P(), P(), P(),
                 vec, vec, vec, P())

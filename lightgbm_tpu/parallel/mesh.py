@@ -208,6 +208,9 @@ class MeshContext:
         devices = devices[:n_mesh]
         self.data_axis = config.data_axis_name
         self.feature_axis = config.feature_axis_name
+        # set by the booster once it knows the row count: whether the
+        # running scores are sharded with the rows (partition.train_rules)
+        self.scores_sharded = False
         if len(shape) == 1:
             self.mesh = Mesh(np.asarray(devices).reshape(shape),
                              (self.data_axis,))
@@ -238,7 +241,8 @@ class MeshContext:
         """The partition-rule table governing every persistent array
         placed on THIS mesh (see ``parallel/partition.py``)."""
         from .partition import train_rules
-        return train_rules(self.data_axis, self.row_sharded)
+        return train_rules(self.data_axis, self.row_sharded,
+                           self.scores_sharded)
 
     def sharding_for(self, name: str) -> NamedSharding:
         """Resolve one persistent array name through the registry —
@@ -276,9 +280,9 @@ class MeshContext:
         return DeviceData(*(placed[f] for f in fields[:len(children)]), *aux)
 
     def place_scores(self, scores) -> jax.Array:
-        """Place a running score state (``scores`` / ``valid/i/scores``)
-        under its registry rule (replicated: host eval reads it per
-        window, and the row count is the unpadded n)."""
+        """Place a running score state under the registry's ``scores``
+        rule: with the rows where ``scores_sharded``, else replicated
+        (the row count is the unpadded n)."""
         return jax.device_put(scores, self.sharding_for("scores"))
 
     def place_valid(self, i: int, dd, scores):

@@ -35,10 +35,12 @@ Name tree (flat, ``/``-joined):
 ``data/<field>``            training ``DeviceData`` arrays (``data/bins``
                             row-sharded for data/voting, replicated for
                             feature-parallel; metadata replicated)
-``scores``                  running train scores ``[n, K]`` (replicated:
-                            host eval/feval/C-API read them per window,
-                            and ``n`` is the UNPADDED row count — row
-                            padding happens inside the jitted build)
+``scores``                  running train scores ``[n, K]`` (``n`` is the
+                            UNPADDED row count — row padding happens
+                            inside the jitted build: row-sharded with
+                            the rows where the shards divide ``n``,
+                            else replicated; host eval/feval/C-API
+                            read them per window either way)
 ``valid/<i>/scores``        running valid scores (replicated)
 ``valid/<i>/data/<field>``  valid ``DeviceData`` arrays (replicated)
 ``grad`` / ``hess``         per-iteration gradient slices (row-sharded
@@ -69,8 +71,8 @@ class PartitionRuleError(ValueError):
 # ---------------------------------------------------------------------------
 # rule tables
 # ---------------------------------------------------------------------------
-def train_rules(data_axis: str = "data",
-                row_sharded: bool = True) -> Tuple[Rule, ...]:
+def train_rules(data_axis: str = "data", row_sharded: bool = True,
+                scores_sharded: bool = False) -> Tuple[Rule, ...]:
     """The training-side rule table for one mesh context.
 
     ``row_sharded`` is the learner-type switch: data/voting-parallel
@@ -78,12 +80,19 @@ def train_rules(data_axis: str = "data",
     slices feature columns inside the shard instead).  The regexes are
     mutually exclusive by construction (``data/bins`` is carved out of
     the metadata catch-all with a lookahead) so the completeness gate
-    can demand EXACTLY one match per name."""
+    can demand EXACTLY one match per name.
+
+    ``scores_sharded``: the running train scores follow the rows of a
+    row-sharded learner where the row count is a multiple of the shard
+    count (no padding rows to leave out): each shard then computes its
+    own rows' gradients and adds its own rows' leaf values, with no
+    gather across shards.  Otherwise they are replicated."""
     row = P(data_axis) if row_sharded else P()
     return (
         ("bins",         r"^data/bins$",            row),
         ("data_meta",    r"^data/(?!bins$)",        P()),
-        ("scores",       r"^scores$",               P()),
+        ("scores",       r"^scores$",
+         P(data_axis) if scores_sharded else P()),
         ("valid_scores", r"^valid/\d+/scores$",     P()),
         ("valid_data",   r"^valid/\d+/data/",       P()),
         ("grad_hess",    r"^(grad|hess)$",          row),

@@ -204,23 +204,40 @@ def test_can_block_on_mesh_and_multiprocess_excluded():
     g._pr = None
 
 
-def test_mesh_scores_and_valid_placed_by_registry():
+@pytest.mark.parametrize("n", [600, 601])
+def test_mesh_scores_and_valid_placed_by_registry(n):
     """The booster's running state is placed under the partition rules
-    at init (scores/valid replicated, bins row-sharded) — the registry
-    is the only placement mechanism on the mesh path.  Checked BEFORE
-    the first dispatch: block outputs may legally carry whatever
-    sharding GSPMD propagated."""
+    at init (valid replicated, bins row-sharded; the train scores with
+    the rows where the shards divide the row count, else replicated) —
+    the registry is the only placement mechanism on the mesh path — and
+    the block hands the scores back as it took them: the second block
+    compiles nothing (left to the partitioner they came back in another
+    layout, and the first step after ``lgb.train`` compiled the block
+    program a second time: 46 s of every four-chip run's set-up)."""
     from lightgbm_tpu.basic import Booster
-    X, y, Xv, yv = _data(n=600)
+    X, y, Xv, yv = _data(n=n)
     tr = lgb.Dataset(X, label=y)
     va = lgb.Dataset(Xv, label=yv, reference=tr)
     bst = Booster(params={**BASE, "tree_learner": "data"}, train_set=tr)
     bst.add_valid(va, "v0")
     g = bst._gbdt
     ctx = g.mesh_ctx
+    shards = ctx.num_data_shards
+    assert ctx.scores_sharded == (n % shards == 0)
     assert g.device_data.bins.sharding == ctx.sharding_for("data/bins")
-    assert g.scores.sharding.is_equivalent_to(ctx.replicated(),
-                                              g.scores.ndim)
+    assert g.scores.sharding == ctx.sharding_for("scores")
+    assert g.scores.sharding.is_equivalent_to(
+        ctx.row_sharding() if n % shards == 0 else ctx.replicated(),
+        g.scores.ndim)
+    compiles = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, *_a, **_k: compiles.append(event)
+        if event == "/jax/core/compile/backend_compile_duration" else None)
+    g.train(1)
+    assert g.scores.sharding == ctx.sharding_for("scores")
+    first = len(compiles)
+    g.train(1)
+    assert len(compiles) == first, "the second block compiled a program"
     assert g._valid_scores[0].sharding.is_equivalent_to(
         ctx.replicated(), g._valid_scores[0].ndim)
     assert g._valid_device[0].bins.sharding.is_equivalent_to(

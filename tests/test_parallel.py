@@ -240,3 +240,129 @@ def test_dryrun_multichip_raises_with_too_few_devices():
         dryrun_multichip(need)
     assert "XLA_FLAGS" in str(e.value) and "JAX_PLATFORMS" in str(e.value)
     assert f"device_count={need}" in str(e.value)
+
+
+# ---------------------------------------------------------------------------
+# one tree whatever the shards (ISSUE 28): the int8 modes under
+# tree_learner=data
+# ---------------------------------------------------------------------------
+TREE_FIELDS = ("feature", "threshold_bin", "default_left", "left_child",
+               "right_child", "gain", "internal_value", "internal_count",
+               "leaf_value", "leaf_count", "leaf_depth", "num_leaves",
+               "row_leaf")
+
+
+@pytest.fixture(scope="module")
+def uneven():
+    """Rows whose largest gradient and hessian differ from shard to
+    shard: a shard's own scales would round its rows to other codes."""
+    n = 2048
+    X, y = _data(n, 8, seed=4)
+    rng = np.random.RandomState(9)
+    ds = BinnedDataset.from_raw(X, Config.from_params({"max_bin": 63}))
+    grad = -(y - y.mean())
+    grad[:n // 4] *= 0.37
+    hess = (0.5 + rng.rand(n)).astype(np.float32)
+    hess[n // 2:] *= 0.61
+    p = GrowthParams(num_leaves=15, split=SplitParams(
+        min_data_in_leaf=10, min_sum_hessian_in_leaf=0.0))
+    return to_device(ds), jnp.asarray(grad), jnp.asarray(hess), p
+
+
+@pytest.fixture(scope="module")
+def serial_trees(uneven):
+    dd, grad, hess, p = uneven
+    return {mode: jax.jit(lambda g, h, m=mode: build_tree(
+        dd, g, h, p, hist_backend="pallas", hist_mode=m))(grad, hess)
+        for mode in ("int8", "int8h", "int8hh")}
+
+
+@pytest.mark.parametrize("overlap", [True, False], ids=["overlap", "plain"])
+@pytest.mark.parametrize("shards", [1, 2, 4])
+@pytest.mark.parametrize("mode", ["int8", "int8h", "int8hh"])
+def test_quantised_data_parallel_grows_the_serial_tree(
+        eight_devices, uneven, serial_trees, mode, shards, overlap):
+    """Global scales, integer code sums across the shards, one
+    dequantisation: every field of the tree, gains and leaf values
+    included, is the serial learner's bit for bit."""
+    dd, grad, hess, p = uneven
+    dist = build_tree_distributed(make_mesh(shards), "data", "data", dd,
+                                  grad, hess, p, hist_backend="pallas",
+                                  hist_mode=mode, overlap=overlap)
+    for name in TREE_FIELDS:
+        np.testing.assert_array_equal(
+            np.asarray(getattr(dist, name)),
+            np.asarray(getattr(serial_trees[mode], name)), err_msg=name)
+
+
+def test_code_sums_cross_the_shards_without_wrapping(eight_devices):
+    """``psum_codes``: four shards' int32 cells from the whole int32
+    range, whose totals pass 2^31, come out as the limbs of the true
+    total, and ``dequant_hist`` rounds it to float32 once (what an
+    int32 -> float32 conversion makes of a total that fits)."""
+    from lightgbm_tpu.ops.pallas_histogram import dequant_hist
+    from lightgbm_tpu.parallel.learners import psum_codes, shard_map
+    from jax.sharding import PartitionSpec as P
+    rng = np.random.RandomState(2)
+    x = rng.randint(-2**31 + 1, 2**31 - 1, size=(4, 4096, 3), dtype=np.int64)
+    x[:, :8] = 2**31 - 1                     # every shard at the bound
+    x[:, 8:16] = -2**31 + 1
+    x[:, 16:24] = np.array([2**24 + 1, 1, 0, 0])[:, None, None]
+    total = x.sum(axis=0)
+    assert np.abs(total).max() > 2**32
+    f = shard_map(lambda s: psum_codes(s[0], "data", 4), mesh=make_mesh(4),
+                  in_specs=(P("data"),), out_specs=P(), check_vma=False)
+    hi, lo = f(jnp.asarray(x.astype(np.int32)))
+    assert lo.dtype == hi.dtype == jnp.int32
+    assert int(lo.min()) >= 0 and int(lo.max()) < 2**16
+    np.testing.assert_array_equal(
+        np.asarray(hi).astype(np.int64) * 2**16 + np.asarray(lo), total)
+    # int8: each column times scale / 127; with a scale of 127 the
+    # floats are the totals rounded to nearest (int64 -> float32)
+    got = dequant_hist((hi, lo), jnp.asarray([127.0, 127.0]), "int8")
+    np.testing.assert_array_equal(np.asarray(got), total.astype(np.float32))
+    # one chip's int32 goes through the same limbs
+    one = dequant_hist(jnp.asarray(x[1].astype(np.int32)),
+                       jnp.asarray([127.0, 127.0]), "int8")
+    np.testing.assert_array_equal(np.asarray(one), x[1].astype(np.float32))
+    with pytest.raises(ValueError, match="at most 511"):
+        psum_codes(jnp.zeros(4, jnp.int32), "data", 512)
+
+
+@pytest.mark.parametrize("shard_over", [False, True],
+                         ids=["global_over", "shard_over"])
+def test_int8_mode_is_judged_on_a_shards_rows(eight_devices, monkeypatch,
+                                              shard_over):
+    """What sums in int32 is a shard's rows.  All rows over the bound
+    and each shard under it: the int8 mode runs, the gauge says so, no
+    ``degrade`` event, and the trees are the serial learner's at the
+    same mode.  A shard over it: the float mode runs, and says so."""
+    from lightgbm_tpu import obs
+    from lightgbm_tpu.learner import serial
+    monkeypatch.setenv("LGBM_TPU_HIST_BACKEND", "pallas")
+    X, yb = _data(n=2001, f=6, seed=3)
+    y = (yb > 0).astype(np.float32)
+    params = {"objective": "binary", "num_leaves": 7, "min_data_in_leaf": 10,
+              "hist_mode": "int8h", "verbose": -1}
+    want = lgb.train(params, lgb.Dataset(X, label=y), 3,
+                     verbose_eval=False)._gbdt.save_model_to_string()
+    monkeypatch.setattr(serial, "_INT8_ROW_LIMIT", 400 if shard_over else 600)
+    obs.reset()
+    obs.enable()
+    try:
+        bst = lgb.train({**params, "tree_learner": "data", "mesh_shape": [4]},
+                        lgb.Dataset(X, label=y), 3, verbose_eval=False,
+                        keep_training_booster=True)
+        s = obs.summary()
+    finally:
+        obs.reset()
+    assert bst._gbdt.mesh_ctx.num_data_shards == 4      # 501 rows a shard
+    if shard_over:
+        assert bst._gbdt.hist_mode == s["gauges"]["gbdt.hist_mode"] == "hhilo"
+        assert s["gauges"]["gbdt.hist_mode_requested"] == "int8h"
+        assert s["events"]["degrade:hist_mode"] == 1
+    else:
+        assert bst._gbdt.hist_mode == s["gauges"]["gbdt.hist_mode"] == "int8h"
+        assert "gbdt.hist_mode_requested" not in s["gauges"]
+        assert not [k for k in s["events"] if k.startswith("degrade:")]
+        assert bst._gbdt.save_model_to_string() == want

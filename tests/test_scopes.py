@@ -248,3 +248,89 @@ def test_no_scope_stands_between_a_kernel_and_its_jitted_wrapper():
     assert stacks["pallas_call"] == ""
     assert {"tree.compact.plan", "tree.compact.regroup"} <= set(
         stacks.values())
+
+
+# ---------------------------------------------------------------------------
+# the row-sharded exchange (ISSUE 28): collective.* on the mesh block
+# ---------------------------------------------------------------------------
+COLLECTIVES = ["collective.hist_psum", "collective.root_psum",
+               "collective.scale_pmax"]
+
+
+def _lower_mesh_block(overlap: bool):
+    """The length-1 block program of a two-shard ``tree_learner=data``
+    booster on the kernel path (interpreted off-TPU) at ``int8h``."""
+    X, y = _data()
+    params = {"objective": "binary", "num_leaves": 15, "max_bin": 15,
+              "min_data_in_leaf": 2, "verbose": -1, "tree_learner": "data",
+              "mesh_shape": [2], "hist_mode": "int8h"}
+    ds = lgb.Dataset(X, label=y, params={"max_bin": 15})
+    g = lgb.Booster(params=params, train_set=ds)._gbdt
+    assert g.hist_backend == "pallas" and g.mesh_ctx is not None
+    return g._make_block_fn(1).lower(
+        g.device_data, g._bins_t, tuple(g._valid_device), g.scores,
+        tuple(g._valid_scores), jnp.float32(0.1), jnp.int32(0),
+        jnp.int32(1))
+
+
+@pytest.fixture(scope="module", params=[True, False],
+                ids=["overlap", "plain"])
+def compiled_mesh(request):
+    """``(optimized HLO with the scopes, with jax.named_scope a no-op,
+    the unscoped lowering's own text)`` of the mesh block, for either
+    lowering of the wave reduction.  The persistent compile cache is
+    off meanwhile: its key leaves metadata out, so the second compile
+    would be handed the first one's program, names and all."""
+    if len(jax.devices()) < 2:
+        pytest.skip("needs 2 virtual devices")
+    from jax.experimental.compilation_cache import compilation_cache
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()     # or the switch is not looked at
+    try:
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setenv("LGBM_TPU_HIST_BACKEND", "pallas")
+            mp.setenv("LGBM_TPU_OVERLAP", "1" if request.param else "0")
+            texts = []
+            for scoped in (True, False):    # one call site: the program
+                jax.clear_caches()          # text holds its line numbers
+                if not scoped:
+                    mp.setattr(jax, "named_scope",
+                               lambda name: contextlib.nullcontext())
+                low = _lower_mesh_block(request.param)
+                texts.append(low.compile().as_text())
+            texts.append(low.as_text(debug_info=True))
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        compilation_cache.reset_cache()
+        jax.clear_caches()
+    return tuple(texts)
+
+
+@pytest.mark.parametrize("scope", COLLECTIVES)
+def test_the_exchange_is_named_on_the_optimized_program(compiled_mesh,
+                                                        scope):
+    """Every all-reduce of the data-parallel block carries a
+    ``collective.*`` scope in the optimized HLO's metadata, whichever
+    lowering runs: the wave reduction (both lowerings one name), the
+    root statistics, the scales."""
+    scoped, plain, plain_lowered = compiled_mesh
+    named = [line for line in scoped.splitlines()
+             if re.search(r" all-reduce(-start)?\(", line)]
+    assert named and all("collective." in line for line in named), named
+    assert any(scope + "/" in line for line in named), scope
+    assert scope not in plain and scope not in plain_lowered
+
+
+def test_the_collective_scopes_leave_the_compiled_block_as_it_was(
+        compiled_mesh):
+    def bare(text):
+        """Without metadata and without the instructions' names, which
+        are made from it (``%broadcast_in_dim.987`` with the scopes,
+        ``%broadcast_in_dim_broadcast_in_dim.992`` without): every
+        operation, shape, layout and attribute, in the order they run."""
+        text = re.sub(r", metadata=\{[^}]*\}", "", text)
+        return re.sub(r"%[\w\-.]+", "%", text)
+    scoped, plain = (bare(text) for text in compiled_mesh[:2])
+    assert "collective." not in scoped and "all-reduce(" in scoped
+    assert scoped == plain
