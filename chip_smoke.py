@@ -165,6 +165,17 @@ def check_quiet_path(summary: dict) -> None:
         f"attempts={c.get('retry.device_dispatch.attempts', 0)}")
 
 
+def say_tiling(summary) -> None:
+    """The grids the traced histogram calls took, from the program's
+    gauges: ``hist.tiling.<cols>`` = ``<feat_tile>x<row tile>`` and the
+    largest share of padded features, ``hist.feature_pad_pct``."""
+    tiling = {k: v for k, v in sorted(summary["gauges"].items())
+              if k.startswith("hist.")}
+    say(f"histogram grids: {tiling}")
+    check(any(k.startswith("hist.tiling.") for k in tiling),
+          "no hist.tiling.<cols> gauge: no histogram kernel was traced")
+
+
 # ---------------------------------------------------------------------------
 # one chip
 # ---------------------------------------------------------------------------
@@ -189,6 +200,7 @@ def phase_train(jax, lgb, obs, X, y):
     backend = after["gauges"].get("gbdt.hist_backend")
     mode = after["gauges"].get("gbdt.hist_mode")
     say(f"resolved backend: {backend}  hist mode: {mode}")
+    say_tiling(after)
     check(backend == "pallas" and g.hist_backend == "pallas",
           f"resolved histogram backend is {backend!r}, not 'pallas'")
     blocks = sum(span_count(after, k) - span_count(before, k)
@@ -472,6 +484,7 @@ def run_four_chips(jax, seed: int) -> None:
     (model_s, _, mode), (model_d, _, _) = serial_and_data(BLOCK)
     check(span_count(obs.summary(), "gbdt.iteration") == 0,
           "a run left the fused block path")
+    say_tiling(obs.summary())
     if mode.startswith("int8"):
         trees_s, trees_d = (m[m.index("Tree=0"):m.index("feature importances:")]
                             for m in (model_s, model_d))

@@ -609,6 +609,35 @@ class GBDT:
             gauge_set("gbdt.hist_mode_requested", asked_mode)
             obs_event("degrade", "hist_mode", requested=asked_mode,
                       effective=hist_mode, rows=int(rows))
+        from ..learner.serial import uses_pallas
+        if uses_pallas(backend):
+            self._record_tiling(hist_mode, rows)
+
+    def _record_tiling(self, hist_mode: str, rows: int) -> None:
+        """The grid of every wave's wide histogram call, from the rule
+        the kernels take it from (``ops/vmem.hist_tiling``) at the
+        shapes they will see (``rows``: a shard's under a row-sharded
+        learner): gauges ``hist.tiling.<cols>`` =
+        ``"<feat_tile>x<row tile>"`` and ``hist.feature_pad_pct``, the
+        largest share of all-zero features a wave contracts."""
+        from ..learner.serial import stage_plan
+        from ..obs import gauge_set
+        from ..ops.pallas_histogram import DEFAULT_ROW_TILE
+        from ..ops.vmem import (bin_stride, col_layout, hist_tiling,
+                                round_up)
+        dd = self.device_data
+        F, B = dd.num_groups, bin_stride(dd.group_max_bins)
+        n_pad = round_up(int(rows), DEFAULT_ROW_TILE)
+        plan, A_tail = stage_plan(self.growth.num_leaves,
+                                  self.growth.wave_size)
+        F_widest = F
+        for A in sorted({*plan, A_tail}):
+            C, _, cols = col_layout(A, hist_mode)
+            T, feat_tile, F_grid = hist_tiling(F, n_pad, B, cols, C,
+                                               DEFAULT_ROW_TILE)
+            gauge_set(f"hist.tiling.{cols}", f"{feat_tile}x{T}")
+            F_widest = max(F_widest, F_grid)
+        gauge_set("hist.feature_pad_pct", 100.0 * (F_widest - F) / F)
 
     def _setup_metrics(self) -> None:
         c = self.config

@@ -79,9 +79,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .pallas_histogram import (DEFAULT_ROW_TILE, _col_layout, _onehot_bins,
-                               _pick_row_tile, _weighted_cols, bin_stride,
-                               combine_hist_cols, is_quantized)
-from .vmem import feat_tiling
+                               _weighted_cols, bin_stride,
+                               combine_hist_cols, is_quantized, pad_features)
+from .vmem import hist_tiling
 
 # leaf slots per compacted tile group.  For the default C=4 int8h mode,
 # C*32 = 128 fills the lane dimension exactly, so no output column is
@@ -280,7 +280,10 @@ def hist_active_compact(bins_t: jnp.ndarray,
     Cc, Gp, cols = _col_layout(G, mode)
     assert Cc == C and Gp == G, (Cc, C, Gp)
     seeded = acc is not None
-    T = _pick_row_tile(n_pad, B, cols, C, row_tile, seeded)
+    # the grid: identical model to the wide kernel's, at the group
+    # column count
+    T, feat_tile, F_grid = hist_tiling(F_pad, n_pad, B, cols, C, row_tile,
+                                       seeded)
     assert n_pad % T == 0, (n_pad, T)
     pad_cols = cols - C * Gp
 
@@ -293,9 +296,6 @@ def hist_active_compact(bins_t: jnp.ndarray,
         src, tile_group, group_active = compact_plan(
             row_leaf.astype(jnp.int32), active.astype(jnp.int32),
             num_leaf_slots, T)
-    # feature tiling: identical VMEM model to the wide kernel, at the
-    # group column count
-    feat_tile, F_grid = feat_tiling(F_pad, B, cols, T, C, seeded)
     with jax.named_scope("tree.compact.regroup"):
         sc = jnp.maximum(src, 0)
         # the regroup gather: one pass over the bins/value streams
@@ -305,8 +305,7 @@ def hist_active_compact(bins_t: jnp.ndarray,
         vals_c = jnp.take(vals, sc, axis=1)              # [C, n_c]
         leaf_c = jnp.where(src >= 0, row_leaf.astype(jnp.int32)[sc],
                            -1)[None, :]                  # [1, n_c]
-        if F_grid != F_pad:
-            bins_c = jnp.pad(bins_c, ((0, F_grid - F_pad), (0, 0)))
+        bins_c = pad_features(bins_c, F_grid)
     nft = F_grid // feat_tile
     n_c = bins_c.shape[1]
 
@@ -368,8 +367,8 @@ def compact_raw_layout(n_pad: int, num_active: int, num_features: int,
     G = COMPACT_GROUP
     n_groups = -(-num_active // G)
     C, Gp, cols = _col_layout(G, mode)
-    T = _pick_row_tile(n_pad, B, cols, C, row_tile, seeded=True)
-    _, F_grid = feat_tiling(num_features, B, cols, T, C, seeded=True)
+    _, _, F_grid = hist_tiling(num_features, n_pad, B, cols, C, row_tile,
+                               seeded=True)
     dtype = jnp.int32 if is_quantized(mode) else jnp.float32
     return ((n_groups + 1) * F_grid * B, cols), dtype
 

@@ -69,21 +69,15 @@ from jax.experimental.pallas import tpu as pltpu
 # caps) is SHARED across every Pallas kernel — ops/vmem.py is its home
 # (and what tools/memcheck's MEM004 keys on); the old underscore names
 # stay bound here for the kernels and tests that grew up on them
-from .vmem import (VMEM_BUDGET_BYTES as _VMEM_BUDGET_BYTES,
-                   cell_vmem_bytes as _cell_vmem_bytes,
-                   feat_tile_cap as _feat_tile_cap, feat_tiling,
-                   hist_cell_ok,
-                   next_pow2 as _next_pow2,
-                   pick_row_tile as _pick_row_tile,
-                   round_up as _round_up)
+from .vmem import (feat_tile_cap as _feat_tile_cap, hist_cell_ok,
+                   hist_tiling, round_up as _round_up)
 
 LANE = 128
-# rows per kernel grid step; env-tunable for A/B perf work.  2048 beats
-# 1024 by ~5% on the bench (fewer grid steps to amortize per-tile fixed
-# cost); kernels halve it per-config when the VMEM cell won't fit (high
-# bin counts).  transpose_bins/pack_values pad to this, so any power-of-
-# two tile <= it divides n_pad; pallas_route imports it for the same
-# reason.
+# the largest row tile a kernel grid step may take; env-tunable for A/B
+# perf work.  Each call takes the tile of its least modelled time among
+# the powers of two from this down to 1024 (ops/vmem.hist_tiling).
+# transpose_bins/pack_values pad to this, so any power-of-two tile <= it
+# divides n_pad; pallas_route imports it for the same reason.
 DEFAULT_ROW_TILE = int(os.environ.get("LGBM_TPU_ROW_TILE", 2048))
 
 
@@ -132,7 +126,7 @@ def pallas_config_ok(max_bins: int, num_leaves: int, mode: str) -> bool:
     # the staged wave plan (learner/serial.py stage_plan) caps active
     # slots at 128 regardless of num_leaves; the minimum feature tile
     # of 8 must fit the full VMEM model at the 1024-row fallback tile
-    # (_pick_row_tile halves down to it) — ADVICE r2: the accumulator
+    # (ops/vmem.row_tiles goes down to it) — ADVICE r2: the accumulator
     # alone under-counts
     return hist_cell_ok(max_bins, min(max(1, num_leaves // 2), 128), mode)
 
@@ -425,6 +419,16 @@ def _weighted_cols(m_bool: jnp.ndarray, vals: jnp.ndarray, n_cols: int,
     return vw
 
 
+def pad_features(bins_t: jnp.ndarray, F_grid: int) -> jnp.ndarray:
+    """``[F_pad, n_pad] -> [F_grid, n_pad]``: all-zero feature rows up
+    to a call's whole number of feature tiles.  One expression for one
+    ``F_grid``: the calls of a tree that share it share the copy."""
+    F_pad = bins_t.shape[0]
+    if F_grid == F_pad:
+        return bins_t
+    return jnp.pad(bins_t, ((0, F_grid - F_pad), (0, 0)))
+
+
 def _hist_kernel(active_ref, bins_ref, vals_ref, leaf_ref,
                  *refs, n_cols: int, B: int, pad_cols: int,
                  seeded: bool = False):
@@ -528,17 +532,16 @@ def hist_active_pallas(bins_t: jnp.ndarray,
 
     _, A_pad, cols = _col_layout(A, mode)
     seeded = acc is not None
-    T = _pick_row_tile(n_pad, B, cols, C, row_tile, seeded)
-    assert n_pad % T == 0, (n_pad, T)
-    pad_cols = cols - C * A_pad
-    # feature tile: bounded by the per-grid-cell VMEM footprint (f32
+    # the grid: bounded by the per-grid-cell VMEM footprint (f32
     # accumulator + the bf16 one-hot + the bins tile — ADVICE r2: the
     # accumulator alone under-counts by the one-hot's tens of MB on wide
     # low-bin datasets; a seeded call also counts the carried
     # accumulator it streams in)
-    feat_tile, F_grid = feat_tiling(F_pad, B, cols, T, C, seeded)
-    if F_grid != F_pad:
-        bins_t = jnp.pad(bins_t, ((0, F_grid - F_pad), (0, 0)))
+    T, feat_tile, F_grid = hist_tiling(F_pad, n_pad, B, cols, C, row_tile,
+                                       seeded)
+    assert n_pad % T == 0, (n_pad, T)
+    pad_cols = cols - C * A_pad
+    bins_t = pad_features(bins_t, F_grid)
 
     leaf = jnp.full((1, n_pad), -1, jnp.int32)
     leaf = jax.lax.dynamic_update_slice(
@@ -600,8 +603,7 @@ def hist_raw_layout(n_pad: int, num_active: int, num_features: int,
     accumulator for this config — the shape a streamed fold carries
     across blocks (``acc`` / ``raw=True`` in :func:`hist_active_pallas`).
 
-    The kernel's own tile arithmetic for a SEEDED call (row tile and
-    feature tile from the shared VMEM model, ``ops/vmem.feat_tiling``),
+    The kernel's own grid for a SEEDED call (``ops/vmem.hist_tiling``),
     so the carry can be allocated before the first call.
     ``num_features`` must equal the
     bins' F_pad (streamed sources transpose with ``feat_tile=None``, so
@@ -611,8 +613,8 @@ def hist_raw_layout(n_pad: int, num_active: int, num_features: int,
     """
     B = bin_stride(max_bins)
     C, A_pad, cols = _col_layout(num_active, mode)
-    T = _pick_row_tile(n_pad, B, cols, C, row_tile, seeded=True)
-    _, F_grid = feat_tiling(num_features, B, cols, T, C, seeded=True)
+    _, _, F_grid = hist_tiling(num_features, n_pad, B, cols, C, row_tile,
+                               seeded=True)
     dtype = jnp.int32 if is_quantized(mode) else jnp.float32
     return (F_grid * B, cols), dtype
 
@@ -851,13 +853,9 @@ def hist_route_pallas(bins_t, vals, leaf2, active,
     B = bin_stride(max_bins)
 
     _, A_pad, cols = _col_layout(A, mode)
-    # the fused kernel holds ALL stored columns in one tile: halve the
-    # row tile until that cell fits the VMEM budget
-    T = row_tile
-    while T > 1024 and (
-            n_pad % T != 0
-            or _cell_vmem_bytes(F_pad, B, cols, T, C) > _VMEM_BUDGET_BYTES):
-        T //= 2
+    # the fused kernel holds ALL stored columns in one tile: the largest
+    # row tile at which that cell fits the VMEM budget
+    T, _, _ = hist_tiling(F_pad, n_pad, B, cols, C, row_tile, whole=True)
     assert n_pad % T == 0 and leaf2.shape == (2, n_pad)
     pad_cols = cols - C * A_pad
     L = feature.shape[0]

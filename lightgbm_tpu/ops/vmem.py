@@ -19,6 +19,10 @@ arithmetic, pure int math with **no jax import**, so:
   predicates; any module that dispatches a Pallas kernel must reference
   one of them (or any ``*vmem*`` helper) on its guard path.
 
+The grid of a histogram kernel call (row tile, feature tile, padded
+feature count) is chosen here too: :func:`hist_tiling`, the least
+modelled time over the cells this model admits.
+
 Budget provenance: 12 MiB per grid cell.  The previous spread-matmul
 kernel demonstrably ran larger footprints on the v5e, so 12 MiB under
 the ~16 MB/core ceiling leaves room for the streamed inputs'
@@ -35,6 +39,19 @@ LANE = 128
 # per-grid-cell VMEM budget for the histogram-family kernels' resident
 # arrays (f32 accumulator + bf16 one-hot + bins tile + value columns)
 VMEM_BUDGET_BYTES = 12 * 1024 * 1024
+
+# The time model a histogram call's grid is chosen by (hist_tiling): a
+# grid cell costs its MACs at HIST_MAC_FS each plus HIST_CELL_FS.
+# Provenance (PERF.md section 5, builder's chip run, PR 29): the wide
+# kernel alone on a v5e at [67, 13,281,280], int8h, 31 grids over 128 /
+# 256 / 512 output columns at 63 and 255 bins: least squares give 5.15-
+# 5.27 fs a MAC (the int8 peak is 5.09) and 0.305-0.307 us a cell; the
+# model reads every grid within 5% and ranks each wave's grids as the
+# chip does.  The bf16 modes read 10.19 fs and 0.36 us (7 grids, hilo):
+# twice the MAC, the same order of cell, and the same choice at every
+# shape read.  Measured facts, not knobs: no environment variable.
+HIST_MAC_FS = 5.2
+HIST_CELL_FS = 0.306e9
 
 # The sanctioned VMEM-guard predicate names: tools/memcheck rule MEM004
 # parses this tuple (statically — no import) and requires every module
@@ -103,37 +120,68 @@ def feat_tile_cap(B: int, cols: int, T: int, C: int,
     return ft
 
 
-def feat_tiling(F_pad: int, B: int, cols: int, T: int, C: int,
-                seeded: bool = False) -> tuple[int, int]:
-    """``-> (feat_tile, F_grid)`` of a histogram kernel call: the whole
-    feature set in one tile when it fits, else the largest multiple of
-    8 that does (Mosaic's sublane rule — a full-array block is exempt),
-    and the feature count padded to a whole number of tiles.  Shared by
-    the wide and compacted kernels and their raw-layout twins, so a
-    fold's carry can never disagree with the kernel that fills it."""
-    ft_cap = feat_tile_cap(B, cols, T, C, seeded)
-    feat_tile = F_pad if ft_cap >= F_pad else max(8, (ft_cap // 8) * 8)
-    return feat_tile, round_up(F_pad, feat_tile)
+def hist_call_fs(feat_tile: int, F_grid: int, n_pad: int, B: int,
+                 cols: int, T: int) -> float:
+    """Modelled time [fs] of one histogram kernel call on this grid."""
+    cells = (F_grid // feat_tile) * (n_pad // T)
+    return cells * (feat_tile * B * cols * T * HIST_MAC_FS + HIST_CELL_FS)
 
 
-def pick_row_tile(n_pad: int, B: int, cols: int, C: int,
-                  requested: int, seeded: bool = False) -> int:
-    """Largest power-of-two tile <= ``requested`` that divides ``n_pad``
-    and whose minimum-feature-tile grid cell fits the VMEM budget."""
-    T = requested
-    while T > 1024 and (
-            n_pad % T != 0
-            or cell_vmem_bytes(8, B, cols, T, C,
-                               seeded) > VMEM_BUDGET_BYTES):
-        T //= 2
-    return T
+def row_tiles(n_pad: int, requested: int) -> list[int]:
+    """The row tiles a histogram call may use: the powers of two from
+    ``requested`` down to 1,024 that divide ``n_pad`` (``requested``
+    itself where it is 1,024 or less)."""
+    tiles = [requested]
+    while tiles[-1] > 1024:
+        tiles.append(tiles[-1] // 2)
+    return [T for T in tiles if n_pad % T == 0] or tiles[-1:]
+
+
+def hist_tiling(F_pad: int, n_pad: int, B: int, cols: int, C: int,
+                requested: int, seeded: bool = False,
+                whole: bool = False) -> tuple[int, int, int]:
+    """``-> (T, feat_tile, F_grid)``: the grid of one histogram kernel
+    call, chosen for the least modelled time (:func:`hist_call_fs`)
+    over the cells the VMEM model admits.
+
+    Candidates: a row tile ``T`` of :func:`row_tiles`; at each, the
+    whole feature set in one tile where that cell fits the budget (a
+    full-array block is exempt from Mosaic's sublane rule), else every
+    multiple of 8 up to :func:`feat_tile_cap`, the feature count padded
+    to a whole number of tiles (``F_grid``).  ``whole``: the whole set
+    or nothing (the fused route+histogram kernel reads any feature's
+    column from the one tile).  Ties go to the larger row tile, then
+    the larger feature tile.  Where no cell fits (a config the
+    feasibility predicates turn away) the smallest one is returned and
+    the compiler is left to refuse it.
+
+    Shared by the wide, compacted and fused kernels and the raw-layout
+    twins of the first two, so a fold's carry can never disagree with
+    the kernel that fills it."""
+    tiles = row_tiles(n_pad, requested)
+    grids = []
+    for T in tiles:
+        if cell_vmem_bytes(F_pad, B, cols, T, C, seeded) \
+                <= VMEM_BUDGET_BYTES:
+            feat_tiles = [F_pad]
+        elif whole:
+            feat_tiles = []
+        else:
+            feat_tiles = range(8, feat_tile_cap(B, cols, T, C, seeded) + 1,
+                               8)
+        grids += [(T, ft, round_up(F_pad, ft)) for ft in feat_tiles]
+    if not grids:
+        ft = F_pad if whole else 8
+        return tiles[-1], ft, round_up(F_pad, ft)
+    return min(grids, key=lambda g: (
+        hist_call_fs(g[1], g[2], n_pad, B, cols, g[0]), -g[0], -g[1]))
 
 
 def hist_cell_ok(max_bins: int, active_slots: int, mode: str,
                  row_tile: int = 1024, extra_bytes: int = 0) -> bool:
     """The generic histogram-kernel feasibility predicate: does the
     minimum-feature-tile grid cell at ``active_slots`` output slots fit
-    the budget (at the 1024-row fallback tile ``pick_row_tile`` halves
+    the budget (at the 1024-row fallback tile ``row_tiles`` goes
     down to)?  ``extra_bytes`` covers kernel-specific residents (the
     compacted kernel's group-active slice + leaf row)."""
     B = bin_stride(max_bins)

@@ -106,6 +106,57 @@ def test_kernel_int8_matches_scatter(mode):
     assert np.all(np.abs(p[..., 1] - s[..., 1]) <= (m + 1) * step_h / 2)
 
 
+@pytest.mark.parametrize("A", [8, 64])
+@pytest.mark.parametrize("mode", ["int8h", "hilo"])
+def test_criteo_width_runs_the_cells_tiles(mode, A):
+    """F = 67 at 63 bins, the benchmark cells' width, at a 128- and a
+    256-column wave: the grid `ops/vmem.hist_tiling` picks here is the
+    one it picks at the cells' 13.28M rows, so the tiles the cells run
+    are tiles a test runs.  int8h: every int32 code sum equals the
+    scatter oracle's sum of the same codes; hilo: at its tolerance."""
+    from lightgbm_tpu.ops.vmem import col_layout, hist_tiling
+    rng = np.random.RandomState(67)
+    n, F, L, max_bins = 3000, 67, 255, 63
+    bins = rng.randint(0, max_bins, size=(n, F)).astype(np.uint8)
+    grad = rng.normal(size=n).astype(np.float32)
+    hess = rng.uniform(0.1, 1.0, size=n).astype(np.float32)
+    row_leaf = jnp.asarray(rng.randint(-1, L, size=n).astype(np.int32))
+    active = jnp.asarray(rng.choice(L, A, replace=False).astype(np.int32))
+    bins_j = jnp.asarray(bins)
+    bt = transpose_bins(bins_j)
+    C, _, cols = col_layout(A, mode)
+    grid = hist_tiling(F, bt.shape[1], 64, cols, C, 2048)
+    assert grid[1:] == hist_tiling(F, 13_281_280, 64, cols, C, 2048)[1:]
+    assert grid[2] <= 72
+
+    def scatter(g, h):
+        return np.asarray(hist_active_scatter(
+            bins_j, jnp.asarray(g), jnp.asarray(h), row_leaf, active,
+            max_bins=max_bins, num_leaf_slots=L))
+
+    if mode == "int8h":
+        vals, _ = pack_values_q(jnp.asarray(grad), jnp.asarray(hess), mode)
+        # scales=None: the [A, F, B, 4] int32 code sums as they are
+        p = np.asarray(hist_active_pallas(
+            bt, vals, row_leaf, active, None, num_features=F,
+            max_bins=max_bins, mode=mode, interpret=True))
+        codes = np.asarray(vals)[:, :n].astype(np.float32)
+        s01, s2 = scatter(codes[0], codes[1]), scatter(codes[2], codes[3])
+        want = np.stack([s01[..., 0], s01[..., 1], s2[..., 0],
+                         s01[..., 2]], axis=-1)
+        np.testing.assert_array_equal(p, want.astype(np.int32))
+    else:
+        vals = pack_values(jnp.asarray(grad), jnp.asarray(hess), mode)
+        p = np.asarray(hist_active_pallas(
+            bt, vals, row_leaf, active, num_features=F,
+            max_bins=max_bins, mode=mode, interpret=True))
+        s = scatter(grad, hess)
+        np.testing.assert_array_equal(p[..., 2], s[..., 2])
+        scale = np.abs(s[..., :2]).max() + 1e-9
+        np.testing.assert_allclose(p[..., :2] / scale, s[..., :2] / scale,
+                                   atol=5e-4)
+
+
 def test_hilo_split_survives_jit():
     """Regression: the hi/lo split must be done by bit-masking — XLA's
     simplifier folds ``x.astype(bf16).astype(f32)`` to a no-op under

@@ -37,6 +37,7 @@ from lightgbm_tpu.ops.vmem import bin_stride, hist_fold_cell_ok
 
 # the reference's HIGGS settings: the width chip_smoke.py trains at
 N, F, MAX_BIN, LEAVES = 1 << 20, 28, 63, 255
+F_CRITEO = 67               # the benchmark cells' width
 N_TREE = 131_072            # whole-tree programs: same widths, fewer rows
 VALUE_ROWS = {"int8h": 4, "hilo": 5}
 
@@ -80,9 +81,9 @@ def _compiled_text(lowered) -> str:
     return lowered.compile().as_text()
 
 
-def _hist_args(s, n, mode, slots):
+def _hist_args(s, n, mode, slots, features=F):
     vdt = jnp.int8 if mode.startswith("int8") else jnp.float32
-    return [s((F, n), jnp.uint8), s((VALUE_ROWS[mode], n), vdt),
+    return [s((features, n), jnp.uint8), s((VALUE_ROWS[mode], n), vdt),
             s((n,), jnp.int32), s((slots,), jnp.int32),
             s((2,), jnp.float32)]
 
@@ -118,14 +119,18 @@ def _growth():
 # ---------------------------------------------------------------------------
 # histogram kernels
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("slots", [8, 32, 128])
+@pytest.mark.parametrize("slots", [8, 32, 64, 128])
 @pytest.mark.parametrize("mode", ["hilo", "int8h"])
-def test_wide_hist_kernel_compiles(one_chip, mode, slots):
+@pytest.mark.parametrize("features", [F, F_CRITEO])
+def test_wide_hist_kernel_compiles(one_chip, features, mode, slots):
+    """Every wave's grid as `ops/vmem.hist_tiling` chooses it at the
+    real row tiles: at the Criteo width a 67-row full-array `u8` block
+    (128 columns) and 24-row blocks (256, 512 columns)."""
     from lightgbm_tpu.ops.pallas_histogram import hist_active_pallas
     s = _shapes(one_chip)
     text = _compiled_text(hist_active_pallas.lower(
-        *_hist_args(s, N, mode, slots), num_features=F, max_bins=MAX_BIN,
-        mode=mode))
+        *_hist_args(s, N, mode, slots, features), num_features=features,
+        max_bins=MAX_BIN, mode=mode))
     assert "tpu_custom_call" in text
 
 
@@ -155,8 +160,9 @@ def test_compact_hist_kernel_compiles(one_chip, seeded):
     (255, 64, "int8h"),
     (255, 128, "int8h"),    # the gate refuses: so does the compiler
 ])
-def test_seeded_wide_fold_gate_agrees_with_compiler(one_chip, max_bin,
-                                                    slots, mode):
+@pytest.mark.parametrize("features", [F, F_CRITEO])
+def test_seeded_wide_fold_gate_agrees_with_compiler(one_chip, features,
+                                                    max_bin, slots, mode):
     """The streamed fold's seeded wide kernel: wherever the static gate
     (`ops/vmem.hist_fold_cell_ok`) admits a cell the chip's compiler
     takes it, and the cell the gate turns away is one the compiler
@@ -164,10 +170,10 @@ def test_seeded_wide_fold_gate_agrees_with_compiler(one_chip, max_bin,
     from lightgbm_tpu.ops.pallas_histogram import (hist_active_pallas,
                                                    hist_raw_layout)
     s = _shapes(one_chip)
-    shape, dtype = hist_raw_layout(N, slots, F, max_bin, mode)
+    shape, dtype = hist_raw_layout(N, slots, features, max_bin, mode)
     lowered = hist_active_pallas.lower(
-        *_hist_args(s, N, mode, slots), s(shape, dtype),
-        num_features=F, max_bins=max_bin, mode=mode, raw=True)
+        *_hist_args(s, N, mode, slots, features), s(shape, dtype),
+        num_features=features, max_bins=max_bin, mode=mode, raw=True)
     if hist_fold_cell_ok(max_bin, slots, mode):
         assert "tpu_custom_call" in _compiled_text(lowered)
     else:

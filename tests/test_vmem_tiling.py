@@ -1,0 +1,158 @@
+"""The rule that chooses a histogram kernel call's grid
+(`ops/vmem.hist_tiling`): pure integer arithmetic, held over the widths
+the repo trains at (HIGGS 28, Criteo 67, MSLR 136, Expo-like 968,
+Epsilon 2,000) at every wave's slot count.
+
+The rule before PR 29 (the largest feature tile that fits, at the row
+tile the minimum tile of 8 admits) stays here as the reference: the new
+rule may never contract more padded features than it did, nor take
+longer by the model it chooses by.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+
+from lightgbm_tpu.ops import vmem
+from lightgbm_tpu.ops.vmem import (VMEM_BUDGET_BYTES, bin_stride,
+                                   cell_vmem_bytes, col_layout,
+                                   feat_tile_cap, hist_call_fs,
+                                   hist_tiling, round_up, row_tiles)
+
+N_PAD = 13_281_280          # the Criteo cells' shard, padded to 2,048
+ROW_TILE = 2048
+VALUE_ROWS = {"int8h": 4, "hilo": 5}
+
+
+def old_rule(F_pad, n_pad, B, cols, C, requested, seeded):
+    """``-> (T, feat_tile, F_grid)`` as `pick_row_tile` + `feat_tiling`
+    chose them through PR 28."""
+    T = requested
+    while T > 1024 and (
+            n_pad % T != 0
+            or cell_vmem_bytes(8, B, cols, T, C, seeded)
+            > VMEM_BUDGET_BYTES):
+        T //= 2
+    cap = feat_tile_cap(B, cols, T, C, seeded)
+    ft = F_pad if cap >= F_pad else max(8, (cap // 8) * 8)
+    return T, ft, round_up(F_pad, ft)
+
+
+@pytest.mark.parametrize("seeded", [False, True], ids=["plain", "seeded"])
+@pytest.mark.parametrize("mode", ["int8h", "hilo"])
+@pytest.mark.parametrize("A", [8, 32, 64, 128])
+@pytest.mark.parametrize("max_bin", [15, 63, 255])
+@pytest.mark.parametrize("F_pad", [8, 28, 67, 72, 136, 968, 2000])
+def test_chosen_grid(F_pad, max_bin, A, mode, seeded):
+    B = bin_stride(max_bin)
+    C, _, cols = col_layout(A, mode)
+    T, ft, F_grid = hist_tiling(F_pad, N_PAD, B, cols, C, ROW_TILE, seeded)
+
+    # a grid the kernel can run
+    assert N_PAD % T == 0 and 1024 <= T <= ROW_TILE
+    assert ft == F_pad or ft % 8 == 0
+    assert F_grid % ft == 0 and F_pad <= F_grid < F_pad + ft
+    # it fits wherever the static gate admits the config, and only there
+    gate = vmem.hist_fold_cell_ok if seeded else vmem.hist_cell_ok
+    fits = cell_vmem_bytes(ft, B, cols, T, C, seeded) <= VMEM_BUDGET_BYTES
+    assert fits == gate(max_bin, A, mode)
+
+    # never more padded work than the rule before, nor more modelled time
+    T0, ft0, F_grid0 = old_rule(F_pad, N_PAD, B, cols, C, ROW_TILE, seeded)
+    assert F_grid <= F_grid0
+    if fits:
+        assert (hist_call_fs(ft, F_grid, N_PAD, B, cols, T)
+                <= hist_call_fs(ft0, F_grid0, N_PAD, B, cols, T0))
+    # the Criteo cells' shape: 67 features are contracted as 72 at most
+    if F_pad == 67 and max_bin == 63:
+        assert F_grid <= 72
+
+    # the carry a streamed fold allocates is the one the kernel fills
+    if seeded and fits:
+        from lightgbm_tpu.ops.pallas_histogram import (hist_active_pallas,
+                                                       hist_raw_layout)
+        shape, dtype = hist_raw_layout(N_PAD, A, F_pad, max_bin, mode)
+        assert shape == (F_grid * B, cols)
+        s = jax.ShapeDtypeStruct
+        vdt = jnp.int8 if mode == "int8h" else jnp.float32
+        out = jax.eval_shape(
+            lambda *a: hist_active_pallas(
+                *a, num_features=F_pad, max_bins=max_bin, mode=mode,
+                raw=True),
+            s((F_pad, N_PAD), jnp.uint8),
+            s((VALUE_ROWS[mode], N_PAD), vdt), s((N_PAD,), jnp.int32),
+            s((A,), jnp.int32), None, s(shape, dtype))
+        assert (out.shape, out.dtype) == (shape, dtype)
+
+
+@pytest.mark.parametrize("n_pad,requested,tiles", [
+    (N_PAD, 2048, [2048, 1024]),
+    (N_PAD, 4096, [2048, 1024]),        # 4,096 does not divide the shard
+    (1 << 20, 4096, [4096, 2048, 1024]),
+    (3 * 1024, 2048, [1024]),
+    (4096, 512, [512]),                 # a tile under 1,024 is taken as is
+    (N_PAD, 1024, [1024]),              # LGBM_TPU_ROW_TILE: the upper bound
+])
+def test_row_tiles(n_pad, requested, tiles):
+    assert row_tiles(n_pad, requested) == tiles
+    T, _, _ = hist_tiling(67, n_pad, 64, 128, 4, requested)
+    assert T in tiles
+
+
+@pytest.mark.parametrize("F_pad,max_bin", [(28, 63), (67, 63), (67, 255)])
+def test_whole_set_or_nothing(F_pad, max_bin):
+    """The fused route+histogram kernel's grid: one tile of every
+    feature at the largest row tile whose cell fits, as its own loop
+    chose before."""
+    B = bin_stride(max_bin)
+    C, _, cols = col_layout(32, "int8h")
+    T, ft, F_grid = hist_tiling(F_pad, N_PAD, B, cols, C, ROW_TILE,
+                                whole=True)
+    assert ft == F_grid == F_pad
+    fitting = [t for t in row_tiles(N_PAD, ROW_TILE)
+               if cell_vmem_bytes(F_pad, B, cols, t, C) <= VMEM_BUDGET_BYTES]
+    assert T == (fitting[0] if fitting else 1024)
+
+
+@pytest.mark.parametrize("n_pad", [4096, 1 << 20])
+@pytest.mark.parametrize("A", [8, 32, 64, 128])
+def test_choice_does_not_depend_on_rows(A, n_pad):
+    """A call's modelled time is its rows times a function of the grid,
+    so a small test runs the tiles the 13.28M-row cell runs."""
+    C, _, cols = col_layout(A, "int8h")
+    assert (hist_tiling(67, n_pad, 64, cols, C, ROW_TILE)[:2]
+            == hist_tiling(67, N_PAD, 64, cols, C, ROW_TILE)[:2])
+
+
+def test_booster_sets_the_tiling_gauges(monkeypatch):
+    """`hist.tiling.<cols>` and `hist.feature_pad_pct` beside
+    `gbdt.hist_backend`, as `chip_smoke.py` prints them: every wave's
+    grid of a 255-leaf tree at the Criteo width, from the kernels' own
+    rule."""
+    import numpy as np
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu import obs
+    monkeypatch.setenv("LGBM_TPU_HIST_BACKEND", "pallas")
+    rng = np.random.RandomState(0)
+    X = rng.normal(size=(3000, 67)).astype(np.float32)
+    y = (X[:, 0] > 0).astype(np.float32)
+    was_on = obs.enabled()
+    obs.reset()
+    obs.enable()
+    try:
+        lgb.Booster({"objective": "binary", "num_leaves": 255,
+                     "max_bin": 63, "hist_mode": "int8h", "verbose": -1},
+                    lgb.Dataset(X, label=y, params={"max_bin": 63}))
+        gauges = obs.summary()["gauges"]
+    finally:
+        if not was_on:
+            obs.disable()
+        obs.reset()
+    assert gauges["gbdt.hist_backend"] == "pallas"
+    want = {"hist.feature_pad_pct": 100.0 * (72 - 67) / 67}
+    for A in (8, 16, 32, 64, 128):
+        C, _, cols = col_layout(A, "int8h")
+        T, ft, _ = hist_tiling(67, 4096, 64, cols, C, ROW_TILE)
+        want[f"hist.tiling.{cols}"] = f"{ft}x{T}"
+    assert {k: v for k, v in gauges.items() if k.startswith("hist.")} == want
+    assert want["hist.tiling.128"] == "67x1024"
+    assert want["hist.tiling.256"] == want["hist.tiling.512"] == "24x2048"
