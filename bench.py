@@ -18,12 +18,11 @@ guarded, see below), bin255, rank63, serve, rank, valid.
 
 Multi-chip (PR 7 + ISSUE 11, ROADMAP items 1/2): the ``multichip``
 leg trains the HIGGS-shape legs data-parallel on 2/4/8-chip meshes on
-the FUSED scan-block path (one dispatch per window) with the
-overlapped wave reduction on/off (``LGBM_TPU_OVERLAP``) plus the
+the FUSED scan-block path (one dispatch per window) plus the
 unfused per-iteration baseline (``LGBM_TPU_MESH_BLOCK=0``), recording
 per-chip scaling efficiency against the 1-chip serial anchor,
 ``fused_speedup`` + the measured dispatch gaps on both dispatch
-modes, and a byte-identity parity gate across all three schedules.
+modes, and a byte-identity parity gate across the two schedules.
 On a 1-chip image it records ``"skipped: devices"`` without touching
 the single-chip headline; ``--dryrun`` re-execs it on a 2-device
 virtual CPU pool as the tier-1 mechanics gate.
@@ -60,8 +59,7 @@ can't hide inside a throughput number and vice versa.
 
 Wave regime: right after the headline leg (and incrementally emitted),
 ``wave_kernel`` records ns/row per active-slot bucket {8, 32, 64, 128}
-for the wide one-hot kernel and the leaf-compacted deep-wave kernel
-(`ops/compact.py`) — the wide kernel's cost growing with the slot
+for the wide one-hot kernel — its cost growing with the slot
 count; not measured on the current tree.  ``python bench.py --dryrun``
 emits the same table at toy shape on CPU (mechanics gate, tier-1).
 
@@ -348,14 +346,12 @@ def valid_leg(leaves, max_bin, f=28):
 
 def wave_microbench(dryrun: bool = False, f: int = None, max_bin: int = None,
                     buckets=(8, 32, 64, 128), rows: int = None):
-    """ns/row per active-slot bucket for the wide one-hot kernel and the
-    leaf-compacted kernel (`ops/compact.py`) — the wide kernel's cost
-    growing with the slot count, tracked per run (not measured on the
-    current tree).
+    """ns/row per active-slot bucket for the wide one-hot kernel — its
+    cost growing with the slot count, tracked per run (not measured on
+    the current tree).
 
-    Returns a list of rows ``{"active": A, "wide_ns_per_row": ...,
-    "compact_ns_per_row": ...}`` (compact only above the slot
-    threshold).  On TPU this times real dispatches at 1M rows; in
+    Returns a list of rows ``{"active": A, "wide_ns_per_row": ...}``.
+    On TPU this times real dispatches at 1M rows; in
     ``dryrun`` (or off-TPU) it runs interpret-mode kernels at toy shape
     — the TABLE mechanics and kernel paths, not throughput.
 
@@ -366,8 +362,6 @@ def wave_microbench(dryrun: bool = False, f: int = None, max_bin: int = None,
     (``north_star.json`` ``wave_kernel_255`` / ``wave_kernel_mslr``)."""
     import jax
     import jax.numpy as jnp
-    from lightgbm_tpu.ops.compact import (compact_slot_threshold,
-                                          hist_active_compact)
     from lightgbm_tpu.ops.pallas_histogram import (hist_active_pallas,
                                                    pack_values,
                                                    transpose_bins)
@@ -389,7 +383,6 @@ def wave_microbench(dryrun: bool = False, f: int = None, max_bin: int = None,
     leaf_p = jnp.asarray(np.pad(leaf, (0, bt.shape[1] - n),
                                 constant_values=-1))
     vals = pack_values(grad, hess, "hilo")
-    thresh = compact_slot_threshold()
 
     def timed(fn):
         _sync(fn())                      # warm: compile + steady state
@@ -403,17 +396,10 @@ def wave_microbench(dryrun: bool = False, f: int = None, max_bin: int = None,
     for A in buckets:
         active = jnp.asarray(
             (np.arange(A, dtype=np.int32) * max(1, L // A)) % L)
-        row = {"active": A, "wide_ns_per_row": round(timed(
+        table.append({"active": A, "wide_ns_per_row": round(timed(
             lambda: hist_active_pallas(
                 bt, vals, leaf_p, active, num_features=f,
-                max_bins=max_bin, mode="hilo", interpret=interp)), 4)}
-        if A > thresh:
-            row["compact_ns_per_row"] = round(timed(
-                lambda: hist_active_compact(
-                    bt, vals, leaf_p, active, num_features=f,
-                    max_bins=max_bin, num_leaf_slots=L, mode="hilo",
-                    interpret=interp)), 4)
-        table.append(row)
+                max_bins=max_bin, mode="hilo", interpret=interp)), 4)})
     return table
 
 
@@ -893,28 +879,24 @@ def wave_aux_tables(dryrun: bool = False):
 MULTICHIP_SCHEMA_KEYS = (
     "multichip_devices_visible", "multichip_device_kind",
     "multichip_rows", "multichip_iters", "multichip_leaves",
-    "multichip_max_bin", "multichip_overlap_chunks",
+    "multichip_max_bin",
     "multichip_serial_row_iters_per_sec", "multichip_table",
     "multichip_parity_ok", "multichip_best_vs_baseline")
 
 
-def _mc_train_rate(ds, y, n, iters, leaves, max_bin, ndev, overlap,
-                   fused=True):
+def _mc_train_rate(ds, y, n, iters, leaves, max_bin, ndev, fused=True):
     """Train ``iters`` data-parallel iterations on an ``ndev``-device
-    mesh; -> (row_iters/s, auc, phases, model_text).  ``overlap``
-    toggles the chunked double-buffered reduction, ``fused`` the
-    scan-block program (``LGBM_TPU_MESH_BLOCK``): fused runs one
+    mesh; -> (row_iters/s, auc, phases, model_text).  ``fused`` toggles
+    the scan-block program (``LGBM_TPU_MESH_BLOCK``): fused runs one
     dispatch per window, unfused one length-1 block per iteration —
-    byte-identical models either way, so both axes feed the bit-parity
-    gate.  ``phases`` additionally carries ``dispatch_gap_mean_s``
+    byte-identical models either way, which the bit-parity gate
+    holds.  ``phases`` additionally carries ``dispatch_gap_mean_s``
     (host gap between training dispatches, from the live telemetry
     counters) — the `gbdt.dispatch_gap_s` regime the fused path
     exists to kill."""
     from lightgbm_tpu import obs
     from lightgbm_tpu.basic import Booster
-    prev = os.environ.get("LGBM_TPU_OVERLAP")
     prev_mb = os.environ.get("LGBM_TPU_MESH_BLOCK")
-    os.environ["LGBM_TPU_OVERLAP"] = "1" if overlap else "0"
     os.environ["LGBM_TPU_MESH_BLOCK"] = "1" if fused else "0"
     try:
         params = {"objective": "binary", "num_leaves": leaves,
@@ -955,17 +937,15 @@ def _mc_train_rate(ds, y, n, iters, leaves, max_bin, ndev, overlap,
         gc.collect()
         return n * iters / wall, auc, phases, model
     finally:
-        for key, val in (("LGBM_TPU_OVERLAP", prev),
-                         ("LGBM_TPU_MESH_BLOCK", prev_mb)):
-            if val is None:
-                os.environ.pop(key, None)
-            else:
-                os.environ[key] = val
+        if prev_mb is None:
+            os.environ.pop("LGBM_TPU_MESH_BLOCK", None)
+        else:
+            os.environ["LGBM_TPU_MESH_BLOCK"] = prev_mb
 
 
 def multichip_leg(line=None, dryrun: bool = False):
     """Data-parallel training across a REAL >=2-chip mesh: per-chip
-    scaling efficiency + overlap on/off row-iters/s — the ROADMAP item
+    scaling efficiency + fused/unfused row-iters/s — the ROADMAP item
     1 north-star measurement (projected 8-chip 14.5x vs the 3.0x
     target was, until this leg, arithmetic only).
 
@@ -973,19 +953,18 @@ def multichip_leg(line=None, dryrun: bool = False):
     ``"skipped: devices"`` and NEVER zeroes the single-chip headline.
     In ``--dryrun`` on a 1-device image it re-execs itself on a
     2-device virtual CPU pool (``--multichip-child``) so the mesh
-    mechanics, schema, and the overlap bit-parity gate run as a tier-1
+    mechanics, schema, and the bit-parity gate run as a tier-1
     gate without TPU hardware.
 
     Per mesh size d (ISSUE 11): row_iters/s on the FUSED scan-block
     path (the production schedule since the partition-rule refactor:
-    one dispatch per window) with the double-buffered chunked
-    reduction ON and OFF, plus the unfused per-iteration baseline
+    one dispatch per window), plus the unfused per-iteration baseline
     (``LGBM_TPU_MESH_BLOCK=0`` — one dispatch per iteration, the
     ``gbdt.dispatch_gap_s`` regime) with ``fused_speedup`` and the
     measured ``dispatch_gap_mean_s`` on both dispatch modes;
     ``scaling_efficiency`` = rate / (d x serial_rate) against the
     1-chip serial path (the production single-chip anchor, fused
-    blocks), and all three models compared byte-for-byte
+    blocks), and the two models compared byte-for-byte
     (``multichip_parity_ok`` — a parity break zeroes the headline:
     a wrong-answer speedup must not score).  Results are emitted
     incrementally per mesh size when ``line`` is given."""
@@ -1026,13 +1005,11 @@ def multichip_leg(line=None, dryrun: bool = False):
     leaves = int(os.environ.get("BENCH_MC_LEAVES", 7 if dryrun else 255))
     max_bin = int(os.environ.get("BENCH_MC_BIN", 15 if dryrun else 63))
     f = 8 if dryrun else 28
-    from lightgbm_tpu.ops.overlap import overlap_chunks
     out = {
         "multichip_devices_visible": ndev,
         "multichip_device_kind": jax.devices()[0].platform,
         "multichip_rows": n, "multichip_iters": iters,
         "multichip_leaves": leaves, "multichip_max_bin": max_bin,
-        "multichip_overlap_chunks": overlap_chunks(),
     }
     if dryrun:
         out["multichip_dryrun"] = True
@@ -1062,27 +1039,21 @@ def multichip_leg(line=None, dryrun: bool = False):
         if _budget_exceeded():
             out.setdefault("multichip_skipped_counts", []).append(d)
             continue
-        # three runs per mesh size: fused+overlap (the production
-        # path: one dispatch per window), fused without the overlapped
-        # reduction (overlap A/B), and the unfused per-iteration
-        # baseline (LGBM_TPU_MESH_BLOCK=0: one length-1 block per
-        # iteration — the per-dispatch-overhead regime the fused
-        # path removes).  All three models must be byte-identical.
+        # two runs per mesh size: fused (the production path: one
+        # dispatch per window) and the unfused per-iteration baseline
+        # (LGBM_TPU_MESH_BLOCK=0: one length-1 block per iteration —
+        # the per-dispatch-overhead regime the fused path removes).
+        # The two models must be byte-identical.
         r_on, auc_on, ph_on, m_on = _mc_train_rate(
-            ds, y, n, iters, leaves, max_bin, d, overlap=True)
-        r_off, _, ph_off, m_off = _mc_train_rate(
-            ds, y, n, iters, leaves, max_bin, d, overlap=False)
+            ds, y, n, iters, leaves, max_bin, d)
         r_uf, _, ph_uf, m_uf = _mc_train_rate(
-            ds, y, n, iters, leaves, max_bin, d, overlap=True,
-            fused=False)
-        parity_ok = parity_ok and (m_on == m_off) and (m_on == m_uf)
+            ds, y, n, iters, leaves, max_bin, d, fused=False)
+        parity_ok = parity_ok and (m_on == m_uf)
         vs = r_on / REFERENCE_ROW_ITERS_PER_SEC
         best_vs = max(best_vs, vs)
         table.append({
             "devices": d,
             "row_iters_per_sec": round(r_on, 1),
-            "no_overlap_row_iters_per_sec": round(r_off, 1),
-            "overlap_speedup": round(r_on / max(r_off, 1e-9), 4),
             "unfused_row_iters_per_sec": round(r_uf, 1),
             "fused_speedup": round(r_on / max(r_uf, 1e-9), 4),
             "dispatch_gap_mean_s": ph_on["dispatch_gap_mean_s"],
@@ -1125,7 +1096,7 @@ def multichip_leg(line=None, dryrun: bool = False):
         dsf.construct()
         del Xf
         rf, aucf, phf, _ = _mc_train_rate(dsf, yf, nf, itf, leaves,
-                                          max_bin, d, overlap=True)
+                                          max_bin, d)
         out.update({
             "multichip_full_devices": d, "multichip_full_rows": nf,
             "multichip_full_iters": itf,
@@ -1304,7 +1275,7 @@ def stream_ingest_leg(line=None, dryrun: bool = False):
         # scatter, and the upload/compute pipeline vs the serial
         # escape hatch.  Both sides ride the platform's DEFAULT
         # backend resolution — on TPU the kernel leg streams through
-        # the seeded Pallas/compact folds; on CPU (dryrun) both sides
+        # the seeded Pallas folds; on CPU (dryrun) both sides
         # resolve to scatter and the kernel speedup sits at ~1.0 (the
         # schema gate checks presence and sanity, not CPU throughput).
         ab_rows = 2 * block if toy else int(
@@ -1742,7 +1713,7 @@ def dryrun_main():
         line["rank_grad_ok"] = False
         line["rank_grad_leg"] = f"failed: {type(exc).__name__}: {exc}"
     # multichip mechanics gate: the REAL leg on a 2-device virtual CPU
-    # pool (re-exec'd child) — schema + overlap bit-parity validated as
+    # pool (re-exec'd child) — schema + bit-parity validated as
     # tier-1 (tests/test_bench_budget)
     try:
         mleg = multichip_leg(dryrun=True)
@@ -1750,7 +1721,7 @@ def dryrun_main():
         rows = mleg.get("multichip_table") or []
         sane = (not missing and rows
                 and all(r["row_iters_per_sec"] > 0
-                        and r["no_overlap_row_iters_per_sec"] > 0
+                        and r["unfused_row_iters_per_sec"] > 0
                         and r["scaling_efficiency"] > 0 for r in rows)
                 and mleg["multichip_parity_ok"]
                 and mleg["multichip_serial_row_iters_per_sec"] > 0)
@@ -2236,10 +2207,10 @@ def main():
     # landed in an artifact), then the heavyweight 255-bin rank leg,
     # and valid (repeatedly captured) last.
 
-    # multichip leg: data-parallel scaling across a real >=2-chip mesh
-    # with the overlapped reduction on/off (ROADMAP item 1).  Gate:
-    # overlap on/off models must be byte-identical when the leg RAN
-    # (a wrong-answer speedup must not score).
+    # multichip leg: data-parallel scaling across a real >=2-chip mesh,
+    # fused and unfused (ROADMAP item 1).  Gate: the two models must be
+    # byte-identical when the leg RAN (a wrong-answer speedup must not
+    # score).
     if os.environ.get("BENCH_MC", "1") != "0":
         mleg = _leg(line, "multichip", lambda: multichip_leg(line),
                     gate=True)

@@ -416,8 +416,8 @@ class GBDT:
             self._bins_t = None
             backend = resolve_backend(self.device_data, growth.num_leaves,
                                       hist_mode=hist_mode)
-            # the fused 32-iteration block runs on the Pallas backends
-            # only ("pallas"/"compact"): 32 chained SCATTER tree builds
+            # the fused 32-iteration block runs on the Pallas backend
+            # only: 32 chained SCATTER tree builds
             # in one program are one very long dispatch (an earlier
             # runtime's device watchdog killed it at >256 bins x 300k
             # rows; unverified on a local chip), so scatter configs
@@ -469,17 +469,12 @@ class GBDT:
                         wave_size=growth.wave_size,
                         hist_mode=hist_mode)
         else:
-            from ..ops.overlap import overlap_enabled
             from ..parallel.learners import build_tree_distributed
             mesh = self.mesh_ctx.mesh
             axis = self.mesh_ctx.data_axis
             lt, tk = c.tree_learner, c.top_k
             dist_hist_mode = c.hist_mode or None
             self._bins_t = None
-            # overlap resolved ONCE per program build (not at trace
-            # time): an env flip mid-run must not serve a stale trace
-            # from the per-instance jit cache
-            overlap = overlap_enabled()
             if self._pr is None:
                 # place the dataset ONCE under the partition-rule
                 # registry (bins row-sharded / replicated per learner
@@ -517,7 +512,7 @@ class GBDT:
                 return build_tree_distributed(
                     mesh, axis, lt, dd, grad, hess, growth,
                     bag_mask=bag, feature_mask=fmask, top_k=tk,
-                    hist_mode=dist_hist_mode, overlap=overlap)
+                    hist_mode=dist_hist_mode)
 
             # the fused mesh scan block (see _make_block_fn) runs this
             # same build per scan-body iteration; watchdog-wise the
@@ -1202,7 +1197,7 @@ class GBDT:
         (gradients → tree build → score update chained on device).
         Single-process device MESHES ride the same fused block since
         the partition-rule refactor: the scan body traces the
-        distributed build (shard_map + overlapped psum wave) in place
+        distributed build (shard_map + the wave's psum) in place
         of the serial one, so a d-chip mesh pays one dispatch per
         window instead of one per iteration (``LGBM_TPU_MESH_BLOCK=0``
         is the per-iteration escape hatch / A-B baseline).  Excluded:

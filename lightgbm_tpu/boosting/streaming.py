@@ -15,13 +15,13 @@ resident and updated per block as the blocks stream.
 pinned by tests/test_streaming.py): streamed training is
 BYTE-IDENTICAL — model text and score digests via ``Booster.digest()``
 — to in-memory ``lgb.train`` on the same data, serial AND 2-shard
-data-parallel, on ALL THREE histogram backends.  Three mechanisms:
+data-parallel, on BOTH histogram backends.  Three mechanisms:
 
 1. **Carried-accumulator folds.**  On the scatter backend, XLA applies
    same-location scatter-add updates in row order, so folding per-block
    scatters into a carried f32 ``[A, F, B, 3]`` accumulator reproduces
    the monolithic ``hist_active_scatter`` bitwise.  On the
-   Pallas/compact kernels the fold carries the RAW kernel accumulator
+   Pallas kernels the fold carries the RAW kernel accumulator
    instead (``learner.serial.make_hist_fold_fn``): each block's kernel
    call SEEDS its output from the carry via ``input_output_aliases``
    (the ``@pl.when`` zero-init becomes a seed-load), so a chain of
@@ -30,9 +30,7 @@ data-parallel, on ALL THREE histogram backends.  Three mechanisms:
    (per-tree global quantization scales are host-derived over every
    block, :func:`_fold_scales`), same-order f32 on the wide float
    modes.  The raw carry is dequantized/unpacked ONCE per wave, by the
-   same jitted graph the in-memory kernels fuse in-call.  Float
-   COMPACT folds are the one chain-inexact case and degrade to the
-   wide kernel inside the fold seam.
+   same jitted graph the in-memory kernels fuse in-call.
 2. **Canonical chunked root statistics** (``learner/serial.py
    root_stats``): the resident ``_init_state`` derives the root sums
    from fixed ``STREAM_CHUNK``-sized chunk sums reduced by a fixed
@@ -343,14 +341,14 @@ class StreamTrainer:
         # must equal bitwise keys its mode on n too
         self.hist_mode = effective_hist_mode(
             config.hist_mode or default_hist_mode(), n)
-        # kernel-exact folds: on the Pallas/compact backends every block
+        # kernel-exact folds: on the Pallas backend every block
         # call SEEDS the kernel accumulator from the carried raw grid
         # (learner.serial.make_hist_fold_fn), so the streamed chain IS
         # the monolithic kernel bitwise; None -> the exact scatter fold
         self._fold = make_hist_fold_fn(
             self.dd_meta, L, self.A_tail, self.R,
             hist_mode=self.hist_mode, num_data=n)
-        self.backend = self._fold.backend if self._fold else "scatter"
+        self.backend = "pallas" if self._fold else "scatter"
         self._kernel_hist = self._fold is not None
         # bounded-depth-2 upload/compute pipeline (module docstring);
         # "0"/"off" is the byte-identical serial escape hatch
@@ -464,7 +462,7 @@ class StreamTrainer:
         """(bins, leaf2, best, pend_sel, pend_new, acc, grad, hess,
         act_small, scales) -> (leaf2', acc'): route the pending splits
         over this block, then fold its active-leaf histograms into the
-        carry — a SEEDED kernel call on the Pallas/compact backends
+        carry — a SEEDED kernel call on the Pallas backend
         (raw carry; ``scales`` is the shard's fixed quantization pair),
         the row-order scatter on the exact f32 path (``scales`` None)."""
         dd = self.dd_meta

@@ -298,10 +298,9 @@ def _empty_best(L: int, B: int) -> SplitResult:
 # histogram-wave strategies (the learner-type seam, tree_learner.cpp:9-33)
 # ---------------------------------------------------------------------------
 def uses_pallas(backend: str) -> bool:
-    """Whether this backend runs the Pallas kernel family ("compact" is
-    the wide kernel + leaf-compacted deep waves, not a separate kernel
-    stack — routing, fusion, and bins_t prep are shared)."""
-    return backend in ("pallas", "compact")
+    """Whether this (resolved) backend runs the Pallas kernel family:
+    the wide histogram kernel, the route kernels, the ``bins_t`` prep."""
+    return backend == "pallas"
 
 
 def _pallas_interpret() -> bool:
@@ -310,35 +309,15 @@ def _pallas_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def wave_uses_compact(backend: str, num_slots: int) -> bool:
-    """THE per-wave dispatch predicate: a wave whose active-slot count
-    exceeds the compaction threshold takes the leaf-compacted kernel on
-    the "compact" backend (asked for by name; "auto" is the wide kernel
-    in every wave).  Slot counts are static per wave (stage_plan
-    unrolled stages + the fixed-width tail), so this resolves at trace
-    time — shallow waves keep the wide (fused) kernel with zero runtime
-    branching."""
-    from ..ops.compact import compact_slot_threshold
-    return backend == "compact" and num_slots > compact_slot_threshold()
-
-
 def wave_backend_plan(L: int, wave_size: int = 0, backend: str = "pallas",
                       fused_ok: bool = True):
     """Per-wave kernel choice for a stage plan: ``-> (choices, tail)``
-    with entries "compact" / "fused" / "<backend>".  Pure mirror of the
-    dispatch :func:`build_tree` applies (same ``wave_uses_compact``
-    predicate), exposed so tests can pin the selection without tracing
-    a tree build."""
+    with entries "fused" / "<backend>", the same in every wave.  Pure
+    mirror of the dispatch :func:`build_tree` applies, exposed so tests
+    can pin the selection without tracing a tree build."""
     plan, A_tail = stage_plan(L, wave_size)
-
-    def choice(A: int) -> str:
-        if wave_uses_compact(backend, A):
-            return "compact"
-        if uses_pallas(backend) and fused_ok:
-            return "fused"
-        return backend
-
-    return [choice(A) for A in plan], choice(A_tail)
+    choice = "fused" if uses_pallas(backend) and fused_ok else backend
+    return [choice] * len(plan), choice
 
 
 def resolve_backend(data: DeviceData, num_leaf_slots: int,
@@ -349,21 +328,11 @@ def resolve_backend(data: DeviceData, num_leaf_slots: int,
     a kernel path that a static gate turned away must be visible."""
     if backend == "auto":
         backend = default_backend()
+    if backend not in ("pallas", "scatter"):
+        raise ValueError(
+            f"unknown histogram backend {backend!r} (hist_backend / "
+            f"LGBM_TPU_HIST_BACKEND): one of auto, pallas, scatter")
     asked, why = backend, ""
-    if backend == "compact":
-        from ..ops.compact import compact_config_ok, compact_slot_threshold
-        _, A_tail = stage_plan(num_leaf_slots)
-        threshold = compact_slot_threshold()
-        if A_tail <= threshold:
-            # shallow trees never reach the slot threshold: every wave
-            # is a wide-kernel wave anyway
-            backend = "pallas"
-            why = (f"no wave of a {num_leaf_slots}-leaf tree exceeds "
-                   f"{threshold} active slots")
-        elif not compact_config_ok(data.group_max_bins, hist_mode):
-            backend = "pallas"
-            why = (f"the grouped cell at {data.group_max_bins} bins / "
-                   f"{hist_mode} does not fit the VMEM model")
     if uses_pallas(backend) and not pallas_config_ok(
             data.group_max_bins, num_leaf_slots, hist_mode):
         backend = "scatter"     # >256 bins or VMEM-infeasible config
@@ -493,9 +462,6 @@ def make_hist_fn(data: DeviceData, grad, hess, num_leaf_slots: int,
         n_pad = bins_t.shape[1]
         n = data.bins.shape[0]
         interp = _pallas_interpret()
-        # resolved once: the per-wave choice below keys only on the
-        # wave's static slot count
-        from ..ops import compact as compact_mod
 
         def hist_fn(hist_leaf, active):
             with jax.named_scope("tree.hist"):
@@ -503,17 +469,6 @@ def make_hist_fn(data: DeviceData, grad, hess, num_leaf_slots: int,
                 if leaf.shape[0] != n_pad:
                     leaf = jnp.pad(leaf[:n], (0, n_pad - n),
                                    constant_values=-1)
-                if wave_uses_compact(backend, active.shape[0]):
-                    # deep wave: leaf-compacted regroup + grouped kernel
-                    # (ops/compact.py) — per-row MXU work independent of
-                    # A; inside, tree.compact.plan and
-                    # tree.compact.regroup name what is not the kernel
-                    return compact_mod.hist_active_compact(
-                        bins_t, vals, leaf, active, scales,
-                        num_features=data.num_groups,
-                        max_bins=data.group_max_bins,
-                        num_leaf_slots=num_leaf_slots, mode=hist_mode,
-                        interpret=interp)
                 return hist_active_pallas(
                     bins_t, vals, leaf, active, scales,
                     num_features=data.num_groups,
@@ -538,13 +493,10 @@ class HistFold(NamedTuple):
     folds one block's rows into the carried RAW kernel accumulator and
     returns the new carry; ``init_acc()`` allocates the zero carry;
     ``unpack(acc, scales=None)`` finalizes the chain to the
-    ``[A, F, B, 3]`` f32 grid the split scan consumes.  ``backend`` is
-    the RESOLVED kernel choice ("pallas"/"compact") after the fold
-    seam's own degradations."""
+    ``[A, F, B, 3]`` f32 grid the split scan consumes."""
     fold: Callable
     init_acc: Callable
     unpack: Callable
-    backend: str
     hist_mode: str
     quantized: bool
 
@@ -574,9 +526,7 @@ def make_hist_fold_fn(data: DeviceData, num_leaf_slots: int,
     order, block boundaries are just program re-entry.  Exactness holds
     per mode: quantized modes are order-free int32; the wide float modes
     reuse the identical per-tile add sequence (same row tile for every
-    same-shaped block).  Float COMPACT folds are the one chain-INEXACT
-    case (block-local group padding reorders f32 adds) and are degraded
-    to the wide kernel below.
+    same-shaped block).
 
     Args:
       num_active: the streamed wave width (streamed trees run every
@@ -591,7 +541,6 @@ def make_hist_fold_fn(data: DeviceData, num_leaf_slots: int,
     Returns None when the resolved backend is scatter (caller keeps the
     carried-f32 scatter fold) or the SEEDED cell is VMEM-infeasible.
     """
-    from ..ops import compact as compact_mod
     from ..ops.vmem import hist_fold_cell_ok, round_up
 
     if hist_mode is None:
@@ -603,47 +552,23 @@ def make_hist_fold_fn(data: DeviceData, num_leaf_slots: int,
         return None
     quantized = is_quantized(hist_mode)
     mb = data.group_max_bins
-    use_compact = wave_uses_compact(backend, num_active)
-    resolved, why = backend, ""
-    if use_compact and not quantized:
-        use_compact = False
-        why = (f"a chain of float ({hist_mode}) compact folds reorders "
-               f"f32 adds")
-    if use_compact:
-        extra = compact_mod.COMPACT_GROUP * 4 + 2 * 1024 * 4
-        if not hist_fold_cell_ok(mb, compact_mod.COMPACT_GROUP, hist_mode,
-                                 extra_bytes=extra):
-            use_compact = False
-            why = (f"the seeded grouped cell at {mb} bins / {hist_mode} "
-                   f"does not fit the VMEM model")
-    if not use_compact:
-        backend = "pallas"
-        if not hist_fold_cell_ok(mb, num_active, hist_mode):
-            backend = None
-            why = (f"the seeded wide cell at {mb} bins x {num_active} "
-                   f"slots / {hist_mode} does not fit the VMEM model "
-                   f"(hist_fold_cell_ok)")
-    if backend != resolved and why:
-        # the fold seam's own substitutions, as visible as
+    if not hist_fold_cell_ok(mb, num_active, hist_mode):
+        # the fold seam's own substitution, as visible as
         # resolve_backend's: a kernel a static gate turned away must not
         # stay silent on the streamed path either
         from ..utils.log import log_once
-        chosen = backend or "scatter (carried f32 fold)"
-        log_once(f"make_hist_fold_fn:{resolved}:{backend}:{why}",
-                 f"streamed histogram fold: {chosen} (resolved backend "
-                 f"{resolved}): {why}", level="info")
-    if backend is None:
+        why = (f"the seeded wide cell at {mb} bins x {num_active} slots / "
+               f"{hist_mode} does not fit the VMEM model "
+               f"(hist_fold_cell_ok)")
+        log_once(f"make_hist_fold_fn:{backend}:{why}",
+                 f"streamed histogram fold: scatter (carried f32 fold) "
+                 f"(resolved backend {backend}): {why}", level="info")
         return None
 
     from ..ops.pallas_histogram import DEFAULT_ROW_TILE
     n_pad = round_up(block_rows, DEFAULT_ROW_TILE)
     F_pad = data.num_groups     # per-block transpose_bins(feat_tile=None)
-    if use_compact:
-        shape, dtype = compact_mod.compact_raw_layout(
-            n_pad, num_active, F_pad, mb, hist_mode)
-    else:
-        shape, dtype = hist_raw_layout(n_pad, num_active, F_pad, mb,
-                                       hist_mode)
+    shape, dtype = hist_raw_layout(n_pad, num_active, F_pad, mb, hist_mode)
     interp = _pallas_interpret()
 
     def init_acc():
@@ -654,12 +579,6 @@ def make_hist_fold_fn(data: DeviceData, num_leaf_slots: int,
         bins_t = transpose_bins(bins)
         vals, _ = _pack(grad, hess, hist_mode, scales)
         leaf = hist_leaf.astype(jnp.int32)
-        if use_compact:
-            return compact_mod.hist_active_compact(
-                bins_t, vals, leaf, active, scales, acc,
-                num_features=F_pad, max_bins=mb,
-                num_leaf_slots=num_leaf_slots, mode=hist_mode,
-                interpret=interp, raw=True)
         return hist_active_pallas(
             bins_t, vals, leaf, active, scales, acc,
             num_features=F_pad, max_bins=mb, mode=hist_mode,
@@ -673,13 +592,10 @@ def make_hist_fold_fn(data: DeviceData, num_leaf_slots: int,
     # matrix in tests/test_streaming.py).
     @jax.jit
     def unpack(acc, scales=None):
-        if use_compact:
-            return compact_mod.unpack_hist_compact_raw(
-                acc, num_active, data.num_groups, mb, hist_mode, scales)
         return unpack_hist_raw(acc, num_active, data.num_groups, mb,
                                hist_mode, scales)
 
-    return HistFold(fold, init_acc, unpack, backend, hist_mode, quantized)
+    return HistFold(fold, init_acc, unpack, hist_mode, quantized)
 
 
 def make_route_fn(data: DeviceData, backend: str,
@@ -771,19 +687,13 @@ def make_serial_strategy(data: DeviceData, grad, hess, params: GrowthParams,
                          feature_mask, psum_fn=None, backend: str = "auto",
                          hist_mode: Optional[str] = None,
                          bins_t: Optional[jnp.ndarray] = None,
-                         psum_axis: Optional[str] = None,
                          scales: Optional[jnp.ndarray] = None):
     """The serial (and data-parallel, via `psum_fn`) wave strategy:
     histogram the active leaves, subtract siblings, rescan changed leaves.
 
     `psum_fn` injects the data-parallel histogram collective — the
     reference's ReduceScatter seam (`data_parallel_tree_learner.cpp:147-162`)
-    collapses to one psum of the active-leaf histograms.  `psum_axis`
-    switches that collective to the OVERLAPPED lowering
-    (`ops/overlap.py`): the same logical reduction issued as column
-    chunks whose sibling-subtract/state-scatter consumers double-buffer
-    against the chunks still in flight — bit-identical values, identical
-    logical schedule.
+    collapses to one psum of the active-leaf histograms.
 
     Where the kernels histogram quantized values, what crosses the
     shards is the cells' integer code sums (``codes``): rounded against
@@ -808,13 +718,6 @@ def make_serial_strategy(data: DeviceData, grad, hess, params: GrowthParams,
     def wave(hist_state, hist_leaf, act_small, act_parent, act_sibling,
              lsg, lsh, lc):
         new_h = hist_fn(hist_leaf, act_small)   # [A, G, Bg, 3 | C codes]
-        if psum_axis is not None:
-            from ..ops.overlap import reduce_apply_overlapped
-            hist_state, ids, grid = reduce_apply_overlapped(
-                hist_state, new_h, act_small, act_parent, act_sibling, L,
-                psum_axis, psum_fn.reduce, dequant)
-            return scan_grid(data, params, feature_mask, hist_state, ids,
-                             grid, lsg, lsh, lc)
         if psum_fn is not None:
             new_h = dequant(psum_fn(new_h))
         return rescan_changed(data, params, feature_mask, hist_state, new_h,
@@ -839,8 +742,9 @@ def rescan_changed(data: DeviceData, params: GrowthParams, feature_mask,
 def scan_grid(data: DeviceData, params: GrowthParams, feature_mask,
               hist_state, ids, grid, lsg, lsh, lc):
     """EFB unbundle + best-split rescan of the changed-leaf grids — the
-    tail of :func:`rescan_changed`, split out so the overlapped wave
-    (`ops/overlap.py` reduce+apply) can share it verbatim.
+    tail of :func:`rescan_changed`, split out because the streamed
+    trainer (`boosting/streaming.py`) applies its folded histograms
+    itself and scans the result with this.
 
     With the per-leaf split cache OFF (``LGBM_TPU_SPLIT_CACHE=0``) the
     changed-slot narrowing is discarded: every wave rescans the FULL
@@ -901,7 +805,6 @@ def build_tree(data: DeviceData,
                num_hist_features: Optional[int] = None,
                bins_t: Optional[jnp.ndarray] = None,
                hist_mode: Optional[str] = None,
-               psum_axis: Optional[str] = None,
                scales: Optional[jnp.ndarray] = None) -> BuiltTree:
     """Grow one tree.  Jittable; `psum_fn` lets the data-parallel learner
     inject a collective over active-leaf histograms; `strategy` replaces
@@ -909,12 +812,9 @@ def build_tree(data: DeviceData,
     `parallel/learners.py`).  `num_hist_features` overrides the width of
     the histogram state (feature-parallel shards keep only their slice);
     `bins_t` is the once-per-dataset transposed bins (computed here when
-    absent); `psum_axis` routes the data-parallel wave reduction through
-    the overlapped chunked lowering (`ops/overlap.py`) — `psum_fn` is
-    still used for the root-statistics reduction either way; `scales`
-    are the quantized modes' ``[2]`` rounding scales where they are not
-    to be taken from this call's own rows (a row-sharded learner's are
-    the largest over all shards)."""
+    absent); `scales` are the quantized modes' ``[2]`` rounding scales
+    where they are not to be taken from this call's own rows (a
+    row-sharded learner's are the largest over all shards)."""
     n = data.bins.shape[0]
     L = params.num_leaves
 
@@ -954,15 +854,11 @@ def build_tree(data: DeviceData,
                                  mode))
     fused_fn = (make_fused_fn(data, grad, hess, mode, bins_t, scales)
                 if fused else None)
-    # the "compact" backend (by name only; "auto" never resolves to it)
-    # needs the strategy (route + compacted hist) for its deep waves
-    # even when the shallow waves run fused
-    if strategy is None and (not fused or backend == "compact"):
+    if strategy is None and not fused:
         strategy = make_serial_strategy(data, grad, hess, params,
                                         feature_mask, psum_fn=psum_fn,
                                         backend=backend, bins_t=bins_t,
-                                        hist_mode=hist_mode,
-                                        psum_axis=psum_axis, scales=scales)
+                                        hist_mode=hist_mode, scales=scales)
     route_fn = make_route_fn(data, backend, bins_t)
 
     def scan_changed(hist_state, new_h, s, lsg, lsh, lc):
@@ -980,14 +876,7 @@ def build_tree(data: DeviceData,
         # --- 0-3: apply last wave's pending splits to the rows, then
         # histogram the active leaves, subtract siblings, rescan.  The
         # fused kernel does the route inside the histogram's bins stream.
-        # Every wave of the default ("pallas") backend is a wide-kernel
-        # wave.  Asked for by name, "compact" sends its deep waves (slot
-        # count static, > compaction threshold) through the route + the
-        # leaf-compacted grouped kernel and keeps the wide fused kernel
-        # for the shallow ones (wave_uses_compact — the same predicate
-        # make_hist_fn applies inside the strategy)
-        if fused and not wave_uses_compact(backend,
-                                           s.act_small.shape[0]):
+        if fused:
             new_h, leaf2 = fused_fn(s.leaf2, s.best, s.pend_sel,
                                     s.pend_new, s.act_small)
             hist_state, ids, res = scan_changed(

@@ -647,11 +647,9 @@ def _unpack_hist(out, B, cols, C, A_pad, A, num_features, mode, scales):
 def combine_hist_cols(out, mode, scales):
     """``[..., C]`` raw kernel value columns -> ``[..., 3]`` f32
     ``(sum_grad, sum_hess, count)``: combine hi/lo pairs or dequantize.
-    Shared by the wide kernel's unpack and the leaf-compacted kernel
-    (``ops/compact.py``), so the two paths cannot drift.  A quantized
-    mode with ``scales`` None leaves the ``[..., C]`` int32 code sums as
-    they are: the data-parallel learner sums them over the shards
-    exactly and dequantizes once after (:func:`dequant_hist`)."""
+    A quantized mode with ``scales`` None leaves the ``[..., C]`` int32
+    code sums as they are: the data-parallel learner sums them over the
+    shards exactly and dequantizes once after (:func:`dequant_hist`)."""
     if is_quantized(mode):
         return out if scales is None else dequant_hist(out, scales, mode)
     C = out.shape[-1]
@@ -705,12 +703,10 @@ def hist_active_scatter(bins: jnp.ndarray,
 def default_backend() -> str:
     """What "auto" means: "pallas" (the wide MXU kernel in every wave of
     a tree) on TPU, "scatter" elsewhere; ``LGBM_TPU_HIST_BACKEND`` names
-    another.  "compact" (``ops/compact.py``: the wide kernel + leaf-
-    compacted deep waves) is reachable by name only: on the chip its
-    plan and regroup cost 1,731 ms an iteration at 13.28M x 67 x 63 bins
-    to save 184 ms of wide-kernel columns (PERF.md, PR 27)."""
+    the other (`learner/serial.py` ``resolve_backend`` refuses a name
+    that is neither)."""
     forced = os.environ.get("LGBM_TPU_HIST_BACKEND", "")
-    if forced:
+    if forced and forced != "auto":
         return forced
     return "pallas" if jax.default_backend() == "tpu" else "scatter"
 

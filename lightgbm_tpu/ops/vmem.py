@@ -3,12 +3,12 @@
 Through PR 5 each kernel carried its own copy of the same question —
 "does this config's per-grid-cell working set fit the ~16 MB/core VMEM
 with headroom?" — as ``pallas_histogram._cell_vmem_bytes`` /
-``_feat_tile_cap``, ``compact.compact_config_ok``, and the split
-kernel's ``_vmem_budget_bytes`` leaf-tile chooser.  PR 1's ADVICE-r5
-fix (the pallas_split lane cap blowing VMEM and surfacing as a Mosaic
-crash instead of a fallback) showed what happens when a kernel ships
-WITHOUT the model.  This module is the single home for that
-arithmetic, pure int math with **no jax import**, so:
+``_feat_tile_cap`` and the split kernel's ``_vmem_budget_bytes``
+leaf-tile chooser.  PR 1's ADVICE-r5 fix (the pallas_split lane cap
+blowing VMEM and surfacing as a Mosaic crash instead of a fallback)
+showed what happens when a kernel ships WITHOUT the model.  This
+module is the single home for that arithmetic, pure int math with
+**no jax import**, so:
 
 * every kernel dispatcher keys its config gate on one budget
   (``VMEM_BUDGET_BYTES``, measured headroom under the v5e's ~16 MB/core
@@ -61,7 +61,6 @@ HIST_CELL_FS = 0.306e9
 VMEM_GUARDS = (
     "pallas_config_ok",      # wide one-hot histogram + route table model
     "fused_config_ok",       # fused route+hist kernel
-    "compact_config_ok",     # leaf-compacted deep-wave kernel
     "hist_cell_ok",          # the generic predicate below
     "hist_fold_cell_ok",     # accumulator-seeded streamed-fold variant
     "split_lane_chunk_features",   # fused split kernel's lane chunking
@@ -155,9 +154,9 @@ def hist_tiling(F_pad: int, n_pad: int, B: int, cols: int, C: int,
     feasibility predicates turn away) the smallest one is returned and
     the compiler is left to refuse it.
 
-    Shared by the wide, compacted and fused kernels and the raw-layout
-    twins of the first two, so a fold's carry can never disagree with
-    the kernel that fills it."""
+    Shared by the wide and fused kernels and the wide kernel's
+    raw-layout twin, so a fold's carry can never disagree with the
+    kernel that fills it."""
     tiles = row_tiles(n_pad, requested)
     grids = []
     for T in tiles:
@@ -178,32 +177,28 @@ def hist_tiling(F_pad: int, n_pad: int, B: int, cols: int, C: int,
 
 
 def hist_cell_ok(max_bins: int, active_slots: int, mode: str,
-                 row_tile: int = 1024, extra_bytes: int = 0) -> bool:
+                 row_tile: int = 1024) -> bool:
     """The generic histogram-kernel feasibility predicate: does the
     minimum-feature-tile grid cell at ``active_slots`` output slots fit
     the budget (at the 1024-row fallback tile ``row_tiles`` goes
-    down to)?  ``extra_bytes`` covers kernel-specific residents (the
-    compacted kernel's group-active slice + leaf row)."""
+    down to)?"""
     B = bin_stride(max_bins)
     C, _, cols = col_layout(active_slots, mode)
-    return (cell_vmem_bytes(8, B, cols, row_tile, C) + extra_bytes
-            <= VMEM_BUDGET_BYTES)
+    return cell_vmem_bytes(8, B, cols, row_tile, C) <= VMEM_BUDGET_BYTES
 
 
 def hist_fold_cell_ok(max_bins: int, active_slots: int, mode: str,
-                      row_tile: int = 1024, extra_bytes: int = 0) -> bool:
+                      row_tile: int = 1024) -> bool:
     """Feasibility of the accumulator-SEEDED histogram cell (the
-    out-of-core fold variant of the kernels): on top of
+    out-of-core fold variant of the kernel): on top of
     :func:`hist_cell_ok`'s residents, the carried accumulator operand
     streams in as a double-buffered ``[ft*B, cols]`` block (same
     element size as the output; int32 on the quantized modes) for the
-    seed-load.  ``extra_bytes`` composes with kernel-specific residents
-    exactly as in :func:`hist_cell_ok` (the compacted fold passes its
-    group-active slice + leaf row through here)."""
+    seed-load."""
     B = bin_stride(max_bins)
     C, _, cols = col_layout(active_slots, mode)
     return (cell_vmem_bytes(8, B, cols, row_tile, C, seeded=True)
-            + extra_bytes <= VMEM_BUDGET_BYTES)
+            <= VMEM_BUDGET_BYTES)
 
 
 def split_vmem_budget_bytes() -> int:

@@ -50,25 +50,10 @@ the mesh block's score update is the serial path's multiply-add and no
 gather.  Measured on four v5e chips at 4 x 13,281,250 rows x 67 x 63
 bins x 255 leaves (`PERF.md` §5, PR 28): the exchange is 1.0-1.2 ms of a
 0.66-0.71 s iteration.
-
-Deep-wave compaction threads through all three learners via the shared
-``make_hist_fn`` seam: on the "compact" backend (by name only; the TPU
-default is the wide kernel in every wave, whose output contract is the
-same) each shard regroups ITS OWN rows leaf-contiguously and runs
-the grouped kernel (`ops/compact.py`) for waves above the slot
-threshold.  The collective schedule is untouched — the data-parallel
-``psum`` still reduces the same ``[A, F, B, 3]`` active-leaf block (the
-compacted kernel has the identical output contract), feature-parallel
-shards compact their own column slice, and voting-parallel compacts its
-local histograms before the vote — so spmdcheck's static schedule and
-the runtime flight-recorder fingerprints are identical to the wide
-kernel's (shape/dtype/op/axis all unchanged; `tests/test_compact.py::
-test_compact_psum_data_parallel` pins the psum'd parity).
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -139,8 +124,9 @@ class Psum:
     ``collective.<what>``, which stamps the flight-recorder site's name
     into the HLO op metadata: profiler captures and HLO dumps name the
     collective by the same site the runtime digest uses.  ``reduce`` is
-    the reduction alone, for the overlapped lowering's chunks
-    (`ops/overlap.py` records the one logical reduction itself)."""
+    the reduction alone: the benchmark's rehearsal of a shard left out
+    of the exchange (`benchmark/tests/test_correct_dp.py`) puts its
+    fault there."""
 
     def __init__(self, axis: str, num_shards: int):
         self.axis, self.num_shards = axis, num_shards
@@ -424,22 +410,13 @@ def build_tree_distributed(mesh: Mesh, axis: str, learner_type: str,
                            bag_mask=None, feature_mask=None,
                            top_k: int = 20,
                            hist_backend: str = "auto",
-                           hist_mode=None,
-                           overlap: Optional[bool] = None) -> BuiltTree:
+                           hist_mode=None) -> BuiltTree:
     """Run one tree build as an SPMD program over `mesh`.
 
     Row-sharded inputs (data/voting): ``bins``, ``grad``, ``hess``,
     ``bag_mask`` are sharded on the leading axis; tree outputs are
     replicated; ``row_leaf`` stays sharded.  Feature-parallel replicates
     rows and slices features inside the shard.
-
-    ``overlap`` (data-parallel only; default = ``LGBM_TPU_OVERLAP``,
-    on): lower the per-wave histogram psum through the double-buffered
-    chunked reduction (`ops/overlap.py`) — bit-identical trees, the
-    identical logical collective schedule (same flight-recorder
-    fingerprints), with the reduction tail hidden behind the per-chunk
-    sibling-subtract/state-scatter.  The root-statistics psum and the
-    feature/voting collectives are untouched either way.
 
     Where the kernels histogram quantized values (an int8 mode that a
     SHARD's rows keep exact in int32: the mode is judged on
@@ -449,9 +426,6 @@ def build_tree_distributed(mesh: Mesh, axis: str, learner_type: str,
     its trees are the serial learner's, bit for bit, for any number of
     shards.
     """
-    from ..ops.overlap import overlap_enabled
-    if overlap is None:
-        overlap = overlap_enabled()
     num_shards = mesh.shape[axis]
     row_shard = learner_type in ("data", "voting")
     n = data.num_data
@@ -481,14 +455,11 @@ def build_tree_distributed(mesh: Mesh, axis: str, learner_type: str,
         data_l = DeviceData(bins, offs, nb, db, mt, ic, nanb, fg, fo,
                             *statics)
         nhf = None
-        psum_axis = None
         scales = (global_scales(grad_l, hess_l, axis) if quantized_kernels
                   else None)
         if learner_type == "data":
             strategy = None        # serial strategy + histogram psum
             psum_fn = Psum(axis, num_shards)
-            if overlap:
-                psum_axis = axis   # overlapped wave reduction
         elif learner_type == "feature":
             strategy, nhf = make_feature_parallel_strategy(
                 data_l, grad_l, hess_l, params, fmask_l, axis, num_shards,
@@ -505,7 +476,7 @@ def build_tree_distributed(mesh: Mesh, axis: str, learner_type: str,
                           feature_mask=fmask_l, strategy=strategy,
                           psum_fn=psum_fn, hist_backend=hist_backend,
                           num_hist_features=nhf, hist_mode=hist_mode,
-                          psum_axis=psum_axis, scales=scales)
+                          scales=scales)
 
     # the data-parallel learner on a Pallas backend emits each shard's
     # rows' leaf values from its final route kernel, as the serial

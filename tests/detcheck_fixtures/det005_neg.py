@@ -5,13 +5,13 @@ import os
 import jax
 
 
-def overlap_enabled():
-    # registered: PROGRAM_PAIRS `overlapped-vs-serial-psum` ->
-    # tests/test_overlap.py
-    return os.environ.get("LGBM_TPU_OVERLAP", "1") != "0"
+def donation_enabled():
+    # registered: PROGRAM_PAIRS `donation-on-vs-off` ->
+    # tests/test_mesh_block.py
+    return os.environ.get("LGBM_TPU_DONATE", "1") != "0"
 
 
 def run(x):
-    if overlap_enabled():
+    if donation_enabled():
         return jax.jit(lambda v: v + 1.0)(x)
     return x + 1.0

@@ -194,8 +194,7 @@ def test_registry_covers_known_seams():
     from tools.detcheck import parity_registry as reg
     envs = {e["env"] for e in reg.PROGRAM_PAIRS}
     assert {"LGBM_TPU_MESH_BLOCK", "LGBM_TPU_SPLIT_CACHE",
-            "LGBM_TPU_DONATE", "LGBM_TPU_OVERLAP",
-            "LGBM_TPU_DART_HOST_RNG"} <= envs
+            "LGBM_TPU_DONATE", "LGBM_TPU_DART_HOST_RNG"} <= envs
     assert "lightgbm_tpu/ops/split.py" in reg.TIE_BREAK
 
 

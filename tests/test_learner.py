@@ -4,14 +4,21 @@ Validation strategy mirrors the reference's (SURVEY.md §4): behavioral
 assertions on small data (a single tree must reproduce an exactly-learnable
 function) rather than C++-style unit mocks.
 """
+from types import SimpleNamespace
+
 import numpy as np
+import jax
 import jax.numpy as jnp
+import pytest
 
 from lightgbm_tpu.config import Config
 from lightgbm_tpu.io.dataset import BinnedDataset
 from lightgbm_tpu.io.device import to_device
 from lightgbm_tpu.learner.serial import (BuiltTree, GrowthParams, build_tree,
-                                         predict_built_tree)
+                                         make_hist_fold_fn,
+                                         predict_built_tree, resolve_backend,
+                                         stage_plan, wave_backend_plan)
+from lightgbm_tpu.ops.pallas_histogram import default_backend
 from lightgbm_tpu.ops.split import SplitParams
 
 
@@ -117,3 +124,99 @@ def test_min_data_in_leaf_respected():
     nl = int(tree.num_leaves)
     counts = np.asarray(tree.leaf_count)[:nl]
     assert (counts >= 5).all()
+
+
+@pytest.mark.parametrize("leaves", [255, 31])
+def test_auto_on_a_tpu_is_the_wide_kernel_in_every_wave(monkeypatch, leaves):
+    """On a TPU "auto" resolves to "pallas", and a wave of it has one
+    histogram path whatever its slot count (PR 27 stopped compacting
+    the 64- and 128-slot waves, PR 31 deleted the compaction): the fused
+    route+histogram kernel where its gate admits it, the wide kernel
+    elsewhere."""
+    monkeypatch.delenv("LGBM_TPU_HIST_BACKEND", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert default_backend() == "pallas"
+    dd = SimpleNamespace(group_max_bins=63)
+    assert resolve_backend(dd, leaves, "auto", "int8h") == "pallas"
+    plan, A_tail = stage_plan(leaves)
+    assert (plan[-2:], A_tail) == (([64, 128], 128) if leaves == 255
+                                   else ([8, 16], 16))
+    for fused_ok, want in ((True, "fused"), (False, "pallas")):
+        choices, tail = wave_backend_plan(leaves, backend="pallas",
+                                          fused_ok=fused_ok)
+        assert choices == [want] * len(plan) and tail == want
+    # leaf-wise growth: every wave is the 8-slot tail
+    assert wave_backend_plan(leaves, wave_size=1) == ([], "fused")
+
+
+@pytest.mark.parametrize("name", ["auto", "pallas", "scatter", "compact"])
+def test_hist_backend_names(monkeypatch, name):
+    """``hist_backend`` / ``LGBM_TPU_HIST_BACKEND`` take three names.
+    Any other would run the scatter learner without a word (no Pallas
+    branch recognises it), so it is refused, and the message names the
+    three."""
+    dd = SimpleNamespace(group_max_bins=63)
+    monkeypatch.delenv("LGBM_TPU_HIST_BACKEND", raising=False)
+    want = {"auto": default_backend()}.get(name, name)
+    for by_env in (False, True):
+        if by_env:
+            monkeypatch.setenv("LGBM_TPU_HIST_BACKEND", name)
+        asked = "auto" if by_env else name
+        if name == "compact":
+            with pytest.raises(ValueError, match="auto, pallas, scatter"):
+                resolve_backend(dd, 255, asked, "int8h")
+        else:
+            assert resolve_backend(dd, 255, asked, "int8h") == want
+
+
+def test_resolve_backend_logs_each_substitution_once(caplog):
+    """Whenever the resolved backend is not the one asked for, the
+    choice and its ground are logged — once per distinct case, at info."""
+    from lightgbm_tpu.utils.log import reset_log_once
+    reset_log_once()
+    dd = SimpleNamespace(group_max_bins=63)
+    with caplog.at_level("INFO", logger="lightgbm_tpu"):
+        # > 256 bins is outside the kernel model altogether
+        for _ in range(2):
+            assert resolve_backend(SimpleNamespace(group_max_bins=300),
+                                   255, "pallas", "int8h") == "scatter"
+        # more leaves than the route kernel's one-hot holds
+        assert resolve_backend(dd, 2048, "pallas", "int8h") == "scatter"
+        # the backend asked for: nothing to say
+        assert resolve_backend(dd, 255, "pallas", "int8h") == "pallas"
+        assert resolve_backend(dd, 255, "scatter", "int8h") == "scatter"
+    msgs = [r.getMessage() for r in caplog.records
+            if "histogram backend" in r.getMessage()]
+    assert len(msgs) == 2, msgs
+    assert all("scatter (asked for pallas)" in m for m in msgs)
+    assert "300 bins" in msgs[0] and "2048 leaves" in msgs[1]
+
+
+def test_hist_fold_logs_each_substitution_once(caplog):
+    """The streamed fold seam's one substitution (seeded wide kernel ->
+    carried f32 scatter fold, where the seeded cell is over the VMEM
+    model) is logged like resolve_backend's: once, at info, with the
+    ground."""
+    from lightgbm_tpu.utils.log import reset_log_once
+    reset_log_once()
+    dd63 = SimpleNamespace(group_max_bins=63, num_groups=28, num_data=8192)
+    dd255 = SimpleNamespace(group_max_bins=255, num_groups=28,
+                            num_data=8192)
+    with caplog.at_level("INFO", logger="lightgbm_tpu"):
+        # the fold the stream asked for: nothing to say
+        for mode in ("int8h", "hilo"):
+            fold = make_hist_fold_fn(dd63, 255, 128, 8192, "pallas", mode)
+            assert fold is not None and fold.hist_mode == mode
+        # the scatter backend keeps the carried f32 fold: nothing to say
+        assert make_hist_fold_fn(dd63, 255, 128, 8192, "scatter",
+                                 "int8h") is None
+        # the seeded wide cell at 255 bins x 128 slots is over the VMEM
+        # model (and the chip's compiler refuses it): scatter fold
+        for _ in range(2):
+            assert make_hist_fold_fn(dd255, 255, 128, 8192, "pallas",
+                                     "int8h") is None
+    msgs = [r.getMessage() for r in caplog.records
+            if "streamed histogram fold" in r.getMessage()]
+    assert len(msgs) == 1, msgs
+    assert msgs[0].startswith("streamed histogram fold: scatter (carried "
+                              "f32 fold) (resolved backend pallas)")

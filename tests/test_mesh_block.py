@@ -17,7 +17,14 @@ uses (one dispatch per window instead of one per iteration):
   ``gbdt.iteration`` spans, while the escape hatch dispatches per
   iteration; ``gbdt.dispatch_gap_mean_s`` is recorded on both;
 * ``LGBM_TPU_NO_BLOCK=1`` still reaches the legacy eager per-iteration
-  loop (``gbdt.iteration`` spans).
+  loop (``gbdt.iteration`` spans);
+* the data-parallel wave exchanges its histograms by ONE reduction of
+  the whole operand (flight recorder);
+* score-buffer donation through the fused block program changes
+  nothing observable: identical models, zero post-warmup recompiles
+  under the trace contract — and it is hard-gated OFF on the CPU
+  backend, where zero-copy ``np.asarray`` host reads alias the
+  memory donation would let XLA reuse.
 """
 import os
 
@@ -242,3 +249,156 @@ def test_mesh_scores_and_valid_placed_by_registry(n):
         ctx.replicated(), g._valid_scores[0].ndim)
     assert g._valid_device[0].bins.sharding.is_equivalent_to(
         ctx.replicated(), g._valid_device[0].bins.ndim)
+
+
+# ---------------------------------------------------------------------------
+# the exchange: one reduction a wave
+# ---------------------------------------------------------------------------
+def test_one_reduction_a_wave_on_the_flight_recorder(monkeypatch):
+    """A 2-shard data-parallel tree records exactly one
+    ``parallel.learners.hist_psum`` a wave, of the whole ``[A, G, B, C]``
+    operand (int32 code sums at an int8 mode): the unrolled waves of the
+    stage plan, then the tail's one traced body.  Around them, once a
+    tree: the scales' ``pmax`` and the root totals' ``psum``."""
+    import jax.numpy as jnp
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.io.dataset import BinnedDataset
+    from lightgbm_tpu.io.device import to_device
+    from lightgbm_tpu.learner import serial
+    from lightgbm_tpu.ops.split import SplitParams
+    from lightgbm_tpu.parallel.learners import build_tree_distributed
+    from lightgbm_tpu.parallel.mesh import make_mesh
+    # staged waves at a size the tracer alone sees (nothing executes)
+    monkeypatch.setattr(serial, "_COMPILE_LEAN_ROWS", 0)
+    X, y, _, _ = _data(n=2048, f=6)
+    dd = to_device(BinnedDataset.from_raw(
+        X, Config.from_params({"max_bin": 63})))
+    p = serial.GrowthParams(num_leaves=31, split=SplitParams(
+        min_data_in_leaf=5, min_sum_hessian_in_leaf=0.0))
+    plan, A_tail = serial.stage_plan(31)
+    assert plan == [8, 8, 8, 8, 16] and A_tail == 16
+    fr.reset()
+    jax.eval_shape(
+        lambda g, h: build_tree_distributed(
+            make_mesh(2), "data", "data", dd, g, h, p,
+            hist_backend="pallas", hist_mode="int8h"),
+        jnp.asarray(y, jnp.float32), jnp.ones(len(y), jnp.float32))
+    sites = [(e["site"].rsplit(".", 1)[1], e["op"], e["shape"], e["dtype"])
+             for e in fr.snapshot()["last"]]
+    fr.reset()
+    G, B = dd.num_groups, 64
+    assert sites == (
+        [("scale_pmax", "pmax", (2,), "float32"),
+         ("root_psum", "psum", (4,), "int32")]
+        + [("hist_psum", "psum", (A, G, B, 4), "int32")
+           for A in plan + [A_tail]])
+
+
+# ---------------------------------------------------------------------------
+# donation: the fused block's score buffers
+# ---------------------------------------------------------------------------
+def _train_small(n_rounds=12):
+    rng = np.random.RandomState(7)
+    X = rng.rand(400, 5).astype(np.float32)
+    y = (X[:, 0] + 0.2 * rng.rand(400) > 0.6).astype(np.float64)
+    Xv = rng.rand(160, 5).astype(np.float32)
+    yv = (Xv[:, 0] + 0.2 * rng.rand(160) > 0.6).astype(np.float64)
+    train = lgb.Dataset(X, label=y)
+    valid = lgb.Dataset(Xv, label=yv, reference=train)
+    return lgb.train(
+        {"objective": "binary", "num_iterations": n_rounds,
+         "num_leaves": 7, "min_data_in_leaf": 5, "output_freq": 4,
+         "verbose": -1},
+        train, valid_sets=[valid])
+
+
+def test_donation_gated_off_on_cpu(monkeypatch):
+    """Donation is hard-gated to accelerator backends: on CPU,
+    ``np.asarray`` host reads are zero-copy views into the very memory
+    a donated dispatch lets XLA reuse — eval reading a just-returned
+    score buffer flakily SIGSEGVs (reproduced on this image).  So
+    ``LGBM_TPU_DONATE=1`` must NOT enable donation on CPU, while the
+    same env on an accelerator backend must."""
+    from lightgbm_tpu.boosting import gbdt as gbdt_mod
+    monkeypatch.setenv("LGBM_TPU_DONATE", "1")
+    assert jax.default_backend() == "cpu"
+    assert not gbdt_mod._donation_enabled()
+    monkeypatch.setattr(gbdt_mod.jax, "default_backend", lambda: "tpu")
+    assert gbdt_mod._donation_enabled()
+    monkeypatch.setenv("LGBM_TPU_DONATE", "0")
+    assert not gbdt_mod._donation_enabled()
+
+
+def test_donation_env_flip_identical_model_and_zero_steady_recompiles(
+        monkeypatch):
+    """Flipping ``LGBM_TPU_DONATE`` must never change the model, and
+    the block program holds the trace contract — zero post-warmup
+    recompiles (the donation gate must not perturb the jit cache).
+    On CPU both arms run undonated (see the gating test above)."""
+    monkeypatch.setenv("LGBM_TPU_DONATE", "0")
+    undonated = _train_small()._gbdt.save_model_to_string()
+    monkeypatch.setenv("LGBM_TPU_DONATE", "1")
+    monkeypatch.setenv("LGBM_TPU_TRACE_CONTRACT", "1")
+    obs.reset()
+    try:
+        bst = _train_small()
+        donated = bst._gbdt.save_model_to_string()
+        rep = obs.summary().get("trace_contract")
+        assert rep is not None, "trace_contract section missing"
+        assert rep["compiles_steady"] == 0 and rep["steady_ok"], rep
+    finally:
+        obs.reset()
+    assert donated == undonated
+    # the live score buffers after the run are the block outputs: they
+    # must be intact and readable (nothing aliases a dead buffer)
+    scores = np.asarray(bst._gbdt.scores)
+    assert np.all(np.isfinite(scores))
+
+
+def test_donation_scores_usable_across_blocks(monkeypatch):
+    """Consecutive block dispatches chain each output into the next
+    input; eval/metric reads between blocks must see live buffers.
+    (On CPU the donation gate keeps dispatches undonated — this is
+    exactly the read pattern the gate exists to protect.)"""
+    monkeypatch.setenv("LGBM_TPU_DONATE", "1")
+    rng = np.random.RandomState(2)
+    X = rng.rand(500, 4).astype(np.float32)
+    y = (X[:, 0] > 0.5).astype(np.float64)
+    ds = lgb.Dataset(X, label=y)
+    bst = lgb.train({"objective": "binary", "num_leaves": 7,
+                     "min_data_in_leaf": 5, "verbose": -1}, ds,
+                    num_boost_round=3, verbose_eval=False,
+                    keep_training_booster=True)
+    g = bst._gbdt
+    for _ in range(3):
+        s = np.asarray(g.scores)       # host read between dispatches
+        assert np.all(np.isfinite(s))
+        g.train_block(2)
+    assert g.num_trees() >= 9
+
+
+# ---------------------------------------------------------------------------
+# placement: the once-placed sharded store
+# ---------------------------------------------------------------------------
+def test_mesh_place_data_shards_bins_once():
+    """place_data puts the bins store on the mesh row-sharded and the
+    metadata replicated — the explicit shard rules the per-iteration
+    builds then consume in place."""
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.io.dataset import BinnedDataset
+    from lightgbm_tpu.io.device import to_device
+    from lightgbm_tpu.parallel.mesh import MeshContext
+    X, _, _, _ = _data(n=2048, f=8)
+    dd = to_device(BinnedDataset.from_raw(
+        X, Config.from_params({"max_bin": 63})))
+    c = Config.from_params({"tree_learner": "data", "mesh_shape": [2]})
+    ctx = MeshContext(c)
+    placed = ctx.place_data(dd, row_sharded=True)
+    assert placed.bins.sharding == ctx.row_sharding()
+    assert placed.num_bins.sharding.is_equivalent_to(
+        ctx.replicated(), placed.num_bins.ndim)
+    np.testing.assert_array_equal(np.asarray(placed.bins),
+                                  np.asarray(dd.bins))
+    # static metadata survives the round trip
+    assert placed.total_bins == dd.total_bins
+    assert placed.max_bins == dd.max_bins

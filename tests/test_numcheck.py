@@ -381,7 +381,7 @@ def test_identity_check_full_matrix():
     (acceptance: ISSUE 19; streamed-kernel groups ISSUE 20) —
     serial/stream1 byte-identical at S=1, mesh2/mesh2_block0/stream2/
     elastic1 byte-identical at S=2, the forced-backend pairs
-    byte-identical within S=1·pallas / S=1·compact, zero ulp-budget
+    byte-identical within S=1·pallas, zero ulp-budget
     trips, with the determinism ledger and the num contract armed.
     Subprocess: the harness pins a 2-device host pool via XLA_FLAGS
     before jax initializes, which this process cannot."""
@@ -402,10 +402,8 @@ def test_identity_check_full_matrix():
     import json
     rec = json.loads(payload[-1])
     assert "S=1·pallas: OK" in proc.stdout, proc.stdout
-    assert "S=1·compact: OK" in proc.stdout, proc.stdout
     assert rec["identity_check_ok"] is True
     assert set(rec["scenarios"]) == {"serial", "stream1", "mesh2",
                                      "mesh2_block0", "stream2",
                                      "elastic1", "serial_pallas",
-                                     "stream1_pallas", "serial_compact",
-                                     "stream1_compact"}
+                                     "stream1_pallas"}

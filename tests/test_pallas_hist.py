@@ -16,19 +16,14 @@ from lightgbm_tpu.ops.pallas_histogram import (
     pack_values_q, transpose_bins)
 
 
-@pytest.mark.parametrize("max_bins,F,mode,kernel", [
-    (63, 28, "hilo", "wide"),
-    (63, 28, "bf16", "wide"),
-    (255, 10, "hilo", "wide"),  # forces feature tiling (acc VMEM budget)
-    # the leaf-compacted deep-wave kernel shares this oracle matrix
-    # (ops/compact.py; deep-slot shapes in tests/test_compact.py)
-    (63, 28, "hilo", "compact"),
-    (255, 10, "hhilo", "compact"),
+@pytest.mark.parametrize("max_bins,F,mode", [
+    (63, 28, "hilo"),
+    (63, 28, "bf16"),
+    (255, 10, "hilo"),  # forces feature tiling (acc VMEM budget)
 ])
-def test_kernel_matches_scatter(max_bins, F, mode, kernel):
+def test_kernel_matches_scatter(max_bins, F, mode):
     rng = np.random.RandomState(7)
-    n, L = 3000, 31
-    A = 15 if kernel == "wide" else 64   # compact needs A > threshold-ish
+    n, L, A = 3000, 31, 15
     bins = rng.randint(0, max_bins, size=(n, F)).astype(np.uint8)
     grad = rng.normal(size=n).astype(np.float32)
     hess = rng.uniform(0.1, 1.0, size=n).astype(np.float32)
@@ -40,18 +35,9 @@ def test_kernel_matches_scatter(max_bins, F, mode, kernel):
     bins_j = jnp.asarray(bins)
     bt = transpose_bins(bins_j)
     vals = pack_values(jnp.asarray(grad), jnp.asarray(hess), mode)
-    if kernel == "wide":
-        out_p = hist_active_pallas(
-            bt, vals, jnp.asarray(row_leaf), jnp.asarray(active),
-            num_features=F, max_bins=max_bins, mode=mode, interpret=True)
-    else:
-        from lightgbm_tpu.ops.compact import hist_active_compact
-        leaf_p = jnp.pad(jnp.asarray(row_leaf), (0, bt.shape[1] - n),
-                         constant_values=-1)
-        out_p = hist_active_compact(
-            bt, vals, leaf_p, jnp.asarray(active),
-            num_features=F, max_bins=max_bins, num_leaf_slots=L,
-            mode=mode, interpret=True)
+    out_p = hist_active_pallas(
+        bt, vals, jnp.asarray(row_leaf), jnp.asarray(active),
+        num_features=F, max_bins=max_bins, mode=mode, interpret=True)
     out_s = hist_active_scatter(
         bins_j, jnp.asarray(grad), jnp.asarray(hess),
         jnp.asarray(row_leaf), jnp.asarray(active),
@@ -155,6 +141,77 @@ def test_criteo_width_runs_the_cells_tiles(mode, A):
         scale = np.abs(s[..., :2]).max() + 1e-9
         np.testing.assert_allclose(p[..., :2] / scale, s[..., :2] / scale,
                                    atol=5e-4)
+
+
+@pytest.mark.parametrize("mode,max_bins,F,A", [
+    (*shape, A) for A in (64, 128) for shape in [
+        ("int8h", 63, 67),  # the benchmark cells' 256- and 512-column calls
+        ("int8h", 255, 10),     # 255-bin stride: the smallest feature tile
+        ("int8", 63, 28),
+        ("int8hh", 63, 28),
+        ("hilo", 63, 28),
+        ("hhilo", 255, 10),
+        ("bf16", 63, 28),
+    ]] + [("int8h", 63, 28, 8), ("int8h", 63, 28, 32)])  # chip_smoke.py's
+def test_wide_kernel_deep_waves_match_scatter(mode, max_bins, F, A):
+    """The 64- and 128-slot waves of a 255-leaf tree (half the kernel
+    time of the benchmark's cells), and the HIGGS width `chip_smoke.py`
+    trains at in a first and a 32-slot wave, against the scatter oracle:
+    bagged-out rows, leaves no wave asked for, ``-1`` padding slots,
+    slots whose leaf holds no row, and a last row tile that the rows do
+    not fill.  A quantised mode's int32 code sums equal the oracle's
+    sums of the same codes bit for bit; a float mode's sums hold the
+    tolerances above, with exact counts."""
+    from lightgbm_tpu.ops.pallas_histogram import (is_quantized,
+                                                   pallas_config_ok)
+    assert pallas_config_ok(max_bins, 255, mode)
+    rng = np.random.RandomState(31)
+    n, L, k = 3000, 255, A - 4
+    bins_j = jnp.asarray(rng.randint(0, max_bins, size=(n, F)), jnp.uint8)
+    grad = rng.normal(size=n).astype(np.float32)
+    hess = rng.uniform(0.1, 1.0, size=n).astype(np.float32)
+    row_leaf = rng.randint(-1, L, size=n).astype(np.int32)
+    row_leaf[row_leaf >= 200] = -1          # leaves 200.. hold no row
+    active = np.full(A, -1, np.int32)
+    active[:k] = rng.permutation(100 + np.arange(k) * max(1, 150 // k))
+    empty = active[:k] >= 200
+    assert empty.any() and not empty.all()
+    bt = transpose_bins(bins_j)
+    assert bt.shape[1] > n
+    row_leaf_j, active_j = jnp.asarray(row_leaf), jnp.asarray(active)
+
+    def scatter(g, h):
+        return np.asarray(hist_active_scatter(
+            bins_j, jnp.asarray(g), jnp.asarray(h), row_leaf_j, active_j,
+            max_bins=max_bins, num_leaf_slots=L))[:k]
+
+    if is_quantized(mode):
+        vals, _ = pack_values_q(jnp.asarray(grad), jnp.asarray(hess), mode)
+        # scales=None: the [A, F, B, C] int32 code sums as they are
+        p = np.asarray(hist_active_pallas(
+            bt, vals, row_leaf_j, active_j, None, num_features=F,
+            max_bins=max_bins, mode=mode, interpret=True))[:k]
+        # one oracle pass a code column; the last column is the count
+        codes = np.asarray(vals)[:-1, :n].astype(np.float32)
+        sums = [scatter(c, c) for c in codes]
+        want = np.stack([s[..., 0] for s in sums] + [sums[0][..., 2]],
+                        axis=-1)
+        assert p.dtype == np.int32 and p.shape == want.shape
+        np.testing.assert_array_equal(p, want.astype(np.int32))
+        assert np.abs(p[~empty]).max() > 0
+    else:
+        vals = pack_values(jnp.asarray(grad), jnp.asarray(hess), mode)
+        p = np.asarray(hist_active_pallas(
+            bt, vals, row_leaf_j, active_j, num_features=F,
+            max_bins=max_bins, mode=mode, interpret=True))[:k]
+        s = scatter(grad, hess)
+        assert p.shape == s.shape == (k, F, bin_stride(max_bins), 3)
+        np.testing.assert_array_equal(p[..., 2], s[..., 2])
+        tol = 5e-4 if mode == "hilo" else 2e-2
+        scale = np.abs(s[..., :2]).max() + 1e-9
+        np.testing.assert_allclose(p[..., :2] / scale, s[..., :2] / scale,
+                                   atol=tol)
+    np.testing.assert_array_equal(p[empty], 0)
 
 
 def test_hilo_split_survives_jit():

@@ -270,29 +270,42 @@ def uneven():
 
 
 @pytest.fixture(scope="module")
-def serial_trees(uneven):
+def row_sets():
+    """What a tree is grown on: all rows and features, or a bag of 70%
+    of the rows (the same rows however they are cut into shards) under
+    a feature mask."""
+    bag = jnp.asarray(np.random.RandomState(11).rand(2048) < 0.7)
+    fmask = jnp.asarray(np.array([1, 1, 0, 1, 1, 0, 1, 1], bool))
+    return {"all": {}, "bagged": dict(bag_mask=bag, feature_mask=fmask)}
+
+
+@pytest.fixture(scope="module")
+def serial_trees(uneven, row_sets):
     dd, grad, hess, p = uneven
-    return {mode: jax.jit(lambda g, h, m=mode: build_tree(
-        dd, g, h, p, hist_backend="pallas", hist_mode=m))(grad, hess)
-        for mode in ("int8", "int8h", "int8hh")}
+    return {(mode, rows): jax.jit(lambda g, h, m=mode, kw=kw: build_tree(
+        dd, g, h, p, hist_backend="pallas", hist_mode=m, **kw))(grad, hess)
+        for mode in ("int8", "int8h", "int8hh")
+        for rows, kw in row_sets.items()}
 
 
-@pytest.mark.parametrize("overlap", [True, False], ids=["overlap", "plain"])
+@pytest.mark.parametrize("rows", ["all", "bagged"])
 @pytest.mark.parametrize("shards", [1, 2, 4])
 @pytest.mark.parametrize("mode", ["int8", "int8h", "int8hh"])
 def test_quantised_data_parallel_grows_the_serial_tree(
-        eight_devices, uneven, serial_trees, mode, shards, overlap):
+        eight_devices, uneven, row_sets, serial_trees, mode, shards, rows):
     """Global scales, integer code sums across the shards, one
     dequantisation: every field of the tree, gains and leaf values
-    included, is the serial learner's bit for bit."""
+    included, is the serial learner's bit for bit — bagged-out rows,
+    masked features and padding slots included."""
     dd, grad, hess, p = uneven
     dist = build_tree_distributed(make_mesh(shards), "data", "data", dd,
                                   grad, hess, p, hist_backend="pallas",
-                                  hist_mode=mode, overlap=overlap)
+                                  hist_mode=mode, **row_sets[rows])
     for name in TREE_FIELDS:
         np.testing.assert_array_equal(
             np.asarray(getattr(dist, name)),
-            np.asarray(getattr(serial_trees[mode], name)), err_msg=name)
+            np.asarray(getattr(serial_trees[mode, rows], name)),
+            err_msg=name)
 
 
 def test_code_sums_cross_the_shards_without_wrapping(eight_devices):

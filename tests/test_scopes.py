@@ -24,8 +24,7 @@ import lightgbm_tpu as lgb
 from lightgbm_tpu import obs
 
 SCOPES = ["obj.grad", "tree.pack", "tree.init", "tree.route", "tree.hist",
-          "tree.compact.plan", "tree.compact.regroup", "tree.split_find",
-          "tree.update", "gbdt.score_update"]
+          "tree.split_find", "tree.update", "gbdt.score_update"]
 
 
 @pytest.fixture(autouse=True)
@@ -42,16 +41,16 @@ def _data(n=3000, f=6, seed=0):
     return X, y
 
 
-def _lower_block(backend="compact"):
-    """The length-1 block program of a booster on the kernel path
-    (interpreted off-TPU), 80 leaves so that ``compact`` has waves to
-    compact."""
+def _lower_block():
+    """The length-1 block program of a booster on the kernel path (what
+    ``auto`` is on a TPU; interpreted off it), 80 leaves: a 64-slot
+    wave among them."""
     X, y = _data()
     params = {"objective": "binary", "num_leaves": 80, "max_bin": 15,
               "min_data_in_leaf": 2, "verbose": -1}
     ds = lgb.Dataset(X, label=y, params={"max_bin": 15})
     g = lgb.Booster(params=params, train_set=ds)._gbdt
-    assert g.hist_backend == backend
+    assert g.hist_backend == "pallas"
     return g._make_block_fn(1).lower(
         g.device_data, g._bins_t, tuple(g._valid_device), g.scores,
         tuple(g._valid_scores), jnp.float32(0.1), jnp.int32(0),
@@ -62,7 +61,7 @@ def _lower_block(backend="compact"):
 def lowered():
     """``(with the scopes, with jax.named_scope a no-op)``."""
     with pytest.MonkeyPatch.context() as mp:
-        mp.setenv("LGBM_TPU_HIST_BACKEND", "compact")
+        mp.setenv("LGBM_TPU_HIST_BACKEND", "pallas")
         jax.clear_caches()
         scoped = _lower_block()
         jax.clear_caches()              # the jitted wrappers' traces too
@@ -84,30 +83,10 @@ def test_block_program_names_its_parts(lowered, scope):
 def test_scopes_leave_the_compiled_block_as_it_was(lowered):
     def stripped(low):
         return re.sub(r", metadata=\{[^}]*\}", "", low.compile().as_text())
-    assert "tree.compact.plan" in lowered[0].compile().as_text()
+    assert "tree.hist" in lowered[0].compile().as_text()
     scoped, plain = (stripped(low) for low in lowered)
-    assert "tree.compact.plan" not in scoped and "metadata=" not in scoped
+    assert "tree.hist" not in scoped and "metadata=" not in scoped
     assert scoped == plain
-
-
-def test_default_block_program_does_not_compact(monkeypatch):
-    """What ``auto`` is on a TPU (PR 27: the wide kernel in every wave)
-    traces no compaction: the block program names ``tree.hist`` and no
-    ``tree.compact.*`` scope, and holds no ``hist_active_compact``."""
-    from lightgbm_tpu.ops.pallas_histogram import default_backend
-    monkeypatch.delenv("LGBM_TPU_HIST_BACKEND", raising=False)
-    with monkeypatch.context() as mp:
-        mp.setattr(jax, "default_backend", lambda: "tpu")
-        on_tpu = default_backend()
-    assert on_tpu == "pallas"
-    # off the TPU the same kernels run interpreted, under the same names
-    monkeypatch.setenv("LGBM_TPU_HIST_BACKEND", on_tpu)
-    text = _lower_block(on_tpu).as_text(debug_info=True)
-    assert re.search(r'[/"]tree\.hist[/"]', text)
-    # the wide kernel, here fused with the route (the shape is small)
-    assert re.search(r"jit\(hist_(route|active)_pallas\)", text)
-    assert "tree.compact." not in text
-    assert "hist_active_compact" not in text
 
 
 @pytest.mark.parametrize("recording", ["recorded.xplane.pb.gz",
@@ -115,8 +94,8 @@ def test_default_block_program_does_not_compact(monkeypatch):
 def test_the_benchmark_counts_the_compacted_waves_by_name(recording):
     """``kernels.hist_compact_calls_per_iter`` (PR 27) counts the grouped
     kernel's calls by its jitted wrapper's name: 2 an iteration on both
-    traces recorded while ``auto`` still compacted (two steps each); the
-    default program has no such wrapper (above), so there it reads 0."""
+    traces recorded while ``auto`` still compacted (two steps each).  The
+    program has held no such wrapper since PR 31, so there it reads 0."""
     import sys
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     sys.path.insert(0, root)
@@ -223,13 +202,13 @@ def test_a_replaced_hist_mode_shows_in_the_summary(monkeypatch, degraded):
 
 def test_no_scope_stands_between_a_kernel_and_its_jitted_wrapper():
     """The TPU compiler names a custom call after the name-stack
-    component right around it: ``jit(hist_active_compact)`` gives
-    ``%hist_active_compact.N``, which the benchmark's class ``hist``
+    component right around it: ``jit(hist_active_pallas)`` gives
+    ``%hist_active_pallas.N``, which the benchmark's class ``hist``
     matches.  A scope inside the wrapper around the ``pallas_call`` would
     rename the kernel (it did: ``%tree.hist.N``), so the kernel's scope
     is the caller's."""
-    from lightgbm_tpu.ops import compact
-    from lightgbm_tpu.ops.pallas_histogram import (pack_values_q,
+    from lightgbm_tpu.ops.pallas_histogram import (hist_active_pallas,
+                                                   pack_values_q,
                                                    transpose_bins)
     n, F = 2048, 4
     rng = np.random.RandomState(0)
@@ -238,32 +217,44 @@ def test_no_scope_stands_between_a_kernel_and_its_jitted_wrapper():
     vals, scales = pack_values_q(jnp.asarray(rng.normal(size=n), jnp.float32),
                                  jnp.ones(n, jnp.float32), "int8h")
     leaf = jnp.asarray(rng.randint(0, 40, size=bins_t.shape[1]), jnp.int32)
-    jaxpr = jax.make_jaxpr(lambda *a: compact.hist_active_compact(
-        *a, num_features=F, max_bins=15, num_leaf_slots=80, mode="int8h",
-        interpret=True))(bins_t, vals, leaf, jnp.arange(40, dtype=jnp.int32),
-                         scales)
+
+    def caller(*a):
+        with jax.named_scope("tree.hist"):
+            return hist_active_pallas(*a, num_features=F, max_bins=15,
+                                      mode="int8h", interpret=True)
+    jaxpr = jax.make_jaxpr(caller)(bins_t, vals, leaf,
+                                   jnp.arange(40, dtype=jnp.int32), scales)
     (wrapper,) = jaxpr.jaxpr.eqns
+    assert wrapper.params["name"] == "hist_active_pallas"
+    assert str(wrapper.source_info.name_stack) == "tree.hist"
     stacks = {e.primitive.name: str(e.source_info.name_stack)
               for e in wrapper.params["jaxpr"].jaxpr.eqns}
-    assert stacks["pallas_call"] == ""
-    assert {"tree.compact.plan", "tree.compact.regroup"} <= set(
-        stacks.values())
+    assert set(stacks.values()) == {""} and "pallas_call" in stacks
 
 
 # ---------------------------------------------------------------------------
 # the row-sharded exchange (ISSUE 28): collective.* on the mesh block
 # ---------------------------------------------------------------------------
-COLLECTIVES = ["collective.hist_psum", "collective.root_psum",
-               "collective.scale_pmax"]
+# per learner, every scope of its exchange: the wave's reduction, which
+# `collective.hist_psum_ms_per_iter` reads, and the six names that
+# `collective.other_ms_per_iter` sums
+COLLECTIVES = {
+    "data": ["collective.hist_psum", "collective.root_psum",
+             "collective.scale_pmax", "collective.count_psum"],
+    "voting": ["collective.vote_gather", "collective.sel_psum",
+               "collective.root_psum", "collective.scale_pmax",
+               "collective.count_psum"],
+    "feature": ["collective.sync_global_best"],
+}
 
 
-def _lower_mesh_block(overlap: bool):
-    """The length-1 block program of a two-shard ``tree_learner=data``
-    booster on the kernel path (interpreted off-TPU) at ``int8h``."""
+def _lower_mesh_block(learner: str):
+    """The length-1 block program of a two-shard booster on the kernel
+    path (interpreted off-TPU) at ``int8h``."""
     X, y = _data()
     params = {"objective": "binary", "num_leaves": 15, "max_bin": 15,
-              "min_data_in_leaf": 2, "verbose": -1, "tree_learner": "data",
-              "mesh_shape": [2], "hist_mode": "int8h"}
+              "min_data_in_leaf": 2, "verbose": -1, "tree_learner": learner,
+              "mesh_shape": [2], "hist_mode": "int8h", "top_k": 3}
     ds = lgb.Dataset(X, label=y, params={"max_bin": 15})
     g = lgb.Booster(params=params, train_set=ds)._gbdt
     assert g.hist_backend == "pallas" and g.mesh_ctx is not None
@@ -273,24 +264,32 @@ def _lower_mesh_block(overlap: bool):
         jnp.int32(1))
 
 
-@pytest.fixture(scope="module", params=[True, False],
-                ids=["overlap", "plain"])
+_MESH_TEXTS = {}
+
+
+@pytest.fixture
 def compiled_mesh(request):
     """``(optimized HLO with the scopes, with jax.named_scope a no-op,
-    the unscoped lowering's own text)`` of the mesh block, for either
-    lowering of the wave reduction.  The persistent compile cache is
-    off meanwhile: its key leaves metadata out, so the second compile
-    would be handed the first one's program, names and all."""
+    the unscoped lowering's own text)`` of the mesh block of the learner
+    that is the test's (indirect) parameter, compiled once a learner.
+    The persistent compile cache is off meanwhile: its key leaves
+    metadata out, so the second compile would be handed the first one's
+    program, names and all."""
     if len(jax.devices()) < 2:
         pytest.skip("needs 2 virtual devices")
+    if request.param in _MESH_TEXTS:
+        return _MESH_TEXTS[request.param]
     from jax.experimental.compilation_cache import compilation_cache
+    from lightgbm_tpu.learner import serial
     cached = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()     # or the switch is not looked at
     try:
         with pytest.MonkeyPatch.context() as mp:
             mp.setenv("LGBM_TPU_HIST_BACKEND", "pallas")
-            mp.setenv("LGBM_TPU_OVERLAP", "1" if request.param else "0")
+            # the integer recount of the leaves' rows, which the program
+            # runs past 2^24 rows in all
+            mp.setattr(serial, "F32_EXACT_ROWS", 0)
             texts = []
             for scoped in (True, False):    # one call site: the program
                 jax.clear_caches()          # text holds its line numbers
@@ -304,24 +303,27 @@ def compiled_mesh(request):
         jax.config.update("jax_enable_compilation_cache", cached)
         compilation_cache.reset_cache()
         jax.clear_caches()
-    return tuple(texts)
+    return _MESH_TEXTS.setdefault(request.param, tuple(texts))
 
 
-@pytest.mark.parametrize("scope", COLLECTIVES)
+@pytest.mark.parametrize(
+    "compiled_mesh,scope",
+    [(learner, scope) for learner, scopes in COLLECTIVES.items()
+     for scope in scopes], indirect=["compiled_mesh"])
 def test_the_exchange_is_named_on_the_optimized_program(compiled_mesh,
                                                         scope):
-    """Every all-reduce of the data-parallel block carries a
-    ``collective.*`` scope in the optimized HLO's metadata, whichever
-    lowering runs: the wave reduction (both lowerings one name), the
-    root statistics, the scales."""
+    """Every collective of a mesh block carries a ``collective.*``
+    scope in the optimized HLO's metadata, and every scope the
+    benchmark reads names one of its learner's."""
     scoped, plain, plain_lowered = compiled_mesh
     named = [line for line in scoped.splitlines()
-             if re.search(r" all-reduce(-start)?\(", line)]
+             if re.search(r" all-(reduce|gather)(-start)?\(", line)]
     assert named and all("collective." in line for line in named), named
     assert any(scope + "/" in line for line in named), scope
     assert scope not in plain and scope not in plain_lowered
 
 
+@pytest.mark.parametrize("compiled_mesh", list(COLLECTIVES), indirect=True)
 def test_the_collective_scopes_leave_the_compiled_block_as_it_was(
         compiled_mesh):
     def bare(text):
@@ -332,5 +334,6 @@ def test_the_collective_scopes_leave_the_compiled_block_as_it_was(
         text = re.sub(r", metadata=\{[^}]*\}", "", text)
         return re.sub(r"%[\w\-.]+", "%", text)
     scoped, plain = (bare(text) for text in compiled_mesh[:2])
-    assert "collective." not in scoped and "all-reduce(" in scoped
+    assert "collective." not in scoped
+    assert re.search(r" all-(reduce|gather)\(", scoped)
     assert scoped == plain

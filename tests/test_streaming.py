@@ -12,7 +12,7 @@ invariance, tail blocks, and the documented descopes.
 
 ISSUE 20 extends the matrix to the kernel backends and the pipeline:
 
-* accumulator-SEEDED Pallas/compact folds (``make_hist_fold_fn``) are
+* accumulator-SEEDED Pallas folds (``make_hist_fold_fn``) are
   byte-identical to the in-memory monolithic kernels, serial AND
   2-shard (kernels force-run on CPU through the auto-interpret path);
 * the depth-2 upload/compute pipeline (``LGBM_TPU_STREAM_PIPELINE``)
@@ -230,39 +230,32 @@ def test_train_streaming_public_surface(tmp_path):
 # ---------------------------------------------------------------------------
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-# (backend, extra env): compact's slot threshold drops to 4 so the
-# num_leaves=15 tail wave actually selects the compact kernel on the
-# toy tree
-KERNEL_BACKENDS = [
-    ("pallas", {}),
-    ("compact", {"LGBM_TPU_COMPACT_SLOTS": "4"}),
-]
+# a streamed tree runs every wave at the tail width: 8 slots at 15
+# leaves, 40 (a 256-column seeded call, as a deep wave's) at 80
+KERNEL_LEAVES = [15, 80]
 
 
-@pytest.mark.parametrize("backend,extra", KERNEL_BACKENDS,
-                         ids=[b for b, _ in KERNEL_BACKENDS])
-def test_streamed_kernel_fold_byte_identical(monkeypatch, backend, extra):
+@pytest.mark.parametrize("leaves", KERNEL_LEAVES)
+def test_streamed_kernel_fold_byte_identical(monkeypatch, leaves):
     """ISSUE 20 gate: the accumulator-SEEDED kernel folds (carried
     operand via input_output_aliases) make multi-block streamed
     training byte-identical to the in-memory monolithic kernel — both
-    sides forced onto the same backend, run on CPU through the
+    sides forced onto the kernel backend, run on CPU through the
     auto-interpret path."""
-    monkeypatch.setenv("LGBM_TPU_HIST_BACKEND", backend)
-    for k, v in extra.items():
-        monkeypatch.setenv(k, v)
+    monkeypatch.setenv("LGBM_TPU_HIST_BACKEND", "pallas")
     X, y = _data()
-    params = dict(BASE, num_iterations=3)
+    params = dict(BASE, num_iterations=3, num_leaves=leaves)
     cfg, res = _resident(X, y, params)
     tr = StreamTrainer(cfg, res, block_rows=STREAM_CHUNK)
     assert tr._fold is not None, "seeded fold must engage"
-    assert tr.backend == backend
+    assert tr.backend == "pallas" and tr.A_tail == (8 if leaves == 15
+                                                    else 40)
     assert len(tr._blocks()) > 1, "parity must exercise MULTI-block"
     assert tr.train(3).digest() == _mem_digest(X, y, params)
 
 
-@pytest.mark.parametrize("backend,extra", KERNEL_BACKENDS,
-                         ids=[b for b, _ in KERNEL_BACKENDS])
-def test_two_shard_kernel_fold_parity(backend, extra):
+@pytest.mark.parametrize("leaves", KERNEL_LEAVES)
+def test_two_shard_kernel_fold_parity(leaves):
     """Seeded kernel folds under 2-shard data-parallel == the
     in-memory 2-shard mesh.  Re-execed in a child with a forced
     2-device CPU pool (tier-1 runs on one device; XLA_FLAGS must be
@@ -272,8 +265,7 @@ def test_two_shard_kernel_fold_parity(backend, extra):
         os.environ["JAX_PLATFORMS"] = "cpu"
         os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
             + " --xla_force_host_platform_device_count=2").strip()
-        os.environ["LGBM_TPU_HIST_BACKEND"] = {backend!r}
-        os.environ.update({extra!r})
+        os.environ["LGBM_TPU_HIST_BACKEND"] = "pallas"
         import sys
         sys.path.insert(0, {_REPO!r})
         import numpy as np
@@ -287,7 +279,7 @@ def test_two_shard_kernel_fold_parity(backend, extra):
         X = rng.normal(size=(n, 6))
         y = (X[:, 0] + 0.5 * X[:, 1]
              + rng.normal(scale=0.3, size=n) > 0).astype(np.float32)
-        params = {{"objective": "binary", "num_leaves": 15,
+        params = {{"objective": "binary", "num_leaves": {leaves},
                    "max_bin": 63, "learning_rate": 0.1,
                    "num_iterations": 3, "verbose": -1,
                    "tree_learner": "data", "mesh_shape": [2]}}
@@ -297,7 +289,7 @@ def test_two_shard_kernel_fold_parity(backend, extra):
         res = BinnedDataset.from_raw(X, cfg, metadata=md)
         tr = StreamTrainer(cfg, res, block_rows=STREAM_CHUNK)
         assert tr.S == 2 and tr._fold is not None
-        assert tr.backend == {backend!r}, tr.backend
+        assert tr.backend == "pallas", tr.backend
         d_str = tr.train(3).digest()
         d_mem = lgb.train(params, lgb.Dataset(X, label=y,
                                               params=params))._gbdt.digest()

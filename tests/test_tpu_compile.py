@@ -2,8 +2,8 @@
 
 Every other Pallas test in this suite runs ``interpret=True`` on the CPU,
 which cannot see what the chip's compiler refuses: a block shape that
-breaks Mosaic's (8, 128) rule (the leaf-compacted kernel's group-active
-operand before PR 21), a grid cell that overflows scoped VMEM (the
+breaks Mosaic's (8, 128) rule (a (32, 1) window into a table, before
+PR 21), a grid cell that overflows scoped VMEM (the
 seeded wide fold at 128 slots before PR 21).  The TPU compiler is
 installed in this image and compiles for a chip that is DESCRIBED, not
 attached (``jax.experimental.topologies``), so these tests compile the
@@ -98,15 +98,15 @@ def _split_tables(s):
             s(leaf, i32)] + [s((F,), i32) for _ in range(6)]
 
 
-def _device_data(n, bins_sharding, meta_sharding):
+def _device_data(n, bins_sharding, meta_sharding, features=F):
     s, m = _shapes(bins_sharding), _shapes(meta_sharding)
-    meta = lambda: m((F,), jnp.int32)                   # noqa: E731
+    meta = lambda: m((features,), jnp.int32)            # noqa: E731
     return DeviceData(
-        bins=s((n, F), jnp.uint8), bin_offsets=meta(), num_bins=meta(),
-        default_bins=meta(), missing_types=meta(),
-        is_categorical=m((F,), jnp.bool_), nan_bins=meta(),
+        bins=s((n, features), jnp.uint8), bin_offsets=meta(),
+        num_bins=meta(), default_bins=meta(), missing_types=meta(),
+        is_categorical=m((features,), jnp.bool_), nan_bins=meta(),
         feat_group=meta(), feat_offset=meta(),
-        total_bins=F * MAX_BIN, max_bins=MAX_BIN, has_categorical=False,
+        total_bins=features * MAX_BIN, max_bins=MAX_BIN, has_categorical=False,
         max_group_bins=MAX_BIN, is_bundled=False, has_missing=False)
 
 
@@ -131,26 +131,6 @@ def test_wide_hist_kernel_compiles(one_chip, features, mode, slots):
     text = _compiled_text(hist_active_pallas.lower(
         *_hist_args(s, N, mode, slots, features), num_features=features,
         max_bins=MAX_BIN, mode=mode))
-    assert "tpu_custom_call" in text
-
-
-@pytest.mark.parametrize("seeded", [False, True], ids=["plain", "seeded"])
-def test_compact_hist_kernel_compiles(one_chip, seeded):
-    """The default deep-wave kernel at the 1M x 28 x 63 shape, 128
-    slots in four 32-slot groups — refused before PR 21: its per-tile
-    group-active block was a (32, 1) window into a [32, n_groups + 1]
-    table."""
-    from lightgbm_tpu.ops.compact import (compact_raw_layout,
-                                          hist_active_compact)
-    s = _shapes(one_chip)
-    args = _hist_args(s, N, "int8h", 128)
-    kw = dict(num_features=F, max_bins=MAX_BIN, num_leaf_slots=LEAVES,
-              mode="int8h")
-    if seeded:
-        shape, dtype = compact_raw_layout(N, 128, F, MAX_BIN, "int8h")
-        args.append(s(shape, dtype))
-        kw["raw"] = True
-    text = _compiled_text(hist_active_compact.lower(*args, **kw))
     assert "tpu_custom_call" in text
 
 
@@ -179,6 +159,27 @@ def test_seeded_wide_fold_gate_agrees_with_compiler(one_chip, features,
     else:
         with pytest.raises(Exception, match="vmem"):
             lowered.compile()
+
+
+@pytest.mark.parametrize("slots", [64, 128])
+def test_seeded_wide_fold_compiles_at_deep_waves(one_chip, on_tpu, slots):
+    """What a deep wave of streamed training runs on the chip: the fold
+    `make_hist_fold_fn` builds for a block of rows at the benchmark
+    cells' width (transpose, pack, the seeded wide kernel on the grid
+    `hist_tiling` gives a seeded call: one jitted program), at the 256-
+    and the 512-column wave."""
+    from lightgbm_tpu.learner.serial import make_hist_fold_fn
+    s = _shapes(one_chip)
+    dd = _device_data(N, one_chip, one_chip, F_CRITEO)
+    fold = make_hist_fold_fn(dd, LEAVES, slots, N, hist_mode="int8h")
+    assert fold is not None and fold.quantized
+    acc = jax.eval_shape(fold.init_acc)
+    rows = lambda dtype: s((N,), dtype)                 # noqa: E731
+    text = _compiled_text(fold.fold.lower(
+        s((N, F_CRITEO), jnp.uint8), rows(jnp.float32), rows(jnp.float32),
+        rows(jnp.int32), s((slots,), jnp.int32), s(acc.shape, acc.dtype),
+        s((2,), jnp.float32)))
+    assert "tpu_custom_call" in text
 
 
 @pytest.mark.parametrize("mode", ["hilo", "int8h"])

@@ -75,12 +75,11 @@ PROGRAM_PAIRS: Tuple[Dict, ...] = (
     {"name": "hist-backend-selection",
      "env": "LGBM_TPU_HIST_BACKEND",
      "programs": ("scatter histogram", "wide fused Pallas kernel",
-                  "leaf-compacted Pallas kernel",
                   "their accumulator-seeded streamed-fold twins "
                   "(learner/serial.py make_hist_fold_fn; streamed=="
                   "resident per backend pinned by "
                   "tests/test_streaming.py)"),
-     "test": "tests/test_compact.py"},
+     "test": "tests/test_learner.py"},
     {"name": "hist-mode-precision",
      "env": "LGBM_TPU_HIST_MODE",
      "programs": ("f32 histogram accumulation",
@@ -90,16 +89,7 @@ PROGRAM_PAIRS: Tuple[Dict, ...] = (
      "env": "LGBM_TPU_DONATE",
      "programs": ("score/grad/hess buffers donated in place",
                   "undonated dispatches"),
-     "test": "tests/test_overlap.py"},
-    {"name": "overlapped-vs-serial-psum",
-     "env": "LGBM_TPU_OVERLAP",
-     "programs": ("chunked double-buffered wave psum",
-                  "single serial psum per wave"),
-     "test": "tests/test_overlap.py"},
-    {"name": "overlap-chunking",
-     "env": "LGBM_TPU_OVERLAP_CHUNKS",
-     "programs": ("N-chunk overlapped psum schedules (N >= 1)",),
-     "test": "tests/test_overlap.py"},
+     "test": "tests/test_mesh_block.py"},
     {"name": "phases-driver-vs-fused-build",
      "env": "LGBM_TPU_TIMETAG",
      "programs": ("unfused per-phase-timed wave driver",
@@ -129,7 +119,7 @@ PROGRAM_PAIRS: Tuple[Dict, ...] = (
      "programs": ("streamed block trainer (boosting/streaming.py: "
                   "out-of-core mmap blocks, carried-accumulator "
                   "histogram folds — row-order scatter AND the "
-                  "accumulator-seeded Pallas/compact kernel folds — "
+                  "accumulator-seeded Pallas kernel folds — "
                   "host-resident scores)",
                   "resident in-memory fused training loop"),
      "test": "tests/test_streaming.py"},
@@ -203,11 +193,9 @@ EXEMPT_ENV: Dict[str, str] = {
     "LGBM_TPU_BLOCK_CAP": "watchdog bound on iterations per dispatch; "
                           "block length is byte-identical by "
                           "construction (tests/test_mesh_block.py)",
-    "LGBM_TPU_COMPACT_SLOTS": "compact-backend wave threshold: backend "
-                              "selection parity is pinned by "
-                              "tests/test_compact.py",
-    "LGBM_TPU_ROW_TILE": "kernel tiling knob; oracle parity in "
-                         "tests/test_compact.py covers all tilings",
+    "LGBM_TPU_ROW_TILE": "kernel tiling knob (the upper bound of the "
+                         "row tile); oracle parity on the grids "
+                         "hist_tiling picks in tests/test_pallas_hist.py",
     "LGBM_TPU_SPLIT_VMEM_MB": "VMEM chunking budget; chunked==unchunked "
                               "bitwise in tests/test_split_cache.py",
     "LGBM_TPU_SPLIT_SCAN_MB": "VMEM chunking budget; chunked==unchunked "

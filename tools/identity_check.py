@@ -14,13 +14,13 @@ asserted within each shard-count group:
   ``mesh2_block0`` (the same mesh under the ``LGBM_TPU_MESH_BLOCK=0``
   per-iteration escape hatch), ``stream2`` (streamed 2-shard), and
   ``elastic1`` (the elastic protocol at world 1 pinned to ``S=2``);
-* ``S=1·pallas`` / ``S=1·compact`` — the ISSUE 20 streamed-kernel
-  groups: ``serial_<backend>`` (in-memory monolithic kernel) vs
-  ``stream1_<backend>`` (accumulator-seeded per-block kernel folds),
-  both force-run on CPU through the auto-interpret path.  These are
-  SEPARATE groups: the quantized kernel histograms legitimately
-  differ in value from the exact scatter backend, so the law is
-  identity within a forced backend, never across backends.
+* ``S=1·pallas`` — the ISSUE 20 streamed-kernel group:
+  ``serial_pallas`` (in-memory monolithic kernel) vs
+  ``stream1_pallas`` (accumulator-seeded per-block kernel folds),
+  both force-run on CPU through the auto-interpret path.  A SEPARATE
+  group: the quantized kernel histograms legitimately differ in
+  value from the exact scatter backend, so the law is identity
+  within a forced backend, never across backends.
 
 (Serial and 2-shard models legitimately differ: per-shard partials
 combine through the psum seam in a different — but partition-pinned —
@@ -82,8 +82,6 @@ MATRIX: Dict[str, str] = {
     "elastic1": "S=2",
     "serial_pallas": "S=1·pallas",
     "stream1_pallas": "S=1·pallas",
-    "serial_compact": "S=1·compact",
-    "stream1_compact": "S=1·compact",
 }
 
 BASE_PARAMS = {"objective": "binary", "num_leaves": 7,
@@ -122,18 +120,12 @@ def run_once(scenario: str, rows: int, rounds: int) -> Dict:
     num_contract.reset()
     X, y = _toy_data(rows)
     params = {**BASE_PARAMS, "num_iterations": rounds}
-    # ISSUE 20 streamed-kernel scenarios: "<base>_<backend>" forces the
-    # histogram backend on BOTH sides of the pair (env save/restored);
-    # compact additionally drops its slot threshold and deepens the
-    # tree so the tail wave actually selects the compact kernel
+    # ISSUE 20 streamed-kernel scenarios: "<base>_pallas" forces the
+    # histogram backend on BOTH sides of the pair (env save/restored)
     base, fenv = scenario, {}
-    for suf in ("_pallas", "_compact"):
-        if scenario.endswith(suf):
-            base, bk = scenario[:-len(suf)], suf[1:]
-            fenv = {"LGBM_TPU_HIST_BACKEND": bk}
-            if bk == "compact":
-                fenv["LGBM_TPU_COMPACT_SLOTS"] = "4"
-                params["num_leaves"] = 15
+    if scenario.endswith("_pallas"):
+        base = scenario[:-len("_pallas")]
+        fenv = {"LGBM_TPU_HIST_BACKEND": "pallas"}
     saved = {k: os.environ.get(k) for k in fenv}
     os.environ.update(fenv)
     try:

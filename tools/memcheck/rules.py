@@ -63,8 +63,8 @@ RULE_TITLES = {
 # the analyzed root (fixture temp dirs); kept in sync by
 # tests/test_memcheck.py::test_guard_registry_matches_ops_vmem
 DEFAULT_VMEM_GUARDS = (
-    "pallas_config_ok", "fused_config_ok", "compact_config_ok",
-    "hist_cell_ok", "hist_fold_cell_ok", "split_lane_chunk_features",
+    "pallas_config_ok", "fused_config_ok", "hist_cell_ok",
+    "hist_fold_cell_ok", "split_lane_chunk_features",
     "split_scan_chunk_features",
 )
 
@@ -489,7 +489,7 @@ def rule_mem004(fi: FileInfo, ctx: MemContext) -> List[Finding]:
         "infeasible config surfaces as a Mosaic compile crash (or "
         "silent VMEM thrash) instead of a fallback; key the config "
         "gate on lightgbm_tpu/ops/vmem.py (VMEM_GUARDS) like "
-        "pallas_config_ok/compact_config_ok do") for c in calls]
+        "pallas_config_ok/fused_config_ok do") for c in calls]
 
 
 # -- MEM005 ---------------------------------------------------------------

@@ -90,8 +90,8 @@ CONTEXTS = (
             "block's kernel call seeds its output from the carry via "
             "input_output_aliases, replaying the monolithic kernel's "
             "adds in the monolithic order — exact int32 on quantized "
-            "modes, identical per-tile f32 add sequence on the wide "
-            "float modes (float compact degrades to wide); pinned "
+            "modes, identical per-tile f32 add sequence on the "
+            "float modes; pinned "
             "streamed==resident per backend by tests/test_streaming.py"},
     {"function": "_fold_scales",
      "module": "lightgbm_tpu/boosting/streaming.py",
