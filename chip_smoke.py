@@ -167,13 +167,15 @@ def check_quiet_path(summary: dict) -> None:
 
 def say_tiling(summary) -> None:
     """The grids the traced histogram calls took, from the program's
-    gauges: ``hist.tiling.<cols>`` = ``<feat_tile>x<row tile>`` and the
-    largest share of padded features, ``hist.feature_pad_pct``."""
+    gauges: ``hist.tiling.<cols>`` = ``<feat_tile>x<row tile>``, the
+    largest share of padded features, ``hist.feature_pad_pct``, and the
+    waves' slot counts, ``hist.wave_slots`` = ``<staged waves>|<tail>``."""
     tiling = {k: v for k, v in sorted(summary["gauges"].items())
               if k.startswith("hist.")}
     say(f"histogram grids: {tiling}")
     check(any(k.startswith("hist.tiling.") for k in tiling),
           "no hist.tiling.<cols> gauge: no histogram kernel was traced")
+    check("hist.wave_slots" in tiling, "no hist.wave_slots gauge")
 
 
 # ---------------------------------------------------------------------------

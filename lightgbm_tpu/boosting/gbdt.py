@@ -613,8 +613,10 @@ class GBDT:
         the kernels take it from (``ops/vmem.hist_tiling``) at the
         shapes they will see (``rows``: a shard's under a row-sharded
         learner): gauges ``hist.tiling.<cols>`` =
-        ``"<feat_tile>x<row tile>"`` and ``hist.feature_pad_pct``, the
-        largest share of all-zero features a wave contracts."""
+        ``"<feat_tile>x<row tile>"``, ``hist.feature_pad_pct``, the
+        largest share of all-zero features a wave contracts, and
+        ``hist.wave_slots``, the staged waves' slot counts and the
+        tail's (``"8,8,8,8,8,16,32,64|128"`` at 255 leaves)."""
         from ..learner.serial import stage_plan
         from ..obs import gauge_set
         from ..ops.pallas_histogram import DEFAULT_ROW_TILE
@@ -625,6 +627,8 @@ class GBDT:
         n_pad = round_up(int(rows), DEFAULT_ROW_TILE)
         plan, A_tail = stage_plan(self.growth.num_leaves,
                                   self.growth.wave_size)
+        gauge_set("hist.wave_slots",
+                  f"{','.join(map(str, plan))}|{A_tail}")
         F_widest = F
         for A in sorted({*plan, A_tail}):
             C, _, cols = col_layout(A, hist_mode)

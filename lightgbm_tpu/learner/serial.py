@@ -258,26 +258,50 @@ def root_stats_q(code_sums, scales, mode: str):
 
 
 def stage_plan(L: int, wave_size: int = 0):
-    """Active-slot counts for the unrolled waves + the while-loop tail.
+    """Slot counts of the unrolled waves and of the while-loop tail:
+    ``-> (plan, A_tail)``.
 
-    Wave ``w`` can split at most ``min(leaves_w, slots)`` leaves, so slot
-    counts track the doubling leaf count; the tail loop finishes whatever
-    the unrolled waves didn't (uneven gain distributions).  The tail runs
-    at full width so a balanced tree completes within the unrolled stages
-    (a narrow tail forced extra waves on the hot path).  Leaf-wise mode
-    (``wave_size=1``) splits one leaf per wave, so everything runs in a
-    narrow while loop instead.
+    A slot of wave ``i`` holds the smaller child of one split that wave
+    ``i - 1`` selected (the sibling is the parent less it), and the
+    histogram kernel multiplies a slot's columns whether it is live or
+    ``-1``.  So ``plan[i]`` is the most splits wave ``i - 1`` can
+    select, not the leaves that exist when wave ``i`` runs.  The root
+    wave histograms 1 leaf.  A wave that selects among ``nl`` leaves
+    selects ``k <= min(nl, L - nl)`` of them (:func:`_apply_wave`: only
+    leaves below ``nl`` carry a gain, and the budget is ``L - nl``), and
+    ``nl <= leaves_i``, the most leaves a tree can have when wave ``i``
+    selects (1, 2, 4, ...: every leaf split in every wave), whatever
+    the data.  Hence ``k <= min(leaves_i, L // 2)`` and
+
+        plan[i + 1] = min(round8(leaves_i), A_tail)
+
+    Why the cap ``k <= min(wave_cap, A_out)`` of ``_apply_wave`` (``A_out
+    = plan[i + 1]``) never newly binds: ``A_out >= leaves_i >= nl >= k``
+    wherever ``A_tail`` does not cut it; where it does, ``A_tail >= L //
+    2 >= k`` up to 256 leaves, and past them the plan sized by the
+    leaves that exist, ``plan[i] = min(round8(leaves_i), A_tail)``,
+    twice this one, was cut to the same ``A_tail``.  The same leaves are
+    split in the same order on any data, balanced or not; the columns
+    that are gone were ``-1`` padding by construction.
+
+    The tail finishes whatever the unrolled waves didn't (uneven gain
+    distributions).  There ``nl`` can be anything below ``L``, so a tail
+    wave can be handed up to ``L // 2`` smaller children: it runs at
+    full width, which also lets a balanced tree complete within the
+    unrolled waves (a narrow tail forced extra waves on the hot path).
+    Leaf-wise mode (``wave_size=1``) splits one leaf per wave, so
+    everything runs in a narrow while loop instead.
     """
     if wave_size == 1:
         return [], 8
-    A_full = min(_round8(max(1, L // 2)), 128)
+    A_tail = min(_round8(max(1, L // 2)), 128)
     plan = []
-    leaves = 1
+    leaves, handed = 1, 1       # the root wave histograms the one leaf
     while leaves < L and len(plan) < 32:
-        A = min(_round8(leaves), A_full)
-        plan.append(A)
-        leaves += min(A, leaves)
-    return plan, A_full
+        plan.append(min(_round8(handed), A_tail))
+        handed = leaves         # a smaller child for every leaf it splits
+        leaves += min(leaves, A_tail)
+    return plan, A_tail
 
 
 def _empty_best(L: int, B: int) -> SplitResult:

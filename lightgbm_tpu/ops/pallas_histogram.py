@@ -20,10 +20,11 @@ compares against a bin iota (:func:`_onehot_bins`) — no gathers, no
 cross-lane reshapes, and no intermediate beyond the bf16 one-hot.
 
 The column count adapts to the wave: ``cols = round128(C * round8(A))``,
-so MXU work scales with the number of active leaves — the first waves of
-a tree (1, 2, 4, ... active leaves) cost a fraction of a full wave.  The
-staged wave plan in ``learner/serial.py`` exploits this by growing the
-active-slot count as the tree grows.
+so MXU work scales with the number of active SLOTS, live or ``-1`` — the
+first waves of a tree (1, 1, 2, 4, ... smaller children) cost a fraction
+of a full wave.  The staged wave plan in ``learner/serial.py`` exploits
+this by sizing each wave's slots by the splits the wave before it can
+make.
 
 Memory layout notes:
 
@@ -523,7 +524,10 @@ def hist_active_pallas(bins_t: jnp.ndarray,
       ``raw=True``.
 
     MXU cost scales with ``round128(C*round8(A))`` — small waves are
-    proportionally cheap.
+    proportionally cheap.  ``A`` is the wave's capacity, not its live
+    count: a ``-1`` slot's columns are multiplied like any other, so the
+    caller sizes ``active`` by what it can be handed
+    (``learner/serial.py`` ``stage_plan``).
     """
     F_pad, n_pad = bins_t.shape
     C = vals.shape[0]

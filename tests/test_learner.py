@@ -130,23 +130,100 @@ def test_min_data_in_leaf_respected():
 def test_auto_on_a_tpu_is_the_wide_kernel_in_every_wave(monkeypatch, leaves):
     """On a TPU "auto" resolves to "pallas", and a wave of it has one
     histogram path whatever its slot count (PR 27 stopped compacting
-    the 64- and 128-slot waves, PR 31 deleted the compaction): the fused
-    route+histogram kernel where its gate admits it, the wide kernel
-    elsewhere."""
+    the two deepest waves, PR 31 deleted the compaction, PR 32 sized
+    them by the 32 and 64 smaller children they can be handed): the
+    fused route+histogram kernel where its gate admits it, the wide
+    kernel elsewhere."""
     monkeypatch.delenv("LGBM_TPU_HIST_BACKEND", raising=False)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert default_backend() == "pallas"
     dd = SimpleNamespace(group_max_bins=63)
     assert resolve_backend(dd, leaves, "auto", "int8h") == "pallas"
     plan, A_tail = stage_plan(leaves)
-    assert (plan[-2:], A_tail) == (([64, 128], 128) if leaves == 255
-                                   else ([8, 16], 16))
+    assert (plan[-2:], A_tail) == (([32, 64], 128) if leaves == 255
+                                   else ([8, 8], 16))
     for fused_ok, want in ((True, "fused"), (False, "pallas")):
         choices, tail = wave_backend_plan(leaves, backend="pallas",
                                           fused_ok=fused_ok)
         assert choices == [want] * len(plan) and tail == want
     # leaf-wise growth: every wave is the 8-slot tail
     assert wave_backend_plan(leaves, wave_size=1) == ([], "fused")
+
+
+@pytest.mark.parametrize(
+    "L", [2, 3, 15, 16, 31, 63, 64, 127, 255, 256, 1024])
+def test_stage_plan_sizes_a_wave_by_the_splits_before_it(L):
+    """A slot holds the smaller child of a split the wave before
+    selected, so wave ``i`` has ``min(round8(need_i), A_tail)`` slots,
+    ``need_i`` the most it can be handed, and no more (half the slots of
+    a plan sized by the leaves that exist can never be filled).  Walked
+    with the worst case, the leaves doubling up to ``L``: the cap ``k <=
+    A_out`` of ``_apply_wave`` binds only where the tail's 128 does."""
+    from lightgbm_tpu.ops.vmem import round_up
+
+    def round8(x):
+        return round_up(x, 8)
+
+    plan, A_tail = stage_plan(L)
+    assert A_tail == min(round8(L // 2), 128)
+    leaves, need = 1, 1                 # the root wave histograms 1 leaf
+    for i, A_in in enumerate(plan):
+        assert leaves < L               # no wave the worst case skips
+        assert A_in == min(round8(need), A_tail)
+        A_out = plan[i + 1] if i + 1 < len(plan) else A_tail
+        # wave i selects among nl <= leaves leaves (an unbalanced tree
+        # has fewer), k <= min(nl, L - nl) of them
+        most = max(min(nl, L - nl) for nl in range(1, leaves + 1))
+        assert A_out >= min(most, A_tail)
+        need = min(most, A_out)
+        leaves += min(leaves, L - leaves, A_out)
+    assert leaves == L                  # a balanced tree needs no tail
+    if L == 255:
+        assert plan == [8, 8, 8, 8, 8, 16, 32, 64]
+
+
+def _grown(monkeypatch, X, y, lean_rows, **split_kw):
+    """One tree of 63 leaves by the kernel path (interpret mode) at
+    ``int8h``, the staged waves on (``lean_rows`` 0) or the single
+    while-loop body at the tail's width (``None``: as shipped)."""
+    from lightgbm_tpu.learner import serial
+    if lean_rows is not None:
+        monkeypatch.setattr(serial, "_COMPILE_LEAN_ROWS", lean_rows)
+    dd = to_device(BinnedDataset.from_raw(
+        X, Config.from_params({"max_bin": 63})))
+    p = GrowthParams(num_leaves=63, split=SplitParams(
+        min_sum_hessian_in_leaf=0.0, **split_kw))
+    return build_tree(dd, jnp.asarray(-(y - y.mean()), jnp.float32),
+                      jnp.ones(len(y), jnp.float32), p,
+                      hist_backend="pallas", hist_mode="int8h")
+
+
+@pytest.mark.parametrize("min_data,tail_splits", [(1, False), (60, True)])
+def test_staged_waves_grow_the_single_bodys_tree(monkeypatch, min_data,
+                                                 tail_splits):
+    """The staged plan hands wave ``i`` the slots its smaller children
+    need and the single body hands every wave the tail's: the same
+    leaves are split in the same order, and at an int8 mode the sums are
+    exact integers whatever the grid, so every array of the tree is
+    equal -- where the unrolled waves finish the tree, and where leaves
+    too small to split leave the rest to the tail's waves."""
+    rng = np.random.RandomState(3)
+    X = rng.rand(3000, 5).astype(np.float32)
+    y = (np.sin(6 * X[:, 0]) + X[:, 1] * X[:, 2]
+         + 0.1 * rng.randn(3000)).astype(np.float32)
+    staged = _grown(monkeypatch, X, y, 0, min_data_in_leaf=min_data)
+    single = _grown(monkeypatch, X, y, None, min_data_in_leaf=min_data)
+    plan, _ = stage_plan(63)
+    nl = int(staged.num_leaves)
+    deepest = int(np.asarray(staged.leaf_depth)[:nl].max())
+    # a leaf deeper than the unrolled waves is one of the tail's
+    assert (deepest > len(plan)) == tail_splits and nl > 32
+    assert nl == 63 or tail_splits
+    for name in BuiltTree._fields:
+        a, b = getattr(staged, name), getattr(single, name)
+        for x, z in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(z),
+                                          err_msg=name)
 
 
 @pytest.mark.parametrize("name", ["auto", "pallas", "scatter", "compact"])

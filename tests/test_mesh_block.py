@@ -276,7 +276,7 @@ def test_one_reduction_a_wave_on_the_flight_recorder(monkeypatch):
     p = serial.GrowthParams(num_leaves=31, split=SplitParams(
         min_data_in_leaf=5, min_sum_hessian_in_leaf=0.0))
     plan, A_tail = serial.stage_plan(31)
-    assert plan == [8, 8, 8, 8, 16] and A_tail == 16
+    assert plan == [8, 8, 8, 8, 8] and A_tail == 16
     fr.reset()
     jax.eval_shape(
         lambda g, h: build_tree_distributed(

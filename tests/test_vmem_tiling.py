@@ -148,7 +148,8 @@ def test_booster_sets_the_tiling_gauges(monkeypatch):
             obs.disable()
         obs.reset()
     assert gauges["gbdt.hist_backend"] == "pallas"
-    want = {"hist.feature_pad_pct": 100.0 * (72 - 67) / 67}
+    want = {"hist.feature_pad_pct": 100.0 * (72 - 67) / 67,
+            "hist.wave_slots": "8,8,8,8,8,16,32,64|128"}
     for A in (8, 16, 32, 64, 128):
         C, _, cols = col_layout(A, "int8h")
         T, ft, _ = hist_tiling(67, 4096, 64, cols, C, ROW_TILE)
