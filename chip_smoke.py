@@ -45,6 +45,7 @@ AUC_AGREE = 0.005           # default backend vs the scatter reference
 LEAF_RATIO = 3.0            # largest |leaf value| vs the scatter reference's
 REF_ROWS, REF_ITERS = 131_072, 16
 CLI_ROWS, CLI_ITERS = 100_000, 16
+EVAL_VALID_ROWS, EVAL_ITERS = 50_000, 12
 
 
 class SmokeFailure(RuntimeError):
@@ -356,6 +357,59 @@ def phase_reference(lgb, obs, X, y, full_width_leaf: float):
     check_quiet_path(obs.summary())
 
 
+def phase_eval(lgb, obs, X, y, seed: int):
+    """The documented job's sampled and evaluated parts: bagging 0.8 / 5,
+    ``feature_fraction`` 0.8, a 50k held-out set, log-loss and AUC of
+    both sets after each of 12 iterations, the metrics worked out on
+    the device.  Held to the host functions on the fetched scores."""
+    import numpy as np
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.metric.metrics import BinaryLoglossMetric, binary_auc
+    Xv, yv = higgs_like(EVAL_VALID_ROWS, seed + 1)
+    ds = lgb.Dataset(X, label=y, params={"max_bin": PARAMS["max_bin"]})
+    dv = lgb.Dataset(Xv, label=yv, reference=ds)
+    params = {**PARAMS, "metric": "binary_logloss,auc", "bagging_freq": 5,
+              "bagging_fraction": 0.8, "feature_fraction": 0.8}
+    before = obs.summary()["counters"]
+    t0 = time.perf_counter()
+    bst = lgb.train(params, ds, num_boost_round=EVAL_ITERS,
+                    valid_sets=[ds, dv], valid_names=["training", "valid"],
+                    keep_training_booster=True)
+    g = bst._gbdt
+    got = {(n, m): v for n, m, v, _ in g.eval_train() + g.eval_valid()}
+    wall = time.perf_counter() - t0
+    summary = obs.summary()
+    evals = summary["counters"].get("gbdt.evals", 0) \
+        - before.get("gbdt.evals", 0)
+    host_rows = summary["counters"].get("gbdt.eval_host_rows", 0) \
+        - before.get("gbdt.eval_host_rows", 0)
+    say(f"eval: {EVAL_ITERS} iterations, bag 0.8 / 5, feature_fraction 0.8, "
+        f"{EVAL_VALID_ROWS} held-out rows, {evals} evaluations in "
+        f"{wall:.2f} s (compile included); gbdt.eval_backend: "
+        f"{summary['gauges'].get('gbdt.eval_backend')}, "
+        f"gbdt.eval_host_rows +{host_rows}")
+    check(summary["gauges"].get("gbdt.eval_backend") == "device",
+          "the metrics were not worked out on the device")
+    check(host_rows == 0, f"{host_rows} score rows fetched for a metric")
+    check(evals == 2 * EVAL_ITERS + 2, f"{evals} evaluations")
+    logloss = BinaryLoglossMetric(Config.from_params({}))
+    for name, scores, labels in (("training", g.scores, y),
+                                 ("valid", g._valid_scores[0], yv)):
+        s = np.asarray(scores)[:, 0]
+        auc = float(binary_auc(labels, s))
+        ll = logloss.eval(labels, s)[0][1]
+        say(f"eval, {name}: device auc {got[(name, 'auc')]!r} host {auc!r}; "
+            f"device binary_logloss {got[(name, 'binary_logloss')]!r} "
+            f"host {ll!r}")
+        check(got[(name, "auc")] == auc,
+              f"{name} AUC on the device {got[(name, 'auc')]!r} is not the "
+              f"host's {auc!r}")
+        check(abs(got[(name, "binary_logloss")] - ll) <= 1e-6 * ll,
+              f"{name} log-loss on the device is not the host's")
+    check(got[("valid", "auc")] > 0.8, "held-out AUC under 0.8")
+    check_quiet_path(summary)
+
+
 def phase_cli(lgb, X, y, tmp):
     """The config-file entry point: task=train then task=predict on a
     generated CSV — native parser, loader, binning, model file."""
@@ -417,6 +471,7 @@ def run_one_chip(jax, seed: int) -> None:
         phase_predict(lgb, bst, X, tmp)
         del bst
         phase_reference(lgb, obs, X, y, full_width_leaf)
+        phase_eval(lgb, obs, X, y, seed)
         phase_cli(lgb, X, y, tmp)
     n_after = cache_entries(cache_dir)
     say(f"compile cache: {n_after - n_before} entries gained "

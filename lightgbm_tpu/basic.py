@@ -318,6 +318,10 @@ class Booster:
         return out
 
     def _custom_eval(self, feval, name, dataset, scores):
+        # a feval takes host scores: the fetched rows are counted beside
+        # those of the metrics with no device form (GBDT._eval_set)
+        from .obs import counter_add
+        counter_add("gbdt.eval_host_rows", int(scores.shape[0]))
         s = scores if scores.shape[1] > 1 else scores[:, 0]
         res = feval(s, dataset)
         if isinstance(res, tuple):

@@ -223,6 +223,9 @@ class GBDT:
         self._valid_device: List[DeviceData] = []
         self._valid_scores: List[jnp.ndarray] = []
         self.metrics: List[Metric] = []
+        # a set's labels and weights as the device metrics take them,
+        # by "train" or the valid set's index (_device_eval_set)
+        self._eval_sets: Dict = {}
         self.feature_names: List[str] = []
         self.max_feature_idx = 0
         self._stacked_cache = None
@@ -1163,13 +1166,7 @@ class GBDT:
 
     # ------------------------------------------------------------------
     def eval_train(self) -> List[Tuple[str, str, float, bool]]:
-        if self._pr is not None:
-            # global scores span other processes' devices: evaluate this
-            # rank's own rows (the reference's machines likewise report
-            # their local shard's training metric)
-            return self._eval_set("training", self._pr.local_np(self.scores),
-                                  self._label, self._weight, self._query)
-        return self._eval_set("training", np.asarray(self.scores),
+        return self._eval_set("training", "train", self.scores,
                               self._label, self._weight, self._query)
 
     def eval_valid(self) -> List[Tuple[str, str, float, bool]]:
@@ -1177,20 +1174,98 @@ class GBDT:
         for i, vs in enumerate(self.valid_sets):
             md = vs.metadata
             out.extend(self._eval_set(
-                self.valid_names[i], np.asarray(self._valid_scores[i]),
+                self.valid_names[i], i, self._valid_scores[i],
                 md.label, md.weight, md.query_boundaries))
         return out
 
-    def _eval_set(self, name, scores, label, weight, query):
+    def _eval_set(self, name, key, scores, label, weight, query):
+        """Every metric of one set (``key``: ``"train"`` or the valid
+        set's index) from its device scores ``[n, K]``.  A metric with a
+        device form (``metric/device.py``) is worked out where the
+        scores live and only its sums cross to the host; for the others
+        the scores are fetched once (``gbdt.eval_host_rows`` counts the
+        rows) and handed to ``metric/metrics.py``."""
         results = []
-        if label is None:
+        if label is None or not self.metrics:
             return results
-        label = np.asarray(label)
-        s = scores if scores.shape[1] > 1 else scores[:, 0]
+        from ..obs import gauge_set
+        counter_add("gbdt.evals")
+        es = self._device_eval_set(key, label, weight, scores)
+        forms = self._device_forms()
+        on_device = (es.eval(scores, forms, self.config.sigmoid)
+                     if es is not None and forms else {})
+        s = None
         for m in self.metrics:
-            for mname, val, hib in m.eval(label, s, weight, query):
+            if m.device_form in on_device:
+                found = m.from_device(on_device[m.device_form])
+            else:
+                if s is None:
+                    # multi-process: global training scores span other
+                    # processes' devices, so a rank evaluates its own
+                    # rows (the reference's machines likewise report
+                    # their local shard's training metric)
+                    s = (self._pr.local_np(scores) if self._pr is not None
+                         and key == "train" else np.asarray(scores))
+                    counter_add("gbdt.eval_host_rows", int(s.shape[0]))
+                    s = s if s.shape[1] > 1 else s[:, 0]
+                    label = np.asarray(label)
+                found = m.eval(label, s, weight, query)
+            for mname, val, hib in found:
                 results.append((name, mname, val, hib))
+        gauge_set("gbdt.eval_backend", "device" if s is None else "host")
         return results
+
+    def _device_forms(self) -> Tuple[str, ...]:
+        return tuple(m.device_form for m in self.metrics
+                     if m.device_form is not None)
+
+    def _compile_evals(self) -> None:
+        """Start compiling the device metrics' programs of every set
+        that ``_train`` evaluates, each on a thread of its own, before
+        the first window: they compile beside the block program, and an
+        evaluation between windows compiles nothing (it waits for its
+        program where that is still on its way).  A thread is started
+        once a program: a later ``_train`` call (a user's loop calls it
+        every iteration) starts none.  Avals only: a live array would
+        pin the score buffer the first block donates."""
+        forms = self._device_forms()
+        sets = [(i, vs.metadata.label, vs.metadata.weight,
+                 self._valid_scores[i])
+                for i, vs in enumerate(self.valid_sets)]
+        if self.config.is_training_metric:
+            sets.append(("train", self._label, self._weight, self.scores))
+        for key, label, weight, scores in sets:
+            es = (self._device_eval_set(key, label, weight, scores)
+                  if label is not None and forms else None)
+            if es is not None and es.first_request(scores, forms,
+                                                   self.config.sigmoid):
+                aval = jax.ShapeDtypeStruct(scores.shape, scores.dtype,
+                                            sharding=scores.sharding)
+                self._start_background(
+                    es.program, f"lgbm-tpu-eval-compile-{key}", aval, forms,
+                    self.config.sigmoid)
+
+    def _device_eval_set(self, key, label, weight, scores):
+        """The set's :class:`metric.device.EvalSet`, made at its first
+        evaluation; None where the device forms do not apply (several
+        scores a row, rows on other processes' devices, weights the
+        device's integers do not hold, no rows)."""
+        if self._pr is not None or scores.shape[1] != 1:
+            return None
+        held = self._eval_sets.get(key)
+        if held is None or held[0] is not label or held[1] is not weight:
+            # made anew where the set's labels or weights were replaced
+            from ..metric.device import EvalSet
+            # the binary objective's labels are the set's own, resident
+            # already; any other objective may have rewritten its copy
+            resident = (self.objective.label
+                        if key == "train" and self.mesh_ctx is None
+                        and getattr(self.objective, "name", "") == "binary"
+                        else None)
+            es = EvalSet(label, weight, resident)
+            held = (label, weight, es if es.usable else None)
+            self._eval_sets[key] = held
+        return held[2]
 
     # -- fused multi-iteration training blocks --------------------------
     def _can_block(self) -> bool:
@@ -1281,6 +1356,19 @@ class GBDT:
                      if self.mesh_ctx is not None and self._pr is None
                      else None)
 
+        def valid_update(vscores, vds, bt, lv_s, k):
+            """Valid-set scoring per tree, on device: the path-agreement
+            matmul (MXU) for numerical valid sets, the node walk where
+            categorical splits need the bitset decision."""
+            with jax.named_scope("gbdt.valid_update"):
+                bts = bt._replace(leaf_value=lv_s)
+                return tuple(
+                    vs.at[:, k].add(
+                        predict_built_tree(bts, vd, vd.bins)
+                        if vd.has_categorical else
+                        predict_built_tree_matmul(bts, vd, vd.bins))
+                    for vs, vd in zip(vscores, vds))
+
         def block(dd, bins_t, vds, scores, vscores, lr, it0, n_active):
             def body(carry, it):
                 scores, vscores = carry
@@ -1300,7 +1388,8 @@ class GBDT:
                 # same functions the per-iteration path uses, so bagged
                 # (and GOSS: _block_sample override) configs stay on
                 # the fused fast path
-                G, H, bag = self._block_sample(G, H, it)
+                with jax.named_scope("gbdt.bag_mask"):
+                    G, H, bag = self._block_sample(G, H, it)
                 # BYTE-identity fence (serial AND mesh since the
                 # out-of-core round): eagerly — and in the streamed
                 # trainer's standalone per-block programs — gradients
@@ -1315,9 +1404,10 @@ class GBDT:
                     bag = jax.lax.optimization_barrier(bag)
                 outs = []
                 for k in range(K):
-                    fmask = (_device_feature_mask(c.feature_fraction_seed,
-                                                  it * K + k, F, kf)
-                             if ff_on else None)
+                    with jax.named_scope("gbdt.feature_mask"):
+                        fmask = (_device_feature_mask(
+                            c.feature_fraction_seed, it * K + k, F, kf)
+                            if ff_on else None)
                     if mesh_build is not None:
                         bt = mesh_build(dd, G[:, k], H[:, k], bag, fmask)
                     else:
@@ -1349,14 +1439,6 @@ class GBDT:
                             else:
                                 scores = scores.at[:, k].add(
                                     lv_s[bt.row_leaf[:scores.shape[0]]])
-                            bts = bt._replace(leaf_value=lv_s)
-                            vscores = tuple(
-                                vs.at[:, k].add(
-                                    predict_built_tree(bts, vd, vd.bins)
-                                    if vd.has_categorical else
-                                    predict_built_tree_matmul(bts, vd,
-                                                              vd.bins))
-                                for vs, vd in zip(vscores, vds))
                     else:
                         # serial branch fenced like the mesh branch
                         # since the out-of-core round: the barrier
@@ -1379,18 +1461,7 @@ class GBDT:
                             else:
                                 scores = scores.at[:, k].add(
                                     lv_s[bt.row_leaf])
-                            # valid-set scoring per tree, on device: the
-                            # path-agreement matmul (MXU) for numerical
-                            # valid sets, the node walk where categorical
-                            # splits need the bitset decision
-                            bts = bt._replace(leaf_value=lv_s)
-                            vscores = tuple(
-                                vs.at[:, k].add(
-                                    predict_built_tree(bts, vd, vd.bins)
-                                    if vd.has_categorical else
-                                    predict_built_tree_matmul(bts, vd,
-                                                              vd.bins))
-                                for vs, vd in zip(vscores, vds))
+                    vscores = valid_update(vscores, vds, bt, lv_s, k)
                     outs.append(bt._replace(row_leaf=bt.row_leaf[:0],
                                             row_value=bt.row_value[:0]))
                 stacked = (outs[0] if K == 1 else
@@ -1471,13 +1542,15 @@ class GBDT:
                             f"failed; keeping the borrowed program "
                             f"({exc})")
 
+        self._start_background(work, f"lgbm-tpu-block-compile-{L}")
+
+    def _start_background(self, work, name: str, *args) -> None:
+        """Run ``work(*args)`` on a thread that ``join_background``
+        reaps.  NON-daemon: a daemon thread mid-XLA-compile at
+        interpreter shutdown races the runtime teardown and segfaults; a
+        normal thread just delays exit until the compile lands."""
         import threading
-        # NON-daemon: a daemon thread mid-XLA-compile at interpreter
-        # shutdown races the runtime teardown and segfaults; a normal
-        # thread just delays exit until the compile lands.  The handle
-        # is kept so join_background can reap it (bounded shutdown)
-        t = threading.Thread(target=work, daemon=False,
-                             name=f"lgbm-tpu-block-compile-{L}")
+        t = threading.Thread(target=work, args=args, daemon=False, name=name)
         self._bg_threads = [th for th in self._bg_threads
                             if th.is_alive()]
         self._bg_threads.append(t)
@@ -1800,6 +1873,8 @@ class GBDT:
         if eval_freq <= 0 and es_on:
             eval_freq = 1
         stopped_early = False
+        if want_eval:
+            self._compile_evals()
         # resumed: num_iterations is the dead run's TOTAL target and
         # self.iter sits mid-run — continue from there, keeping window
         # boundaries (eval/snapshot cadence) aligned with the original

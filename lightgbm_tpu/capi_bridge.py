@@ -430,7 +430,7 @@ def _eval_results(b, data_idx: int) -> List[tuple]:
     i = int(data_idx) - 1
     vs = g.valid_sets[i]
     md = vs.metadata
-    return g._eval_set(g.valid_names[i], np.asarray(g._valid_scores[i]),
+    return g._eval_set(g.valid_names[i], i, g._valid_scores[i],
                        md.label, md.weight, md.query_boundaries)
 
 

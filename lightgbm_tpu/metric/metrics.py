@@ -33,12 +33,19 @@ def _sigmoid(x):
 class Metric:
     names: Sequence[str] = ()
     higher_better = False
+    # the metric's name in ``metric/device.py`` where it can be worked
+    # out from the scores on the device; None: host only
+    device_form: Optional[str] = None
 
     def __init__(self, config: Config):
         self.config = config
 
     def eval(self, label, score, weight=None, query=None) -> List[EvalResult]:
         raise NotImplementedError
+
+    def from_device(self, value: Optional[float]) -> List[EvalResult]:
+        """The result of :meth:`eval` from the device form's value."""
+        return [(self.names[0], value, self.higher_better)]
 
 
 # --- regression metrics (regression_metric.hpp:16+) ------------------------
@@ -149,6 +156,7 @@ class TweedieMetric(Metric):
 # --- binary metrics (binary_metric.hpp:20+) --------------------------------
 class BinaryLoglossMetric(Metric):
     names = ("binary_logloss",)
+    device_form = "binary_logloss"
 
     def eval(self, label, score, weight=None, query=None):
         p = np.clip(_sigmoid(self.config.sigmoid * score), 1e-15, 1 - 1e-15)
@@ -158,6 +166,7 @@ class BinaryLoglossMetric(Metric):
 
 class BinaryErrorMetric(Metric):
     names = ("binary_error",)
+    device_form = "binary_error"
 
     def eval(self, label, score, weight=None, query=None):
         pred = (score > 0).astype(np.float64)
@@ -186,11 +195,7 @@ def binary_auc(label, score, weight=None):
     label = np.asarray(label)
     score = np.asarray(score)
     if len(label) == 0:
-        # degenerate input (e.g. an empty valid set or a zero-row rank
-        # shard): NaN, never a silent perfect score (ADVICE r4)
-        _warn_degenerate_auc("AUC over an empty set is undefined; "
-                             "returning NaN")
-        return float("nan")
+        return _auc_of_empty_set()
     order = np.argsort(score, kind="mergesort")
     s = score[order]
     y = label[order]
@@ -214,17 +219,35 @@ def binary_auc(label, score, weight=None):
     total_pos = wp.sum()
     total_neg = wn.sum()
     if total_pos == 0 or total_neg == 0:
-        # the reference warns and skips AUC when a class is absent
-        # (binary_metric.hpp Init); keep the conventional 1.0 but say so
-        _warn_degenerate_auc("AUC over a single-class set is degenerate; "
-                             "reporting 1.0")
-        return 1.0
+        return _auc_of_one_class()
     return float(area / (total_pos * total_neg))
+
+
+def _auc_of_empty_set() -> float:
+    # degenerate input (e.g. an empty valid set or a zero-row rank
+    # shard): NaN, never a silent perfect score (ADVICE r4)
+    _warn_degenerate_auc("AUC over an empty set is undefined; "
+                         "returning NaN")
+    return float("nan")
+
+
+def _auc_of_one_class() -> float:
+    # the reference warns and skips AUC when a class is absent
+    # (binary_metric.hpp Init); keep the conventional 1.0 but say so
+    _warn_degenerate_auc("AUC over a single-class set is degenerate; "
+                         "reporting 1.0")
+    return 1.0
 
 
 class AucMetric(Metric):
     names = ("auc",)
     higher_better = True
+    device_form = "auc"
+
+    def from_device(self, value):
+        # None: a class is absent, and the device was not asked
+        return [("auc", _auc_of_one_class() if value is None else value,
+                 True)]
 
     def eval(self, label, score, weight=None, query=None):
         return [("auc", binary_auc(label, score, weight), True)]
