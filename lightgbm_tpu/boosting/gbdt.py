@@ -636,7 +636,7 @@ class GBDT:
         for A in sorted({*plan, A_tail}):
             C, _, cols = col_layout(A, hist_mode)
             T, feat_tile, F_grid = hist_tiling(F, n_pad, B, cols, C,
-                                               DEFAULT_ROW_TILE)
+                                               hist_mode, DEFAULT_ROW_TILE)
             gauge_set(f"hist.tiling.{cols}", f"{feat_tile}x{T}")
             F_widest = max(F_widest, F_grid)
         gauge_set("hist.feature_pad_pct", 100.0 * (F_widest - F) / F)

@@ -17,7 +17,8 @@ For one row-tile of ``T`` rows we build, entirely in VMEM,
 and accumulate ``oh @ vw -> [F*B, cols]`` into a VMEM accumulator over the
 row grid.  The one-hot itself is produced by per-feature broadcast
 compares against a bin iota (:func:`_onehot_bins`) — no gathers, no
-cross-lane reshapes, and no intermediate beyond the bf16 one-hot.
+cross-lane reshapes, and no intermediate beyond the one-hot (bf16, or
+int8 on a quantized mode).
 
 The column count adapts to the wave: ``cols = round128(C * round8(A))``,
 so MXU work scales with the number of active SLOTS, live or ``-1`` — the
@@ -103,11 +104,8 @@ def split_hi_lo(x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
 
 # layout arithmetic shared with the VMEM model (ops/vmem.py owns it so
 # the feasibility predicates and the kernels can never disagree on it)
-from .vmem import bin_stride, col_layout as _col_layout  # noqa: E402
-
-
-def is_quantized(mode: str) -> bool:
-    return mode in ("int8", "int8h", "int8hh")
+from .vmem import (bin_stride, col_layout as _col_layout,  # noqa: E402
+                   is_quantized)
 
 
 def pallas_config_ok(max_bins: int, num_leaves: int, mode: str) -> bool:
@@ -536,13 +534,13 @@ def hist_active_pallas(bins_t: jnp.ndarray,
 
     _, A_pad, cols = _col_layout(A, mode)
     seeded = acc is not None
-    # the grid: bounded by the per-grid-cell VMEM footprint (f32
-    # accumulator + the bf16 one-hot + the bins tile — ADVICE r2: the
-    # accumulator alone under-counts by the one-hot's tens of MB on wide
-    # low-bin datasets; a seeded call also counts the carried
-    # accumulator it streams in)
-    T, feat_tile, F_grid = hist_tiling(F_pad, n_pad, B, cols, C, row_tile,
-                                       seeded)
+    # the grid: bounded by the per-grid-cell VMEM footprint (the
+    # accumulator + the one-hot at the mode's operand size + the bins
+    # tile — ADVICE r2: the accumulator alone under-counts by the
+    # one-hot's tens of MB on wide low-bin datasets; a seeded call also
+    # counts the carried accumulator it streams in)
+    T, feat_tile, F_grid = hist_tiling(F_pad, n_pad, B, cols, C, mode,
+                                       row_tile, seeded)
     assert n_pad % T == 0, (n_pad, T)
     pad_cols = cols - C * A_pad
     bins_t = pad_features(bins_t, F_grid)
@@ -617,8 +615,8 @@ def hist_raw_layout(n_pad: int, num_active: int, num_features: int,
     """
     B = bin_stride(max_bins)
     C, A_pad, cols = _col_layout(num_active, mode)
-    _, _, F_grid = hist_tiling(num_features, n_pad, B, cols, C, row_tile,
-                               seeded=True)
+    _, _, F_grid = hist_tiling(num_features, n_pad, B, cols, C, mode,
+                               row_tile, seeded=True)
     dtype = jnp.int32 if is_quantized(mode) else jnp.float32
     return (F_grid * B, cols), dtype
 
@@ -824,7 +822,7 @@ def fused_config_ok(num_groups: int, max_bins: int, num_leaves: int,
     C, _, cols = _col_layout(min(max(1, num_leaves // 2), 128), mode)
     # feasibility at the 1024-row fallback tile (the kernel halves its
     # row tile per-config until the whole feature set fits)
-    return num_groups <= _feat_tile_cap(B, cols, 1024, C)
+    return num_groups <= _feat_tile_cap(B, cols, 1024, C, mode)
 
 
 @functools.partial(
@@ -855,7 +853,8 @@ def hist_route_pallas(bins_t, vals, leaf2, active,
     _, A_pad, cols = _col_layout(A, mode)
     # the fused kernel holds ALL stored columns in one tile: the largest
     # row tile at which that cell fits the VMEM budget
-    T, _, _ = hist_tiling(F_pad, n_pad, B, cols, C, row_tile, whole=True)
+    T, _, _ = hist_tiling(F_pad, n_pad, B, cols, C, mode, row_tile,
+                          whole=True)
     assert n_pad % T == 0 and leaf2.shape == (2, n_pad)
     pad_cols = cols - C * A_pad
     L = feature.shape[0]

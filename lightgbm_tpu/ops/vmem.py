@@ -21,7 +21,11 @@ module is the single home for that arithmetic, pure int math with
 
 The grid of a histogram kernel call (row tile, feature tile, padded
 feature count) is chosen here too: :func:`hist_tiling`, the least
-modelled time over the cells this model admits.
+modelled time over the cells this model admits.  A cell's footprint
+(:func:`cell_vmem_bytes`) counts each resident at the element size the
+call's mode gives it: the one-hot and the weighted values are int8 on a
+quantized mode and bf16 on every other (:func:`operand_bytes`), so every
+function that sizes a cell takes the mode.
 
 Budget provenance: 12 MiB per grid cell.  The previous spread-matmul
 kernel demonstrably ran larger footprints on the v5e, so 12 MiB under
@@ -37,7 +41,8 @@ import os
 LANE = 128
 
 # per-grid-cell VMEM budget for the histogram-family kernels' resident
-# arrays (f32 accumulator + bf16 one-hot + bins tile + value columns)
+# arrays (4-byte accumulator + the one-hot and the weighted values at the
+# mode's operand size + bins tile + value columns: cell_vmem_bytes)
 VMEM_BUDGET_BYTES = 12 * 1024 * 1024
 
 # The time model a histogram call's grid is chosen by (hist_tiling): a
@@ -91,29 +96,45 @@ def col_layout(A: int, mode: str) -> tuple[int, int, int]:
     return C, A_pad, cols
 
 
+def is_quantized(mode: str) -> bool:
+    """The int8 modes: int8 operands on the MXU, exact int32 sums."""
+    return mode in ("int8", "int8h", "int8hh")
+
+
+def operand_bytes(mode: str) -> int:
+    """Element size of the two operands a histogram kernel builds in
+    VMEM and contracts, the one-hot and the weighted values: int8 on a
+    quantized mode, bf16 on every other (``_hist_kernel``'s ``cdt``)."""
+    return 1 if is_quantized(mode) else 2
+
+
 def cell_vmem_bytes(ft: int, B: int, cols: int, T: int, C: int,
-                    seeded: bool = False) -> int:
+                    mode: str, seeded: bool = False) -> int:
     """VMEM footprint of one (feature-tile, row-tile) histogram grid
-    cell: the f32 accumulator, the bf16 one-hot, the weighted value
-    block, the bins tile (double-buffered), and the packed values.
+    cell, each resident at the element size ``mode`` gives it: the
+    4-byte accumulator (f32, int32 on a quantized mode), the one-hot
+    and the weighted value block (:func:`operand_bytes`), the bins tile
+    (double-buffered), and the packed values.
     ``seeded``: the streamed-fold variant also streams the carried
     accumulator IN, double-buffered like every blocked operand — two
     more accumulator-sized blocks (the v5e compiler refused the
     28 x 63-bin x 128-slot seeded cell at 16.76 MB of scoped VMEM
     before this was counted)."""
     acc = ft * B * cols * 4          # accumulator (out block)
+    e = operand_bytes(mode)
     return (acc + (2 * acc if seeded else 0)
-            + ft * B * T * 2         # one-hot bf16
-            + T * cols * 2           # vw bf16
+            + ft * B * T * e         # one-hot
+            + T * cols * e           # vw
             + 2 * ft * T             # bins tile, double-buffered
             + 2 * T * C * 4)         # vals, double-buffered
 
 
-def feat_tile_cap(B: int, cols: int, T: int, C: int,
+def feat_tile_cap(B: int, cols: int, T: int, C: int, mode: str,
                   seeded: bool = False) -> int:
     """Largest feature tile whose grid cell fits the VMEM budget."""
-    ft = max(1, VMEM_BUDGET_BYTES // (B * (cols * 4 + T * 2)))
-    while ft > 1 and cell_vmem_bytes(ft, B, cols, T, C,
+    ft = max(1, VMEM_BUDGET_BYTES
+             // (B * (cols * 4 + T * operand_bytes(mode))))
+    while ft > 1 and cell_vmem_bytes(ft, B, cols, T, C, mode,
                                      seeded) > VMEM_BUDGET_BYTES:
         ft -= 1
     return ft
@@ -137,7 +158,7 @@ def row_tiles(n_pad: int, requested: int) -> list[int]:
 
 
 def hist_tiling(F_pad: int, n_pad: int, B: int, cols: int, C: int,
-                requested: int, seeded: bool = False,
+                mode: str, requested: int, seeded: bool = False,
                 whole: bool = False) -> tuple[int, int, int]:
     """``-> (T, feat_tile, F_grid)``: the grid of one histogram kernel
     call, chosen for the least modelled time (:func:`hist_call_fs`)
@@ -160,14 +181,14 @@ def hist_tiling(F_pad: int, n_pad: int, B: int, cols: int, C: int,
     tiles = row_tiles(n_pad, requested)
     grids = []
     for T in tiles:
-        if cell_vmem_bytes(F_pad, B, cols, T, C, seeded) \
+        if cell_vmem_bytes(F_pad, B, cols, T, C, mode, seeded) \
                 <= VMEM_BUDGET_BYTES:
             feat_tiles = [F_pad]
         elif whole:
             feat_tiles = []
         else:
-            feat_tiles = range(8, feat_tile_cap(B, cols, T, C, seeded) + 1,
-                               8)
+            feat_tiles = range(
+                8, feat_tile_cap(B, cols, T, C, mode, seeded) + 1, 8)
         grids += [(T, ft, round_up(F_pad, ft)) for ft in feat_tiles]
     if not grids:
         ft = F_pad if whole else 8
@@ -184,7 +205,8 @@ def hist_cell_ok(max_bins: int, active_slots: int, mode: str,
     down to)?"""
     B = bin_stride(max_bins)
     C, _, cols = col_layout(active_slots, mode)
-    return cell_vmem_bytes(8, B, cols, row_tile, C) <= VMEM_BUDGET_BYTES
+    return (cell_vmem_bytes(8, B, cols, row_tile, C, mode)
+            <= VMEM_BUDGET_BYTES)
 
 
 def hist_fold_cell_ok(max_bins: int, active_slots: int, mode: str,
@@ -197,7 +219,7 @@ def hist_fold_cell_ok(max_bins: int, active_slots: int, mode: str,
     seed-load."""
     B = bin_stride(max_bins)
     C, _, cols = col_layout(active_slots, mode)
-    return (cell_vmem_bytes(8, B, cols, row_tile, C, seeded=True)
+    return (cell_vmem_bytes(8, B, cols, row_tile, C, mode, seeded=True)
             <= VMEM_BUDGET_BYTES)
 
 

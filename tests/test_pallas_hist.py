@@ -111,9 +111,11 @@ def test_criteo_width_runs_the_cells_tiles(mode, A):
     bins_j = jnp.asarray(bins)
     bt = transpose_bins(bins_j)
     C, _, cols = col_layout(A, mode)
-    grid = hist_tiling(F, bt.shape[1], 64, cols, C, 2048)
-    assert grid[1:] == hist_tiling(F, 13_281_280, 64, cols, C, 2048)[1:]
+    grid = hist_tiling(F, bt.shape[1], 64, cols, C, mode, 2048)
+    assert grid == hist_tiling(F, 13_281_280, 64, cols, C, mode, 2048)
     assert grid[2] <= 72
+    if mode == "int8h":     # the whole set in one tile: nothing padded
+        assert grid == ((2048, 67, 67) if A == 8 else (1024, 67, 67))
 
     def scatter(g, h):
         return np.asarray(hist_active_scatter(
@@ -141,6 +143,104 @@ def test_criteo_width_runs_the_cells_tiles(mode, A):
         scale = np.abs(s[..., :2]).max() + 1e-9
         np.testing.assert_allclose(p[..., :2] / scale, s[..., :2] / scale,
                                    atol=5e-4)
+
+
+@pytest.mark.parametrize("A,old_grid,new_grid", [
+    (8, (1024, 67, 67), (2048, 67, 67)),    # the 128-column calls
+    (32, (1024, 67, 67), (2048, 67, 67)),
+    (64, (2048, 24, 72), (1024, 67, 67)),   # the 256-column call
+])
+def test_int8h_sums_do_not_depend_on_the_grid(monkeypatch, A, old_grid,
+                                              new_grid):
+    """The raw int32 accumulator of an int8h call at the cells' width,
+    on the grid the cells ran through PR 33 and on the one they run
+    since PR 34 (the one-hot counted at its own byte): the same integers
+    are summed, so every cell is equal bit for bit.  Where the whole
+    feature set fits at both row tiles the old grid is asked for
+    through ``row_tile``; the feature-tiled one is handed to the
+    untraced function in the rule's place."""
+    from lightgbm_tpu.ops import pallas_histogram as ph
+    from lightgbm_tpu.ops.vmem import col_layout, hist_tiling
+    rng = np.random.RandomState(34)
+    n, F, L, max_bins = 4000, 67, 255, 63
+    bt = transpose_bins(
+        jnp.asarray(rng.randint(0, max_bins, size=(n, F)), jnp.uint8))
+    vals, _ = pack_values_q(
+        jnp.asarray(rng.normal(size=n).astype(np.float32)),
+        jnp.asarray(rng.uniform(0.1, 1.0, size=n).astype(np.float32)),
+        "int8h")
+    row_leaf = jnp.asarray(rng.randint(-1, L, size=n).astype(np.int32))
+    active = jnp.asarray(rng.choice(L, A, replace=False).astype(np.int32))
+    C, _, cols = col_layout(A, "int8h")
+    assert hist_tiling(F, bt.shape[1], 64, cols, C, "int8h",
+                       2048) == new_grid
+    kw = dict(num_features=F, max_bins=max_bins, mode="int8h",
+              interpret=True, raw=True)
+    new = np.asarray(hist_active_pallas(bt, vals, row_leaf, active, **kw))
+    assert new.dtype == np.int32 and new.shape == (F * 64, cols)
+    assert new.any()
+    if old_grid[1] == F:
+        assert hist_tiling(F, bt.shape[1], 64, cols, C, "int8h",
+                           1024) == old_grid
+        old = hist_active_pallas(bt, vals, row_leaf, active, row_tile=1024,
+                                 **kw)
+    else:
+        # untraced, so no cached program ever holds the forced grid
+        monkeypatch.setattr(ph, "hist_tiling", lambda *a, **k: old_grid)
+        old = hist_active_pallas.__wrapped__(bt, vals, row_leaf, active,
+                                             **kw)
+        assert old.shape == (old_grid[2] * 64, cols)
+    np.testing.assert_array_equal(np.asarray(old)[:F * 64], new)
+
+
+def test_tiling_gauges_are_the_kernels_grids(monkeypatch):
+    """`GBDT._record_tiling`'s gauges against what `hist_tiling` returns
+    for the arguments the kernels themselves hand it while a tree of the
+    cells' plan is traced (staged waves, then the tail: a data set this
+    small would otherwise trace the tail alone).  Traced, not compiled:
+    the grid is chosen while the call is traced."""
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu import obs
+    from lightgbm_tpu.learner import serial
+    from lightgbm_tpu.ops import pallas_histogram as ph
+    monkeypatch.setenv("LGBM_TPU_HIST_BACKEND", "pallas")
+    monkeypatch.setattr(serial, "_COMPILE_LEAN_ROWS", 0)
+    seen = {}
+
+    def recording(F_pad, n_pad, B, cols, C, mode, *rest, **kw):
+        grid = rule(F_pad, n_pad, B, cols, C, mode, *rest, **kw)
+        seen[cols] = f"{grid[1]}x{grid[0]}"
+        assert mode == "int8h" and (F_pad, B) == (67, 64)
+        return grid
+
+    rule = ph.hist_tiling
+    monkeypatch.setattr(ph, "hist_tiling", recording)
+    rng = np.random.RandomState(5)
+    X = rng.normal(size=(5000, 67)).astype(np.float32)
+    y = (X[:, 0] > 0).astype(np.float32)
+    was_on = obs.enabled()
+    obs.reset()
+    obs.enable()
+    try:
+        g = lgb.Booster({"objective": "binary", "num_leaves": 255,
+                         "max_bin": 63, "hist_mode": "int8h",
+                         "verbose": -1},
+                        lgb.Dataset(X, label=y,
+                                    params={"max_bin": 63}))._gbdt
+        gauges = obs.summary()["gauges"]
+    finally:
+        if not was_on:
+            obs.disable()
+        obs.reset()
+    rows = jax.ShapeDtypeStruct((5000,), jnp.float32)
+    jax.eval_shape(
+        lambda grad, hess: serial.build_tree(
+            g.device_data, grad, hess, g.growth, hist_mode="int8h"),
+        rows, rows)
+    tiling = {int(k.rsplit(".", 1)[1]): v for k, v in gauges.items()
+              if k.startswith("hist.tiling.")}
+    assert tiling == {128: "67x2048", 256: "67x1024", 512: "24x2048"}
+    assert seen == tiling
 
 
 @pytest.mark.parametrize("mode,max_bins,F,A", [

@@ -39,7 +39,8 @@ from lightgbm_tpu.ops.vmem import bin_stride, hist_fold_cell_ok
 N, F, MAX_BIN, LEAVES = 1 << 20, 28, 63, 255
 F_CRITEO = 67               # the benchmark cells' width
 N_TREE = 131_072            # whole-tree programs: same widths, fewer rows
-VALUE_ROWS = {"int8h": 4, "hilo": 5}
+N_CRITEO = 13_281_280       # a cell's shard, padded to the row tile
+VALUE_ROWS = {"int8": 3, "int8h": 4, "int8hh": 5, "hilo": 5}
 
 
 @pytest.fixture(scope="module")
@@ -88,14 +89,14 @@ def _hist_args(s, n, mode, slots, features=F):
             s((2,), jnp.float32)]
 
 
-def _split_tables(s):
+def _split_tables(s, features=F):
     """The per-leaf split tables + per-feature metadata the route
     kernels take, in their positional order."""
     i32, b = jnp.int32, jnp.bool_
     leaf = (LEAVES,)
     return [s(leaf, i32), s(leaf, i32), s(leaf, b), s(leaf, b),
             s((LEAVES, bin_stride(MAX_BIN)), b), s(leaf, b),
-            s(leaf, i32)] + [s((F,), i32) for _ in range(6)]
+            s(leaf, i32)] + [s((features,), i32) for _ in range(6)]
 
 
 def _device_data(n, bins_sharding, meta_sharding, features=F):
@@ -134,11 +135,37 @@ def test_wide_hist_kernel_compiles(one_chip, features, mode, slots):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("slots,grid", [
+    (32, (2048, 67, 67)),   # a 128-column call: 11.58 MB by the model
+    (64, (1024, 67, 67)),   # the 256-column call: 9.2 MB
+    (128, (2048, 24, 72)),  # the tail's: as before PR 34
+])
+def test_wide_hist_kernel_compiles_at_the_cells_shape(one_chip, slots,
+                                                      grid):
+    """The benchmark cells' calls as they are made: `[67, 13,281,280]`,
+    int8h, 63 bins, on the grids the model admits once it counts the
+    int8 one-hot at one byte: the whole feature set in one tile at
+    2,048 rows a cell (128 columns) and at 1,024 (256 columns).  The
+    chip's compiler takes them under its default scoped-VMEM limit."""
+    from lightgbm_tpu.ops.pallas_histogram import hist_active_pallas
+    from lightgbm_tpu.ops.vmem import col_layout, hist_tiling
+    C, _, cols = col_layout(slots, "int8h")
+    assert hist_tiling(F_CRITEO, N_CRITEO, bin_stride(MAX_BIN), cols, C,
+                       "int8h", 2048) == grid
+    s = _shapes(one_chip)
+    text = _compiled_text(hist_active_pallas.lower(
+        *_hist_args(s, N_CRITEO, "int8h", slots, F_CRITEO),
+        num_features=F_CRITEO, max_bins=MAX_BIN, mode="int8h"))
+    assert "tpu_custom_call" in text
+
+
 @pytest.mark.parametrize("max_bin,slots,mode", [
     (63, 128, "int8h"),     # 16.76 MB of scoped VMEM before PR 21
     (63, 128, "hilo"),
     (255, 64, "int8h"),
     (255, 128, "int8h"),    # the gate refuses: so does the compiler
+    (255, 128, "int8"),     # admitted since the one-hot counts one byte
+    (255, 64, "int8hh"),    # (PR 34): 11.97 / 11.98 MB by the model
 ])
 @pytest.mark.parametrize("features", [F, F_CRITEO])
 def test_seeded_wide_fold_gate_agrees_with_compiler(one_chip, features,
@@ -182,15 +209,23 @@ def test_seeded_wide_fold_compiles_at_deep_waves(one_chip, on_tpu, slots):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("mode", ["hilo", "int8h"])
-def test_fused_hist_route_kernel_compiles(one_chip, mode):
-    from lightgbm_tpu.ops.pallas_histogram import hist_route_pallas
+@pytest.mark.parametrize("features,mode,slots", [
+    (F, "hilo", 32),
+    (F, "int8h", 32),
+    (60, "int8h", 128),     # the widest set the gate admits at 63 bins
+    (60, "int8h", 8),       # since PR 34 (43 before), at 1,024 / 2,048 rows
+])
+def test_fused_hist_route_kernel_compiles(one_chip, features, mode, slots):
+    from lightgbm_tpu.ops.pallas_histogram import (fused_config_ok,
+                                                   hist_route_pallas)
+    assert fused_config_ok(features, MAX_BIN, LEAVES, mode)
     s = _shapes(one_chip)
-    bins_t, vals, _, active, scales = _hist_args(s, N, mode, 32)
+    bins_t, vals, _, active, scales = _hist_args(s, N, mode, slots,
+                                                 features)
     text = _compiled_text(hist_route_pallas.lower(
-        bins_t, vals, s((2, N), jnp.int32), active, *_split_tables(s),
-        scales, num_features=F, max_bins=MAX_BIN, mode=mode,
-        any_cat=False))
+        bins_t, vals, s((2, N), jnp.int32), active,
+        *_split_tables(s, features), scales, num_features=features,
+        max_bins=MAX_BIN, mode=mode, any_cat=False))
     assert "tpu_custom_call" in text
 
 

@@ -7,6 +7,11 @@ The rule before PR 29 (the largest feature tile that fits, at the row
 tile the minimum tile of 8 admits) stays here as the reference: the new
 rule may never contract more padded features than it did, nor take
 longer by the model it chooses by.
+
+Since PR 34 the footprint a grid is chosen under counts the one-hot and
+the weighted values at the size the call's mode builds them in (int8:
+one byte; the bf16 modes: two): `test_grid_by_element_size` pins the
+grids that moved and the ones that must not.
 """
 import jax
 import jax.numpy as jnp
@@ -23,16 +28,16 @@ ROW_TILE = 2048
 VALUE_ROWS = {"int8h": 4, "hilo": 5}
 
 
-def old_rule(F_pad, n_pad, B, cols, C, requested, seeded):
+def old_rule(F_pad, n_pad, B, cols, C, mode, requested, seeded):
     """``-> (T, feat_tile, F_grid)`` as `pick_row_tile` + `feat_tiling`
     chose them through PR 28."""
     T = requested
     while T > 1024 and (
             n_pad % T != 0
-            or cell_vmem_bytes(8, B, cols, T, C, seeded)
+            or cell_vmem_bytes(8, B, cols, T, C, mode, seeded)
             > VMEM_BUDGET_BYTES):
         T //= 2
-    cap = feat_tile_cap(B, cols, T, C, seeded)
+    cap = feat_tile_cap(B, cols, T, C, mode, seeded)
     ft = F_pad if cap >= F_pad else max(8, (cap // 8) * 8)
     return T, ft, round_up(F_pad, ft)
 
@@ -45,7 +50,8 @@ def old_rule(F_pad, n_pad, B, cols, C, requested, seeded):
 def test_chosen_grid(F_pad, max_bin, A, mode, seeded):
     B = bin_stride(max_bin)
     C, _, cols = col_layout(A, mode)
-    T, ft, F_grid = hist_tiling(F_pad, N_PAD, B, cols, C, ROW_TILE, seeded)
+    T, ft, F_grid = hist_tiling(F_pad, N_PAD, B, cols, C, mode, ROW_TILE,
+                                seeded)
 
     # a grid the kernel can run
     assert N_PAD % T == 0 and 1024 <= T <= ROW_TILE
@@ -53,11 +59,13 @@ def test_chosen_grid(F_pad, max_bin, A, mode, seeded):
     assert F_grid % ft == 0 and F_pad <= F_grid < F_pad + ft
     # it fits wherever the static gate admits the config, and only there
     gate = vmem.hist_fold_cell_ok if seeded else vmem.hist_cell_ok
-    fits = cell_vmem_bytes(ft, B, cols, T, C, seeded) <= VMEM_BUDGET_BYTES
+    fits = (cell_vmem_bytes(ft, B, cols, T, C, mode, seeded)
+            <= VMEM_BUDGET_BYTES)
     assert fits == gate(max_bin, A, mode)
 
     # never more padded work than the rule before, nor more modelled time
-    T0, ft0, F_grid0 = old_rule(F_pad, N_PAD, B, cols, C, ROW_TILE, seeded)
+    T0, ft0, F_grid0 = old_rule(F_pad, N_PAD, B, cols, C, mode, ROW_TILE,
+                                seeded)
     assert F_grid <= F_grid0
     if fits:
         assert (hist_call_fs(ft, F_grid, N_PAD, B, cols, T)
@@ -94,7 +102,7 @@ def test_chosen_grid(F_pad, max_bin, A, mode, seeded):
 ])
 def test_row_tiles(n_pad, requested, tiles):
     assert row_tiles(n_pad, requested) == tiles
-    T, _, _ = hist_tiling(67, n_pad, 64, 128, 4, requested)
+    T, _, _ = hist_tiling(67, n_pad, 64, 128, 4, "int8h", requested)
     assert T in tiles
 
 
@@ -105,11 +113,12 @@ def test_whole_set_or_nothing(F_pad, max_bin):
     chose before."""
     B = bin_stride(max_bin)
     C, _, cols = col_layout(32, "int8h")
-    T, ft, F_grid = hist_tiling(F_pad, N_PAD, B, cols, C, ROW_TILE,
-                                whole=True)
+    T, ft, F_grid = hist_tiling(F_pad, N_PAD, B, cols, C, "int8h",
+                                ROW_TILE, whole=True)
     assert ft == F_grid == F_pad
     fitting = [t for t in row_tiles(N_PAD, ROW_TILE)
-               if cell_vmem_bytes(F_pad, B, cols, t, C) <= VMEM_BUDGET_BYTES]
+               if cell_vmem_bytes(F_pad, B, cols, t, C, "int8h")
+               <= VMEM_BUDGET_BYTES]
     assert T == (fitting[0] if fitting else 1024)
 
 
@@ -119,8 +128,95 @@ def test_choice_does_not_depend_on_rows(A, n_pad):
     """A call's modelled time is its rows times a function of the grid,
     so a small test runs the tiles the 13.28M-row cell runs."""
     C, _, cols = col_layout(A, "int8h")
-    assert (hist_tiling(67, n_pad, 64, cols, C, ROW_TILE)[:2]
-            == hist_tiling(67, N_PAD, 64, cols, C, ROW_TILE)[:2])
+    assert (hist_tiling(67, n_pad, 64, cols, C, "int8h", ROW_TILE)[:2]
+            == hist_tiling(67, N_PAD, 64, cols, C, "int8h", ROW_TILE)[:2])
+
+
+# (T, feat_tile, F_grid) at 8 / 16 / 32 / 64 / 128 slots, 67 features,
+# 13,281,280 rows.  The bf16 modes': what the model chose before it knew
+# the element size (PR 29-33), and must still.  The int8 modes': the
+# benchmark cells' grids at 63 bins (the whole set at 2,048 rows a cell
+# in the 128-column calls and at 1,024 in the 256-column one: no feature
+# padded there), and what the rule picks at 255 bins, which no cell runs
+# yet.
+WHOLE_1K, WHOLE_2K = (1024, 67, 67), (2048, 67, 67)
+T24_1K, T24_2K = (1024, 24, 72), (2048, 24, 72)
+T8_1K, T8_2K = (1024, 8, 72), (2048, 8, 72)
+GRIDS = {
+    ("int8h", 63): [WHOLE_2K, WHOLE_2K, WHOLE_2K, WHOLE_1K, T24_2K],
+    ("int8", 63): [WHOLE_2K, WHOLE_2K, WHOLE_2K, WHOLE_1K, WHOLE_1K],
+    ("int8hh", 63): [WHOLE_2K, WHOLE_2K, WHOLE_1K, WHOLE_1K, T24_2K],
+    ("int8h", 255): [T24_1K, T24_1K, T24_1K, T8_2K, T8_2K],
+    ("hilo", 63): [WHOLE_1K, WHOLE_1K, T24_2K, T24_2K, T24_1K],
+    ("hhilo", 63): [WHOLE_1K, WHOLE_1K, WHOLE_1K, T24_2K, T24_2K],
+    ("ghilo", 63): [WHOLE_1K, WHOLE_1K, WHOLE_1K, T24_2K, T24_2K],
+    ("bf16", 63): [WHOLE_1K, WHOLE_1K, WHOLE_1K, T24_2K, T24_2K],
+    ("hilo", 255): [T8_2K, T8_2K, T8_2K, T8_1K, T8_1K],
+    ("hhilo", 255): [T8_2K, T8_2K, T8_2K, T8_2K, T8_1K],
+}
+
+
+@pytest.mark.parametrize("mode,max_bin", list(GRIDS))
+def test_grid_by_element_size(mode, max_bin):
+    B = bin_stride(max_bin)
+    got = []
+    for A in (8, 16, 32, 64, 128):
+        C, _, cols = col_layout(A, mode)
+        got.append(hist_tiling(67, N_PAD, B, cols, C, mode, ROW_TILE))
+    assert got == GRIDS[mode, max_bin]
+
+
+@pytest.mark.parametrize("mode,bytes_128x2048", [
+    ("int8h", 11_579_392),      # 2.20 MB of accumulator, 8.78 of one-hot
+    ("hilo", 20_623_360),       # the same cell in bf16: turned away
+])
+def test_cell_bytes_by_element_size(mode, bytes_128x2048):
+    """The 67-feature x 2,048-row cell at 128 columns, 63 bins: the
+    one-hot and the weighted values at the mode's element size, the
+    accumulator at 4 bytes, the bins tile at 1, the packed values as
+    they were."""
+    got = cell_vmem_bytes(67, 64, 128, 2048, 4, mode)
+    assert got == bytes_128x2048
+    assert vmem.operand_bytes(mode) == (1 if mode == "int8h" else 2)
+    assert (got <= VMEM_BUDGET_BYTES) == (mode == "int8h")
+    assert VMEM_BUDGET_BYTES == 12 * 1024 * 1024
+
+
+@pytest.mark.parametrize("features,max_bin,leaves,mode,ok", [
+    (67, 63, 255, "int8h", False),  # the cells: 60 features fit, not 67
+    (28, 63, 255, "int8h", True),   # chip_smoke.py's width: as before
+    (28, 63, 255, "hilo", True),
+    (67, 63, 255, "hilo", False),
+    (60, 63, 255, "int8h", True),   # 43 before the one-hot's own byte
+    (61, 63, 255, "int8h", False),
+    (43, 63, 255, "hhilo", True),   # the bf16 cap: where it was
+    (44, 63, 255, "hhilo", False),
+])
+def test_fused_gate_by_element_size(features, max_bin, leaves, mode, ok):
+    """`fused_config_ok` is judged at the tail's columns at 1,024 rows.
+    The shapes the cells and the tests train at keep the decision they
+    had; between the bf16 cap and the int8 cap a quantized config now
+    takes the fused kernel (compiled for the described chip in
+    `tests/test_tpu_compile.py`)."""
+    from lightgbm_tpu.ops.pallas_histogram import fused_config_ok
+    assert fused_config_ok(features, max_bin, leaves, mode) == ok
+
+
+@pytest.mark.parametrize("max_bin,slots,mode,ok", [
+    (255, 128, "int8h", False),     # refused since PR 21: accumulators
+    (255, 128, "hilo", False),
+    (255, 64, "int8h", True),
+    (255, 64, "hilo", False),
+    (255, 128, "int8", True),       # 384 columns: admitted by the byte
+    (255, 64, "int8hh", True),      # likewise
+    (63, 128, "int8h", True),
+])
+def test_seeded_fold_gate_by_element_size(max_bin, slots, mode, ok):
+    """The streamed fold's gate: three accumulator-sized blocks decide
+    at 255 bins x 128 slots whatever the one-hot's size; the two cells
+    the byte admits are compiled for the described chip in
+    `tests/test_tpu_compile.py`."""
+    assert vmem.hist_fold_cell_ok(max_bin, slots, mode) == ok
 
 
 def test_booster_sets_the_tiling_gauges(monkeypatch):
@@ -152,8 +248,9 @@ def test_booster_sets_the_tiling_gauges(monkeypatch):
             "hist.wave_slots": "8,8,8,8,8,16,32,64|128"}
     for A in (8, 16, 32, 64, 128):
         C, _, cols = col_layout(A, "int8h")
-        T, ft, _ = hist_tiling(67, 4096, 64, cols, C, ROW_TILE)
+        T, ft, _ = hist_tiling(67, 4096, 64, cols, C, "int8h", ROW_TILE)
         want[f"hist.tiling.{cols}"] = f"{ft}x{T}"
     assert {k: v for k, v in gauges.items() if k.startswith("hist.")} == want
-    assert want["hist.tiling.128"] == "67x1024"
-    assert want["hist.tiling.256"] == want["hist.tiling.512"] == "24x2048"
+    assert want["hist.tiling.128"] == "67x2048"
+    assert want["hist.tiling.256"] == "67x1024"
+    assert want["hist.tiling.512"] == "24x2048"
