@@ -525,15 +525,18 @@ class GBDT:
                                           effective_hist_mode,
                                           resolve_backend, uses_pallas)
             asked_mode = dist_hist_mode or default_hist_mode()
-            # the mode that runs is judged on what sums in int32: a
-            # SHARD's rows where the learner shards rows (the build
-            # judges it on its own bins inside the shard_map), all rows
-            # where it replicates them
+            # the mode that runs is judged on what the limbs add: a
+            # SHARD's row chunks where the learner shards rows (the
+            # build judges it on its own bins inside the shard_map)
+            # times the shards the exchange adds, all rows where it
+            # replicates them
             shard_rows = (
                 self.mesh_ctx.pad_rows(self.num_data)
                 // self.mesh_ctx.num_data_shards
                 if self.mesh_ctx.row_sharded else self.num_data)
-            mesh_hist_mode = effective_hist_mode(asked_mode, shard_rows)
+            mesh_hist_mode = effective_hist_mode(
+                asked_mode, shard_rows,
+                self.mesh_ctx.num_data_shards if lt == "data" else 1)
             mesh_backend = resolve_backend(
                 self.device_data, growth.num_leaves, hist_mode=mesh_hist_mode)
             self._block_backend_ok = (jax.default_backend() != "tpu"
@@ -593,8 +596,8 @@ class GBDT:
         build program, on the instance and in the run summary's gauges
         — what a run on the chip checks to know which kernels it ran.
         Where the mode that runs is not the one asked for
-        (``effective_hist_mode``: a quantized mode past the exact-int32
-        row bound), the summary says so: gauge
+        (``effective_hist_mode``: a quantized mode of more row shards x
+        row chunks than the limbs add exactly), the summary says so: gauge
         ``gbdt.hist_mode_requested`` and event ``degrade:hist_mode``
         with the ``rows`` the bound was held against (a shard's under a
         row-sharded learner)."""
@@ -617,14 +620,16 @@ class GBDT:
         shapes they will see (``rows``: a shard's under a row-sharded
         learner): gauges ``hist.tiling.<cols>`` =
         ``"<feat_tile>x<row tile>"``, ``hist.feature_pad_pct``, the
-        largest share of all-zero features a wave contracts, and
+        largest share of all-zero features a wave contracts,
         ``hist.wave_slots``, the staged waves' slot counts and the
-        tail's (``"8,8,8,8,8,16,32,64|128"`` at 255 leaves)."""
-        from ..learner.serial import stage_plan
+        tail's (``"8,8,8,8,8,16,32,64|128"`` at 255 leaves), and
+        ``hist.row_chunks``, the int32 partials a call sums ``rows`` in
+        (1 but for a quantized mode past the rows one cell holds)."""
+        from ..learner.serial import shard_row_chunks, stage_plan
         from ..obs import gauge_set
         from ..ops.pallas_histogram import DEFAULT_ROW_TILE
         from ..ops.vmem import (bin_stride, col_layout, hist_tiling,
-                                round_up)
+                                is_quantized, round_up)
         dd = self.device_data
         F, B = dd.num_groups, bin_stride(dd.group_max_bins)
         n_pad = round_up(int(rows), DEFAULT_ROW_TILE)
@@ -640,6 +645,9 @@ class GBDT:
             gauge_set(f"hist.tiling.{cols}", f"{feat_tile}x{T}")
             F_widest = max(F_widest, F_grid)
         gauge_set("hist.feature_pad_pct", 100.0 * (F_widest - F) / F)
+        gauge_set("hist.row_chunks",
+                  shard_row_chunks(int(rows)) if is_quantized(hist_mode)
+                  else 1)
 
     def _setup_metrics(self) -> None:
         c = self.config

@@ -336,11 +336,12 @@ class StreamTrainer:
         self.Bh = bin_stride(self.dd_meta.group_max_bins)
         from ..learner.serial import default_hist_mode, effective_hist_mode
         # the hist mode keys on the GLOBAL row count, not the block
-        # size: quantized int32 accumulators bound on the total rows
-        # folded through them, and the in-memory model this trainer
-        # must equal bitwise keys its mode on n too
+        # size: the fold carries ONE int32 accumulator over the stream,
+        # bound on the total rows folded through it (the resident
+        # learner sums such rows in chunks; this fold does not yet:
+        # ROADMAP R2)
         self.hist_mode = effective_hist_mode(
-            config.hist_mode or default_hist_mode(), n)
+            config.hist_mode or default_hist_mode(), n, chunked=False)
         # kernel-exact folds: on the Pallas backend every block
         # call SEEDS the kernel accumulator from the carried raw grid
         # (learner.serial.make_hist_fold_fn), so the streamed chain IS

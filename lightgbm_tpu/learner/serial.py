@@ -44,13 +44,16 @@ import jax.numpy as jnp
 
 from ..io.binning import MISSING_NAN, MISSING_NONE, MISSING_ZERO
 from ..io.device import DeviceData
-from ..ops.pallas_histogram import (bin_stride, default_backend,
+from ..ops.pallas_histogram import (DEFAULT_ROW_TILE, INT8_ROW_LIMIT,
+                                    MAX_CODE_SHARDS, bin_stride,
+                                    default_backend,
                                     dequant_hist, fused_config_ok,
                                     hist_active_pallas,
                                     hist_active_scatter, hist_raw_layout,
                                     hist_route_pallas, is_quantized,
                                     pack_values, pack_values_q,
-                                    pallas_config_ok, transpose_bins,
+                                    pallas_config_ok, row_chunks,
+                                    sum_code_limbs, transpose_bins,
                                     unpack_hist_raw)
 from ..ops.pallas_route import (route_rows_pallas, route_rows_values_pallas,
                                 route_rows_xla)
@@ -231,14 +234,27 @@ def root_stats(grad, hess, bag):
     return reduce_chunk_sums(root_chunk_sums(grad, hess, bag))
 
 
-def root_code_sums(vals, bag) -> jnp.ndarray:
+def root_code_sums(vals, bag):
     """In-bag sums of the packed int8 value rows (:func:`pack_values_q`):
     ``[C, n_pad] int8 -> [C] int32``.  Integer adds are exact, so the
     result does not depend on the reduction order or on how the rows are
-    partitioned into blocks; per-block results add up to the whole."""
-    bag = jnp.pad(bag, (0, vals.shape[1] - bag.shape[0]))
-    return jnp.sum(jnp.where(bag[None, :], vals.astype(jnp.int32), 0),
-                   axis=1)
+    partitioned into blocks; per-block results add up to the whole.
+    More rows than one int32 sums exactly (``_INT8_ROW_LIMIT``) are
+    summed in row chunks as the histogram cells are, and the result is
+    the limb pair of the total (``sum_code_limbs``), which
+    :func:`root_stats_q` and the exchange take alike."""
+    n_pad = vals.shape[1]
+    bag = jnp.pad(bag, (0, n_pad - bag.shape[0]))
+
+    def part(bag, vals):
+        return jnp.sum(jnp.where(bag[None, :], vals.astype(jnp.int32), 0),
+                       axis=1)
+
+    K, rows = row_chunks(n_pad, 1, _INT8_ROW_LIMIT)
+    if K == 1:
+        return part(bag, vals)
+    return sum_code_limbs([part(bag[lo:lo + rows], vals[:, lo:lo + rows])
+                           for lo in range(0, n_pad, rows)])
 
 
 def root_stats_q(code_sums, scales, mode: str):
@@ -372,9 +388,11 @@ def resolve_backend(data: DeviceData, num_leaf_slots: int,
 
 
 # int8 histogram cells accumulate exactly in int32 only while n*127 <
-# 2^31 (~16.9M rows into one cell worst-case); past that the quantized
-# modes would silently wrap
-_INT8_ROW_LIMIT = ((1 << 31) - 1) // 127
+# 2^31 (~16.9M rows into one cell worst-case): the bound of a row CHUNK.
+# A chip's rows past it are summed in chunks of at most this many, and
+# the chunks' int32 partials add as limbs (``row_chunks``,
+# ``sum_code_limbs``); one accumulator over more rows would silently wrap
+_INT8_ROW_LIMIT = INT8_ROW_LIMIT
 
 
 # a float32 holds every integer up to here: the growth carries its row
@@ -401,14 +419,38 @@ def leaf_row_counts(leaf: jnp.ndarray, num_leaves: int) -> jnp.ndarray:
     return cnt.reshape(-1)[:num_leaves]
 
 
-def effective_hist_mode(mode: str, n: int) -> str:
-    """Downgrade quantized modes past the exact-int32 row bound (the
-    root leaf can concentrate every row in one cell) to the closest
-    float mode by the parity table: int8hh (hi/lo grad AND hessian)
-    maps to hilo, the others to hhilo."""
-    if is_quantized(mode) and n > _INT8_ROW_LIMIT:
+def effective_hist_mode(mode: str, n: int, shards: int = 1,
+                        chunked: bool = True) -> str:
+    """The mode that runs for ``n`` rows on each of ``shards`` row
+    shards.  A quantized mode sums a chunk of at most ``_INT8_ROW_LIMIT``
+    rows exactly in int32 (the root leaf can concentrate every row in
+    one cell); a resident shard of more rows is summed in row chunks
+    whose partials add as limbs, so its size alone downgrades nothing.
+    What does: more parts (shards x chunks) than the limbs add exactly
+    (``MAX_CODE_SHARDS``), or one accumulator carried over all ``n``
+    rows (``chunked`` False: the streamed fold, `boosting/streaming.py`)
+    past the bound of a chunk.  Then the closest float mode by the
+    parity table runs: int8hh (hi/lo grad AND hessian) maps to hilo,
+    the others to hhilo."""
+    if not is_quantized(mode):
+        return mode
+    chunks = shard_row_chunks(n)
+    if shards * chunks > MAX_CODE_SHARDS or (chunks > 1 and not chunked):
         return "hilo" if mode == "int8hh" else "hhilo"
     return mode
+
+
+def _row_shards(psum_fn) -> int:
+    """The row shards whose sums ``psum_fn`` adds (None: one)."""
+    return psum_fn.num_shards if psum_fn is not None else 1
+
+
+def shard_row_chunks(n: int) -> int:
+    """The row chunks a quantized mode sums a resident shard of ``n``
+    rows in: the histogram call's own count (``row_chunks`` over its
+    row tiles) at the largest tile, 1 up to 16,908,288 rows."""
+    return row_chunks(-(-n // DEFAULT_ROW_TILE), DEFAULT_ROW_TILE,
+                      _INT8_ROW_LIMIT)[0]
 
 
 def default_hist_mode() -> str:
@@ -497,7 +539,8 @@ def make_hist_fn(data: DeviceData, grad, hess, num_leaf_slots: int,
                     bins_t, vals, leaf, active, scales,
                     num_features=data.num_groups,
                     max_bins=data.group_max_bins,
-                    mode=hist_mode, interpret=interp)
+                    mode=hist_mode, interpret=interp,
+                    row_limit=_INT8_ROW_LIMIT)
     else:
         n = data.bins.shape[0]
 
@@ -560,7 +603,9 @@ def make_hist_fold_fn(data: DeviceData, num_leaf_slots: int,
       num_data: GLOBAL stream row count for the quantized-mode row
         bound (``effective_hist_mode`` must see the stream total, not
         the block size — a 1B-row stream can overflow an int32 cell
-        even though each block is tiny).  Defaults to ``data.num_data``.
+        even though each block is tiny; the fold carries ONE
+        accumulator, so the bound of a chunk is the bound of the
+        stream).  Defaults to ``data.num_data``.
 
     Returns None when the resolved backend is scatter (caller keeps the
     carried-f32 scatter fold) or the SEEDED cell is VMEM-infeasible.
@@ -570,7 +615,8 @@ def make_hist_fold_fn(data: DeviceData, num_leaf_slots: int,
     if hist_mode is None:
         hist_mode = default_hist_mode()
     hist_mode = effective_hist_mode(
-        hist_mode, data.num_data if num_data is None else num_data)
+        hist_mode, data.num_data if num_data is None else num_data,
+        chunked=False)
     backend = resolve_backend(data, num_leaf_slots, backend, hist_mode)
     if not uses_pallas(backend):
         return None
@@ -589,7 +635,6 @@ def make_hist_fold_fn(data: DeviceData, num_leaf_slots: int,
                  f"(resolved backend {backend}): {why}", level="info")
         return None
 
-    from ..ops.pallas_histogram import DEFAULT_ROW_TILE
     n_pad = round_up(block_rows, DEFAULT_ROW_TILE)
     F_pad = data.num_groups     # per-block transpose_bins(feat_tile=None)
     shape, dtype = hist_raw_layout(n_pad, num_active, F_pad, mb, hist_mode)
@@ -727,7 +772,7 @@ def make_serial_strategy(data: DeviceData, grad, hess, params: GrowthParams,
     cut (``tests/test_parallel.py``)."""
     L = params.num_leaves
     mode = effective_hist_mode(hist_mode or default_hist_mode(),
-                               data.num_data)
+                               data.num_data, _row_shards(psum_fn))
     backend = resolve_backend(data, L, backend, mode)
     codes = (psum_fn is not None and uses_pallas(backend)
              and is_quantized(mode))
@@ -842,7 +887,8 @@ def build_tree(data: DeviceData,
     n = data.bins.shape[0]
     L = params.num_leaves
 
-    mode = effective_hist_mode(hist_mode or default_hist_mode(), n)
+    mode = effective_hist_mode(hist_mode or default_hist_mode(), n,
+                               _row_shards(psum_fn))
     backend = resolve_backend(data, L, hist_backend, mode)
     if uses_pallas(backend) and bins_t is None:
         bins_t = transpose_bins(data.bins)
@@ -875,7 +921,7 @@ def build_tree(data: DeviceData,
     fused = (strategy is None and psum_fn is None and uses_pallas(backend)
              and not _os.environ.get("LGBM_TPU_NO_FUSED")
              and fused_config_ok(bins_t.shape[0], data.group_max_bins, L,
-                                 mode))
+                                 mode, bins_t.shape[1], _INT8_ROW_LIMIT))
     fused_fn = (make_fused_fn(data, grad, hess, mode, bins_t, scales)
                 if fused else None)
     if strategy is None and not fused:
@@ -950,8 +996,7 @@ def build_tree(data: DeviceData,
             row_value = jnp.zeros(0, jnp.float32)   # empty: caller gathers
     final = final._replace(leaf2=leaf2_final)
     leaf_count = final.leaf_count.astype(jnp.int32)
-    if n * (psum_fn.num_shards if psum_fn is not None
-            else 1) > F32_EXACT_ROWS:
+    if n * _row_shards(psum_fn) > F32_EXACT_ROWS:
         # more rows than float32 counts exactly: the model's leaf counts
         # are the rows routed there, counted in integers (in-bag rows,
         # as the growth's own), summed over the shards

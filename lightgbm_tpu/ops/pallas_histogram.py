@@ -61,6 +61,7 @@ from __future__ import annotations
 
 import functools
 import os
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -295,28 +296,56 @@ def pack_values_q(grad: jnp.ndarray, hess: jnp.ndarray, mode: str,
     return vals.astype(jnp.int8), jnp.stack([sg, sh])
 
 
-def code_limbs(x: jnp.ndarray):
+# an int32 cell sums int8 codes exactly while rows * 127 < 2^31: the
+# most rows of one CHUNK (16,909,320).  More rows than that are summed
+# in chunks, each exact in int32, whose partials add as limbs
+INT8_ROW_LIMIT = ((1 << 31) - 1) // 127
+
+# the most int32 partials (row shards x row chunks) whose limbs
+# :func:`sum_code_limbs` / ``psum_codes`` add exactly
+MAX_CODE_SHARDS = 511
+
+
+def row_chunks(units: int, unit_rows: int = 1,
+               row_limit: int = INT8_ROW_LIMIT):
+    """``-> (K, units a chunk)``: the fewest chunks of at most
+    ``row_limit`` rows that hold ``units`` units of ``unit_rows`` rows
+    (a kernel's row tiles; single rows), the units shared evenly.
+    Static, from the shape: ``K == 1`` wherever one int32 sum holds."""
+    fit = max(1, row_limit // unit_rows)
+    K = -(-units // fit)
+    return K, -(-units // K)
+
+
+class CodeLimbs(NamedTuple):
+    """An integer code sum past int32 as two int32 limbs: ``hi * 2^16 +
+    lo``.  What tells a limb pair from any other pair of arrays."""
+    hi: jnp.ndarray
+    lo: jnp.ndarray
+
+
+def code_limbs(x: jnp.ndarray) -> CodeLimbs:
     """An int32 code sum as two 16-bit limbs ``(hi, lo)``: ``x = hi *
     2^16 + lo`` with ``lo`` in ``[0, 2^16)`` (``>>`` is arithmetic, so
     this holds for negative ``x`` too)."""
-    return x >> 16, x & 0xFFFF
+    return CodeLimbs(x >> 16, x & 0xFFFF)
 
 
-def sum_code_limbs(parts):
-    """Several shards' int32 code sums (arrays of one shape) added
-    exactly: ``-> (hi, lo)`` limbs of each cell's total, ``lo`` in ``[0,
-    2^16)``.  The streamed trainer's host-side twin of the mesh
-    exchange (`parallel/learners.py` ``psum_codes``, whose bounds hold
-    here: at most 511 parts)."""
+def sum_code_limbs(parts) -> CodeLimbs:
+    """Several int32 code sums (arrays of one shape: the shards' of a
+    stream, the row chunks' of a chip) added exactly: ``-> (hi, lo)``
+    limbs of each cell's total, ``lo`` in ``[0, 2^16)``.  The twin of
+    the mesh exchange (`parallel/learners.py` ``psum_codes``, whose
+    bounds hold here: at most 511 parts)."""
     his, los = zip(*(code_limbs(p) for p in parts))
     return carry_limbs(sum(his[1:], his[0]), sum(los[1:], los[0]))
 
 
-def carry_limbs(hi: jnp.ndarray, lo: jnp.ndarray):
+def carry_limbs(hi: jnp.ndarray, lo: jnp.ndarray) -> CodeLimbs:
     """Limbs whose ``lo`` holds a sum of low limbs ``->`` the pair of
     the same total with ``lo`` back in ``[0, 2^16)``: the one pair a
     total has, whatever sums it was made of."""
-    return hi + (lo >> 16), lo & 0xFFFF
+    return CodeLimbs(hi + (lo >> 16), lo & 0xFFFF)
 
 
 def _limbs_f32(hi: jnp.ndarray, lo: jnp.ndarray) -> jnp.ndarray:
@@ -336,11 +365,12 @@ def dequant_hist(out, scales: jnp.ndarray, mode: str) -> jnp.ndarray:
     """``[A, F, B, C] int32 (+ scales) -> [A, F, B, 3] f32`` — undo
     :func:`pack_values_q` after exact integer accumulation.
 
-    ``out`` is one chip's int32 code sums or, from the row-sharded
-    exchange, their sums over all shards as a pair of int32 limbs
-    (:func:`code_limbs`; `parallel/learners.py` ``psum_codes``): one
-    chip's sums go through the same limbs, so the same total gives the
-    same floats either way.  A hi/lo pair of value columns is combined
+    ``out`` is one int32 accumulator's code sums or the sums of several
+    (a chip's row chunks, :func:`sum_code_limbs`; all shards' from the
+    row-sharded exchange, `parallel/learners.py` ``psum_codes``) as a
+    pair of int32 limbs (:func:`code_limbs`): one accumulator's sums go
+    through the same limbs, so the same total gives the same floats
+    either way.  A hi/lo pair of value columns is combined
     as integers, ``127 * hi + lo`` in units of ``scale / 16129`` (limb
     by limb: ``|127 * hi_limb + lo_limb|`` stays under 2^31 for the 511
     shards ``psum_codes`` admits), and rounded to float32 once: no
@@ -430,7 +460,7 @@ def pad_features(bins_t: jnp.ndarray, F_grid: int) -> jnp.ndarray:
 
 def _hist_kernel(active_ref, bins_ref, vals_ref, leaf_ref,
                  *refs, n_cols: int, B: int, pad_cols: int,
-                 seeded: bool = False):
+                 seeded: bool = False, chunk_tiles: int = 0):
     """One (feature-tile, row-tile) grid cell; accumulates over row tiles.
 
     Everything rides rows-on-lanes: the leaf mask is built ``[A_pad, T]``
@@ -446,6 +476,11 @@ def _hist_kernel(active_ref, bins_ref, vals_ref, leaf_ref,
     bitwise EXTENSION of the monolithic kernel: same adds in the same
     order, just split across calls — which is what puts streamed
     training in the byte-identity domain on the kernel backends.
+
+    ``chunk_tiles``: where the rows of a call are more than one int32
+    cell sums exactly, the output has a leading axis of row chunks (the
+    caller's ``BlockSpec`` moves on every ``chunk_tiles`` row tiles) and
+    the accumulator starts anew at each chunk's first tile.
     """
     if seeded:
         acc_ref, out_ref = refs
@@ -453,7 +488,7 @@ def _hist_kernel(active_ref, bins_ref, vals_ref, leaf_ref,
         (out_ref,) = refs
     rt = pl.program_id(1)
 
-    @pl.when(rt == 0)
+    @pl.when(rt % chunk_tiles == 0 if chunk_tiles else rt == 0)
     def _():
         if seeded:
             out_ref[:] = acc_ref[:]
@@ -478,7 +513,7 @@ def _hist_kernel(active_ref, bins_ref, vals_ref, leaf_ref,
 @functools.partial(
     jax.jit,
     static_argnames=("num_features", "max_bins", "mode", "row_tile",
-                     "interpret", "raw"))
+                     "interpret", "raw", "row_limit"))
 def hist_active_pallas(bins_t: jnp.ndarray,
                        vals: jnp.ndarray,
                        row_leaf: jnp.ndarray,
@@ -491,7 +526,8 @@ def hist_active_pallas(bins_t: jnp.ndarray,
                        mode: str = "hilo",
                        row_tile: int = DEFAULT_ROW_TILE,
                        interpret: bool = False,
-                       raw: bool = False) -> jnp.ndarray:
+                       raw: bool = False,
+                       row_limit: int = INT8_ROW_LIMIT) -> jnp.ndarray:
     """Histograms for the active leaves: ``-> [A, F, B, 3]`` float32.
 
     Args:
@@ -515,6 +551,15 @@ def hist_active_pallas(bins_t: jnp.ndarray,
         (int32 on the quantized path) instead of unpacking — the carry
         for the next block's ``acc``.  Unpack once at the end of the
         fold chain with :func:`unpack_hist_raw`.
+      row_limit: the most rows one int32 cell sums exactly (quantized
+        modes).  A call over more rows sums them in ``K`` row chunks
+        (:func:`row_chunks` over its row tiles): the kernel's output is
+        ``[K, F_grid*B, cols]``, each chunk exact in int32 (what
+        ``raw=True`` returns then), and the chunks' partials are added
+        as limbs (:func:`sum_code_limbs`, scope ``tree.hist.chunk_sum``)
+        before the one dequantization; with ``scales`` None the result
+        is the limb pair of the ``[A, F, B, C]`` code sums.  ``K == 1``
+        is the one-accumulator program.
 
     Returns:
       ``[A, F, B, 3]`` f32 with B = ``bin_stride(max_bins)``, cells
@@ -542,6 +587,14 @@ def hist_active_pallas(bins_t: jnp.ndarray,
     T, feat_tile, F_grid = hist_tiling(F_pad, n_pad, B, cols, C, mode,
                                        row_tile, seeded)
     assert n_pad % T == 0, (n_pad, T)
+    K, chunk_tiles = (row_chunks(n_pad // T, T, row_limit)
+                      if is_quantized(mode) else (1, 0))
+    if K == 1:
+        chunk_tiles = 0         # the one-accumulator program
+    elif seeded:
+        raise ValueError(
+            f"a seeded histogram call carries one int32 accumulator: "
+            f"{n_pad} rows are {K} chunks of it")
     pad_cols = cols - C * A_pad
     bins_t = pad_features(bins_t, F_grid)
 
@@ -576,23 +629,34 @@ def hist_active_pallas(bins_t: jnp.ndarray,
                                      lambda f, r: (f, 0),
                                      memory_space=pltpu.VMEM))
         operands.append(acc)
+    out_block, out_index = (feat_tile * B, cols), lambda f, r: (f, 0)
+    out_dims = (F_grid * B, cols)
+    if K > 1:
+        # a leading axis of row chunks in the accumulator: the output
+        # block moves on every `chunk_tiles` row tiles, and each chunk's
+        # cells are exact in int32
+        out_block, out_dims = (None, *out_block), (K, *out_dims)
+
+        def out_index(f, r):
+            return r // chunk_tiles, f, 0
     out = pl.pallas_call(
         functools.partial(_hist_kernel, n_cols=C, B=B, pad_cols=pad_cols,
-                          seeded=seeded),
+                          seeded=seeded, chunk_tiles=chunk_tiles),
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((feat_tile * B, cols),
-                               lambda f, r: (f, 0),
+        out_specs=pl.BlockSpec(out_block, out_index,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct(
-            (F_grid * B, cols),
-            jnp.int32 if is_quantized(mode) else jnp.float32),
+            out_dims, jnp.int32 if is_quantized(mode) else jnp.float32),
         input_output_aliases=({4: 0} if seeded else {}),
         interpret=interpret,
     )(*operands)
 
     if raw:
         return out
+    if K > 1:
+        with jax.named_scope("tree.hist.chunk_sum"):
+            out = sum_code_limbs(tuple(out[k] for k in range(K)))
     # [F_grid*B, cols] -> [A, F, B, C'] -> combine hi/lo -> [A, F, B, 3]
     return _unpack_hist(out, B, cols, C, A_pad, A, num_features, mode,
                         scales)
@@ -812,11 +876,15 @@ def _hist_route_kernel(active_ref, bins_ref, vals_ref, leaf2_ref, rtabs_ref,
 
 
 def fused_config_ok(num_groups: int, max_bins: int, num_leaves: int,
-                    mode: str) -> bool:
+                    mode: str, n_rows: int = 0,
+                    row_limit: int = INT8_ROW_LIMIT) -> bool:
     """Fusion needs the whole feature set in one tile (the route reads the
     split feature's column, which may live in any tile) plus the usual
-    kernel bounds."""
+    kernel bounds; its one int32 accumulator also needs the ``n_rows``
+    of a quantized call to be one row chunk (:func:`row_chunks`)."""
     if not pallas_config_ok(max_bins, num_leaves, mode):
+        return False
+    if is_quantized(mode) and n_rows > row_limit:
         return False
     B = bin_stride(max_bins)
     C, _, cols = _col_layout(min(max(1, num_leaves // 2), 128), mode)

@@ -74,42 +74,44 @@ from ..learner.serial import (BuiltTree, GrowthParams, apply_hist_wave,
                               make_hist_fn,
                               resolve_backend, split_cache_enabled,
                               uses_pallas)
-from ..ops.pallas_histogram import (bin_stride, carry_limbs, code_limbs,
-                                    is_quantized, quant_scales)
+from ..ops.pallas_histogram import (MAX_CODE_SHARDS, CodeLimbs, bin_stride,
+                                    carry_limbs, code_limbs, is_quantized,
+                                    quant_scales)
 from ..ops.split import (K_MIN_SCORE, SplitParams, SplitResult,
                          find_best_splits)
 
 
-# the most shards whose code sums :func:`psum_codes` adds exactly
-MAX_CODE_SHARDS = 511
-
-
-def psum_codes(x: jnp.ndarray, axis: str, num_shards: int):
+def psum_codes(x, axis: str, num_shards: int) -> CodeLimbs:
     """The sum over ``axis`` of int32 code sums, exact whatever the
     data: ``-> (hi, lo)`` int32 limbs of each cell's total, ``hi * 2^16
     + lo`` with ``lo`` in ``[0, 2^16)``, for ``dequant_hist`` to round
-    to float32 once.
+    to float32 once.  ``x`` is a shard's int32 code sums or, from a
+    shard of more rows than one int32 cell sums exactly, the limb pair
+    of its row chunks' sums (``sum_code_limbs``).
 
-    A shard's cell is exact in int32 while its rows are at most
-    ``_INT8_ROW_LIMIT`` (`learner/serial.py`), but the total of 4 x
-    13.28M rows x 127 is past 2^31, and float32 partial sums would lose
-    the integers above 2^24 that the one-chip path keeps.  So a cell
-    crosses the shards as two 16-bit limbs (``code_limbs``: ``hi`` in
-    ``[-2^15, 2^15)``, ``lo`` in ``[0, 2^16)``), each summed in int32:
-    over ``S`` shards ``|sum hi| <= S * 2^15`` and ``sum lo < S *
-    2^16``, nowhere near 2^31.  The carry of ``sum lo`` moves to the
-    high limb, which leaves ``|hi| <= S * (2^15 + 1)``: under the 2^24
-    to which ``dequant_hist`` converts exactly, and under the 2^31 / 127
-    its hi/lo pairs need, for ``S <= 511``.  The limbs of a total are
-    unique, so a total that one chip can hold in int32 dequantizes to
-    the same floats whether one chip summed it or many did:
-    `tests/test_parallel.py` holds 1, 2 and 4 shards to the serial
-    learner's trees, bit for bit."""
+    An int32 cell is exact while its rows are at most
+    ``_INT8_ROW_LIMIT`` (`learner/serial.py`: a chunk), but the total of
+    4 x 13.28M rows x 127 is past 2^31, and float32 partial sums would
+    lose the integers above 2^24 that the one-chip path keeps.  So a
+    cell crosses the shards as two 16-bit limbs (``code_limbs``: ``hi``
+    in ``[-2^15, 2^15)``, ``lo`` in ``[0, 2^16)``), each summed in
+    int32: over ``S`` parts (shards x chunks) ``|sum hi| <= S * 2^15``
+    and ``sum lo < S * 2^16``, nowhere near 2^31.  The carry of ``sum
+    lo`` moves to the high limb, which leaves ``|hi| <= S * (2^15 +
+    1)``: under the 2^24 to which ``dequant_hist`` converts exactly, and
+    under the 2^31 / 127 its hi/lo pairs need, for ``S <= 511``
+    (``MAX_CODE_SHARDS``; ``effective_hist_mode`` runs a float mode
+    past it).  The limbs of a total are unique, so a total that one
+    chip can hold in int32 dequantizes to the same floats whether one
+    accumulator summed it or many did: `tests/test_parallel.py` holds 1,
+    2 and 4 shards, and 1, 2 and 4 chunks, to the serial learner's
+    trees, bit for bit."""
     if num_shards > MAX_CODE_SHARDS:
         raise ValueError(
             f"the exact exchange of quantized histograms holds for at most "
             f"{MAX_CODE_SHARDS} row shards, not {num_shards}")
-    return carry_limbs(*jax.lax.psum(code_limbs(x), axis))
+    limbs = x if isinstance(x, CodeLimbs) else code_limbs(x)
+    return carry_limbs(*jax.lax.psum(limbs, axis))
 
 
 class Psum:
@@ -132,12 +134,15 @@ class Psum:
         self.axis, self.num_shards = axis, num_shards
 
     def reduce(self, x, what: str = "hist_psum"):
+        def limbs(a):
+            return isinstance(a, CodeLimbs)
+
         def one(a):
-            if jnp.issubdtype(a.dtype, jnp.integer):
+            if limbs(a) or jnp.issubdtype(a.dtype, jnp.integer):
                 return psum_codes(a, self.axis, self.num_shards)
             return jax.lax.psum(a, self.axis)
         with jax.named_scope("collective." + what):
-            return jax.tree.map(one, x)
+            return jax.tree.map(one, x, is_leaf=limbs)
 
     def __call__(self, x, what: str = "hist_psum"):
         _fr_record("parallel.learners." + what, "psum", self.axis, x)
@@ -445,7 +450,8 @@ def build_tree_distributed(mesh: Mesh, axis: str, learner_type: str,
     # rows that sum in int32 (a shard's where rows are sharded), the
     # backend on shapes that do not depend on the rows
     mode = effective_hist_mode(hist_mode or default_hist_mode(),
-                               n // num_shards if row_shard else n)
+                               n // num_shards if row_shard else n,
+                               num_shards if learner_type == "data" else 1)
     backend = resolve_backend(data, params.num_leaves, hist_backend, mode)
     quantized_kernels = (row_shard and uses_pallas(backend)
                          and is_quantized(mode))

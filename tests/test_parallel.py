@@ -252,59 +252,94 @@ TREE_FIELDS = ("feature", "threshold_bin", "default_left", "left_child",
                "row_leaf")
 
 
+# the rows of the trees below: one row tile of the kernels, and the four
+# that a cut into row chunks needs
+ROWS, ROWS_CHUNKED = 2048, 8192
+
+
 @pytest.fixture(scope="module")
 def uneven():
     """Rows whose largest gradient and hessian differ from shard to
-    shard: a shard's own scales would round its rows to other codes."""
-    n = 2048
-    X, y = _data(n, 8, seed=4)
-    rng = np.random.RandomState(9)
-    ds = BinnedDataset.from_raw(X, Config.from_params({"max_bin": 63}))
-    grad = -(y - y.mean())
-    grad[:n // 4] *= 0.37
-    hess = (0.5 + rng.rand(n)).astype(np.float32)
-    hess[n // 2:] *= 0.61
-    p = GrowthParams(num_leaves=15, split=SplitParams(
-        min_data_in_leaf=10, min_sum_hessian_in_leaf=0.0))
-    return to_device(ds), jnp.asarray(grad), jnp.asarray(hess), p
+    shard: a shard's own scales would round its rows to other codes.
+    ``{rows: (data, grad, hess, params)}``."""
+    def make(n):
+        X, y = _data(n, 8, seed=4)
+        rng = np.random.RandomState(9)
+        ds = BinnedDataset.from_raw(X, Config.from_params({"max_bin": 63}))
+        grad = -(y - y.mean())
+        grad[:n // 4] *= 0.37
+        hess = (0.5 + rng.rand(n)).astype(np.float32)
+        hess[n // 2:] *= 0.61
+        p = GrowthParams(num_leaves=15, split=SplitParams(
+            min_data_in_leaf=10, min_sum_hessian_in_leaf=0.0))
+        return to_device(ds), jnp.asarray(grad), jnp.asarray(hess), p
+    return {n: make(n) for n in (ROWS, ROWS_CHUNKED)}
 
 
 @pytest.fixture(scope="module")
 def row_sets():
     """What a tree is grown on: all rows and features, or a bag of 70%
-    of the rows (the same rows however they are cut into shards) under
-    a feature mask."""
-    bag = jnp.asarray(np.random.RandomState(11).rand(2048) < 0.7)
+    of the rows (the same rows however they are cut into shards or
+    chunks) under a feature mask."""
     fmask = jnp.asarray(np.array([1, 1, 0, 1, 1, 0, 1, 1], bool))
-    return {"all": {}, "bagged": dict(bag_mask=bag, feature_mask=fmask)}
+
+    def bag(n):
+        return jnp.asarray(np.random.RandomState(11).rand(n) < 0.7)
+    return {n: {"all": {}, "bagged": dict(bag_mask=bag(n),
+                                          feature_mask=fmask)}
+            for n in (ROWS, ROWS_CHUNKED)}
 
 
 @pytest.fixture(scope="module")
 def serial_trees(uneven, row_sets):
-    dd, grad, hess, p = uneven
-    return {(mode, rows): jax.jit(lambda g, h, m=mode, kw=kw: build_tree(
-        dd, g, h, p, hist_backend="pallas", hist_mode=m, **kw))(grad, hess)
-        for mode in ("int8", "int8h", "int8hh")
-        for rows, kw in row_sets.items()}
+    """The serial learner's trees, every sum in one int32 accumulator."""
+    def grow(n, mode, kw):
+        dd, grad, hess, p = uneven[n]
+        return jax.jit(lambda g, h: build_tree(
+            dd, g, h, p, hist_backend="pallas", hist_mode=mode, **kw))(
+                grad, hess)
+    return {(n, mode, rows): grow(n, mode, kw)
+            for n in (ROWS, ROWS_CHUNKED)
+            for mode in ("int8", "int8h", "int8hh")
+            for rows, kw in row_sets[n].items()}
+
+
+# (row shards, row chunks a shard): the cuts of one set of rows
+CUTS = [(1, 1), (2, 1), (4, 1), (1, 2), (1, 4), (2, 2)]
 
 
 @pytest.mark.parametrize("rows", ["all", "bagged"])
-@pytest.mark.parametrize("shards", [1, 2, 4])
+@pytest.mark.parametrize("shards,chunks", CUTS,
+                         ids=[f"{s}x{k}" for s, k in CUTS])
 @pytest.mark.parametrize("mode", ["int8", "int8h", "int8hh"])
 def test_quantised_data_parallel_grows_the_serial_tree(
-        eight_devices, uneven, row_sets, serial_trees, mode, shards, rows):
+        eight_devices, uneven, row_sets, serial_trees, monkeypatch, mode,
+        shards, chunks, rows):
     """Global scales, integer code sums across the shards, one
     dequantisation: every field of the tree, gains and leaf values
     included, is the serial learner's bit for bit — bagged-out rows,
-    masked features and padding slots included."""
-    dd, grad, hess, p = uneven
-    dist = build_tree_distributed(make_mesh(shards), "data", "data", dd,
-                                  grad, hess, p, hist_backend="pallas",
-                                  hist_mode=mode, **row_sets[rows])
+    masked features and padding slots included.  A shard of more rows
+    than one int32 cell sums exactly (the bound patched down to a row
+    tile or two) sums them in row chunks whose partials add as the
+    shards' do: the same tree from 1, 2 and 4 chunks on one chip
+    (the serial learner, no exchange) and from two shards of two."""
+    from lightgbm_tpu.learner import serial
+    n = ROWS if chunks == 1 else ROWS_CHUNKED
+    dd, grad, hess, p = uneven[n]
+    kw = dict(hist_backend="pallas", hist_mode=mode, **row_sets[n][rows])
+    if chunks > 1:
+        monkeypatch.setattr(serial, "_INT8_ROW_LIMIT", n // shards // chunks)
+        assert serial.shard_row_chunks(n // shards) == chunks
+        assert serial.effective_hist_mode(mode, n // shards, shards) == mode
+    if shards == 1 and chunks > 1:
+        got = jax.jit(lambda g, h: build_tree(dd, g, h, p, **kw))(grad, hess)
+    else:
+        got = build_tree_distributed(make_mesh(shards), "data", "data", dd,
+                                     grad, hess, p, **kw)
     for name in TREE_FIELDS:
         np.testing.assert_array_equal(
-            np.asarray(getattr(dist, name)),
-            np.asarray(getattr(serial_trees[mode, rows], name)),
+            np.asarray(getattr(got, name)),
+            np.asarray(getattr(serial_trees[n, mode, rows], name)),
             err_msg=name)
 
 
@@ -342,14 +377,17 @@ def test_code_sums_cross_the_shards_without_wrapping(eight_devices):
         psum_codes(jnp.zeros(4, jnp.int32), "data", 512)
 
 
-@pytest.mark.parametrize("shard_over", [False, True],
-                         ids=["global_over", "shard_over"])
-def test_int8_mode_is_judged_on_a_shards_rows(eight_devices, monkeypatch,
-                                              shard_over):
-    """What sums in int32 is a shard's rows.  All rows over the bound
-    and each shard under it: the int8 mode runs, the gauge says so, no
-    ``degrade`` event, and the trees are the serial learner's at the
-    same mode.  A shard over it: the float mode runs, and says so."""
+@pytest.mark.parametrize("case", ["global_over", "shard_over",
+                                  "parts_over"])
+def test_int8_mode_is_judged_on_the_parts_it_sums(eight_devices, monkeypatch,
+                                                  case):
+    """What sums in int32 is a chunk of a shard's rows, and what the
+    limbs add is shards x chunks.  All rows over the bound of a chunk
+    and each shard under it, or a shard over it too (it sums its rows in
+    chunks): the int8 mode runs, the gauge says so, no ``degrade``
+    event, and the trees are the serial learner's at the same mode.
+    More parts than the limbs add exactly: the float mode runs, and
+    says so."""
     from lightgbm_tpu import obs
     from lightgbm_tpu.learner import serial
     monkeypatch.setenv("LGBM_TPU_HIST_BACKEND", "pallas")
@@ -359,7 +397,10 @@ def test_int8_mode_is_judged_on_a_shards_rows(eight_devices, monkeypatch,
               "hist_mode": "int8h", "verbose": -1}
     want = lgb.train(params, lgb.Dataset(X, label=y), 3,
                      verbose_eval=False)._gbdt.save_model_to_string()
-    monkeypatch.setattr(serial, "_INT8_ROW_LIMIT", 400 if shard_over else 600)
+    monkeypatch.setattr(serial, "_INT8_ROW_LIMIT",
+                        400 if case == "shard_over" else 600)
+    if case == "parts_over":
+        monkeypatch.setattr(serial, "MAX_CODE_SHARDS", 3)
     obs.reset()
     obs.enable()
     try:
@@ -370,7 +411,7 @@ def test_int8_mode_is_judged_on_a_shards_rows(eight_devices, monkeypatch,
     finally:
         obs.reset()
     assert bst._gbdt.mesh_ctx.num_data_shards == 4      # 501 rows a shard
-    if shard_over:
+    if case == "parts_over":
         assert bst._gbdt.hist_mode == s["gauges"]["gbdt.hist_mode"] == "hhilo"
         assert s["gauges"]["gbdt.hist_mode_requested"] == "int8h"
         assert s["events"]["degrade:hist_mode"] == 1

@@ -182,7 +182,9 @@ def test_span_attributes_carry_rows_and_features(tmp_path):
 def test_a_replaced_hist_mode_shows_in_the_summary(monkeypatch, degraded):
     from lightgbm_tpu.learner import serial
     if degraded:
-        monkeypatch.setattr(serial, "_INT8_ROW_LIMIT", 100)
+        # more parts than the limbs add exactly (a resident shard's size
+        # alone replaces no mode: its rows are summed in chunks)
+        monkeypatch.setattr(serial, "MAX_CODE_SHARDS", 0)
     obs.enable()
     X, y = _data(500, 5)
     bst = lgb.Booster(params={"objective": "binary", "hist_mode": "int8h",

@@ -40,6 +40,7 @@ N, F, MAX_BIN, LEAVES = 1 << 20, 28, 63, 255
 F_CRITEO = 67               # the benchmark cells' width
 N_TREE = 131_072            # whole-tree programs: same widths, fewer rows
 N_CRITEO = 13_281_280       # a cell's shard, padded to the row tile
+N_C32 = 53_125_000          # a chip of 32: four row chunks at an int8 mode
 VALUE_ROWS = {"int8": 3, "int8h": 4, "int8hh": 5, "hilo": 5}
 
 
@@ -157,6 +158,27 @@ def test_wide_hist_kernel_compiles_at_the_cells_shape(one_chip, slots,
         *_hist_args(s, N_CRITEO, "int8h", slots, F_CRITEO),
         num_features=F_CRITEO, max_bins=MAX_BIN, mode="int8h"))
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("slots,cols,tile", [(8, 128, 2048), (32, 128, 2048),
+                                             (64, 256, 1024)])
+def test_chunked_hist_kernel_compiles_at_the_c32_shape(one_chip, slots, cols,
+                                                       tile):
+    """`criteo-67-b63-c32.train`'s calls: `[67, 53,125,120]` (3.56e9
+    bins, the first array here past 2^31 elements), int8h: four row
+    chunks in the accumulator (`s32[4, 4288, cols]`), the output block
+    moving on every 6,485 row tiles of 2,048 (12,970 of 1,024), and the
+    limb sum after the call."""
+    from lightgbm_tpu.ops.pallas_histogram import (hist_active_pallas,
+                                                   row_chunks)
+    n_pad = N_C32 + 120
+    assert row_chunks(n_pad // tile, tile) == (4, 6_485 * 2048 // tile)
+    s = _shapes(one_chip)
+    text = _compiled_text(hist_active_pallas.lower(
+        *_hist_args(s, n_pad, "int8h", slots, F_CRITEO),
+        num_features=F_CRITEO, max_bins=MAX_BIN, mode="int8h"))
+    assert "tpu_custom_call" in text
+    assert f"s32[4,4288,{cols}]" in text
 
 
 @pytest.mark.parametrize("max_bin,slots,mode", [
@@ -287,6 +309,35 @@ def test_build_tree_default_backend_compiles(one_chip, on_tpu):
         dd, s((N_TREE,), jnp.float32), s((N_TREE,), jnp.float32),
         s((F, N_TREE), jnp.uint8)))
     assert text.count("tpu_custom_call") >= 3
+
+
+def test_build_tree_compiles_at_the_c32_cells_rows(one_chip, on_tpu):
+    """A tree of `criteo-67-b63-c32.train`: 53,125,000 x 67 on one chip
+    at int8h, every histogram call and the root's sums in four row
+    chunks.  XLA's own plan for it (arguments + temporaries) fits a
+    v5e's 16 GB with the room the cell asks for (under 12 GiB)."""
+    from lightgbm_tpu.learner.serial import (effective_hist_mode,
+                                             shard_row_chunks)
+    assert effective_hist_mode("int8h", N_C32) == "int8h"
+    assert shard_row_chunks(N_C32) == 4
+    s = _shapes(one_chip)
+    dd = _device_data(N_C32, one_chip, one_chip, F_CRITEO)
+    growth = _growth()
+    fn = jax.jit(lambda dd, g, h, bins_t: build_tree(
+        dd, g, h, growth, bins_t=bins_t, hist_mode="int8h"))
+    compiled = fn.lower(
+        dd, s((N_C32,), jnp.float32), s((N_C32,), jnp.float32),
+        s((F_CRITEO, N_C32 + 120), jnp.uint8)).compile()
+    text = compiled.as_text()
+    assert "s32[4,4288,128]" in text and "s32[4,4288,256]" in text
+    # the limb sum under its scope, and the leaves' rows recounted in
+    # integers (53.1M rows are past what float32 counts exactly)
+    assert "tree.hist.chunk_sum" in text and "tree.count" in text
+    mem = compiled.memory_analysis()
+    plan = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    print(f"args {mem.argument_size_in_bytes / 2 ** 20:.0f} MiB + temp "
+          f"{mem.temp_size_in_bytes / 2 ** 20:.0f} MiB")
+    assert 4 * 2 ** 30 < plan < 12 * 2 ** 30
 
 
 def test_build_tree_distributed_data_compiles(topo, on_tpu):

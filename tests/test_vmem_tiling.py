@@ -220,7 +220,7 @@ def test_seeded_fold_gate_by_element_size(max_bin, slots, mode, ok):
 
 
 def test_booster_sets_the_tiling_gauges(monkeypatch):
-    """`hist.tiling.<cols>` and `hist.feature_pad_pct` beside
+    """`hist.tiling.<cols>`, `hist.feature_pad_pct`, `hist.row_chunks` beside
     `gbdt.hist_backend`, as `chip_smoke.py` prints them: every wave's
     grid of a 255-leaf tree at the Criteo width, from the kernels' own
     rule."""
@@ -245,7 +245,8 @@ def test_booster_sets_the_tiling_gauges(monkeypatch):
         obs.reset()
     assert gauges["gbdt.hist_backend"] == "pallas"
     want = {"hist.feature_pad_pct": 100.0 * (72 - 67) / 67,
-            "hist.wave_slots": "8,8,8,8,8,16,32,64|128"}
+            "hist.wave_slots": "8,8,8,8,8,16,32,64|128",
+            "hist.row_chunks": 1}
     for A in (8, 16, 32, 64, 128):
         C, _, cols = col_layout(A, "int8h")
         T, ft, _ = hist_tiling(67, 4096, 64, cols, C, "int8h", ROW_TILE)
