@@ -228,7 +228,11 @@ class Booster:
         if train_set is not None:
             train_set.construct()
             cfg = Config.from_params(params)
-            from .boosting.variants import create_boosting
+            # the first import of the boosting package (the learner, the
+            # Pallas kernels) is seconds of a process's first training call
+            from .obs import span
+            with span("engine.import"):
+                from .boosting.variants import create_boosting
             self._gbdt = create_boosting(cfg, train_set._constructed,
                                          fobj=cfg.extra.get("fobj"))
             self._valid_sets: List[Dataset] = []

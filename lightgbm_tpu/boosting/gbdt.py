@@ -254,7 +254,8 @@ class GBDT:
         self._watchdog = None
 
         if train_set is not None:
-            self._init_train(train_set)
+            with obs_span("gbdt.init", rows=train_set.num_data):
+                self._init_train(train_set)
 
     # ------------------------------------------------------------------
     def _init_train(self, train_set: BinnedDataset) -> None:
@@ -323,15 +324,17 @@ class GBDT:
         if self.objective is None and c.objective != "none":
             self.objective = create_objective(c)
         if self.objective is not None:
-            self.objective.init(train_set.metadata, train_set.num_data)
-            self.num_tree_per_iteration = self.objective.num_model_per_iteration
-            if self._pr is not None:
-                # gradients compute over the GLOBAL row axis: every
-                # per-row objective array becomes row-sharded (pad rows
-                # 0), dataset-level statistics recompute globally
-                from ..io.distributed import jax_process_allgather
-                self.objective.globalize_rows(self._pr.globalize,
-                                              jax_process_allgather)
+            with obs_span("gbdt.objective"):
+                self.objective.init(train_set.metadata, train_set.num_data)
+                self.num_tree_per_iteration = \
+                    self.objective.num_model_per_iteration
+                if self._pr is not None:
+                    # gradients compute over the GLOBAL row axis: every
+                    # per-row objective array becomes row-sharded (pad
+                    # rows 0), dataset-level statistics recompute globally
+                    from ..io.distributed import jax_process_allgather
+                    self.objective.globalize_rows(self._pr.globalize,
+                                                  jax_process_allgather)
 
         K = self.num_tree_per_iteration
         # scores built host-side and device_put in one transfer: eager
@@ -485,8 +488,9 @@ class GBDT:
                 # consumes it in place instead of re-laying-out the
                 # store to the mesh (the multi-process path is already
                 # placed via make_array_from_process_local_data)
-                self.device_data = self.mesh_ctx.place_data(
-                    self.device_data)
+                with obs_span("gbdt.place"):
+                    self.device_data = self.mesh_ctx.place_data(
+                        self.device_data)
             pad = self._row_pad
             # in-program placement constraints come from the SAME
             # registry rules (grad/hess/bag row-sharded for data/
@@ -1675,8 +1679,6 @@ class GBDT:
             # compile_s / steady_s split reads exactly this)
             compiling = L not in self._block_fns
             fn = self._block_fn(L)
-            if compiling:
-                counter_add("gbdt.block_compiles")
             self._gap_dispatch_start()
             with obs_span("gbdt.block_compile" if compiling
                           else "gbdt.block", iters=nb), \

@@ -415,15 +415,17 @@ class BinnedDataset:
                           features=len(ds.used_features)):
                 cols = bin_rows(ds.mappers, X, ds.used_features,
                                 prediction_mode)
-            if ds.bundle is not None and ds.bundle.is_bundled:
-                ds.bins = pack_group_columns(cols, ds.feature_info, ds.bundle)
-            else:
-                # prediction mode's categorical miss sentinel is num_bin,
-                # which overflows uint8 when num_bin == 256
-                force_wide = (prediction_mode
-                              and ds.feature_info.max_num_bins >= 256)
-                ds.bins = cls._pack_columns(cols, ds.feature_info,
-                                            force_int32=force_wide)
+            with obs_span("io.pack"):
+                if ds.bundle is not None and ds.bundle.is_bundled:
+                    ds.bins = pack_group_columns(cols, ds.feature_info,
+                                                 ds.bundle)
+                else:
+                    # prediction mode's categorical miss sentinel is
+                    # num_bin, which overflows uint8 when num_bin == 256
+                    force_wide = (prediction_mode
+                                  and ds.feature_info.max_num_bins >= 256)
+                    ds.bins = cls._pack_columns(cols, ds.feature_info,
+                                                force_int32=force_wide)
             ds.metadata = metadata or Metadata()
             return ds
 
@@ -449,11 +451,16 @@ class BinnedDataset:
                                                 bundle_allgather is not None),
                                             bundle_allgather=bundle_allgather,
                                             rank=rank)
-        sample_cnt = min(n, config.bin_construct_sample_cnt)
-        rng = np.random.RandomState(config.data_random_seed)
-        sample_idx = (np.arange(n) if sample_cnt >= n
-                      else np.sort(rng.choice(n, sample_cnt, replace=False)))
-        ds.mappers = find_mappers_from_sample(X[sample_idx], config, cat_set)
+        # `io.find_bin` (one a feature) is this span's child: its self
+        # time is the row draw and each column's copy and filter
+        with obs_span("io.sample", rows=n):
+            sample_cnt = min(n, config.bin_construct_sample_cnt)
+            rng = np.random.RandomState(config.data_random_seed)
+            sample_idx = (np.arange(n) if sample_cnt >= n
+                          else np.sort(rng.choice(n, sample_cnt,
+                                                  replace=False)))
+            ds.mappers = find_mappers_from_sample(X[sample_idx], config,
+                                                  cat_set)
         ds.used_features = [f for f in range(num_features)
                             if not ds.mappers[f].is_trivial]
         return cls._finish_from_mappers(ds, X, config, metadata, n,
@@ -499,39 +506,52 @@ class BinnedDataset:
             n_sparse = sum(m.sparse_rate >= config.sparse_threshold
                            and m.num_bin > 1 for m in used_mappers)
             if n_sparse >= 2:
-                if bundle_allgather is None or rank == 0:
-                    feat_matrix = cls._pack_columns(cols, ds.feature_info)
-                    groups = fast_feature_bundling(
-                        feat_matrix, used_mappers, config.max_conflict_rate,
-                        config.data_random_seed, config.sparse_threshold,
-                        max_group_bins=256)
-                else:
-                    groups = None      # rank 0's proposal arrives below
-                if bundle_allgather is not None:
-                    # every eligible rank reaches this collective (the
-                    # gates above depend only on the shared mappers)
-                    proposals = bundle_allgather(
-                        [[int(f) for f in grp] for grp in groups]
-                        if groups is not None else None)
-                    groups = [[int(f) for f in grp] for grp in proposals[0]]
-                if len(groups) < len(ds.used_features):
-                    ds.bundle = build_bundle_info(
-                        groups, ds.feature_info.num_bins)
-        if ds.bundle is not None and ds.bundle.is_bundled:
-            ds.bins = pack_group_columns(cols, ds.feature_info, ds.bundle)
-            log_info(f"EFB bundled {len(ds.used_features)} features into "
-                     f"{ds.bins.shape[1]} groups")
-        else:
-            ds.bundle = None
-            # `packed` (two-round loader): cols are views of an already
-            # correctly-packed matrix — adopt it, don't copy
-            ds.bins = (packed if packed is not None
-                       else cls._pack_columns(cols, ds.feature_info))
+                with obs_span("io.efb", sparse_features=n_sparse):
+                    ds.bundle = cls._try_bundle(
+                        ds, cols, used_mappers, config, bundle_allgather,
+                        rank)
+        with obs_span("io.pack"):
+            if ds.bundle is not None and ds.bundle.is_bundled:
+                ds.bins = pack_group_columns(cols, ds.feature_info,
+                                             ds.bundle)
+                log_info(f"EFB bundled {len(ds.used_features)} features "
+                         f"into {ds.bins.shape[1]} groups")
+            else:
+                ds.bundle = None
+                # `packed` (two-round loader): cols are views of an already
+                # correctly-packed matrix — adopt it, don't copy
+                ds.bins = (packed if packed is not None
+                           else cls._pack_columns(cols, ds.feature_info))
         ds.metadata = metadata or Metadata()
         log_info(f"constructed dataset: {n} rows, "
                  f"{len(ds.used_features)}/{num_features} used features, "
                  f"{ds.feature_info.total_bins} total bins")
         return ds
+
+    @classmethod
+    def _try_bundle(cls, ds: "BinnedDataset", cols: List[np.ndarray],
+                    used_mappers: List[BinMapper], config: Config,
+                    bundle_allgather, rank: int) -> Optional[BundleInfo]:
+        """Propose feature groups (rank 0's, where ranks share one) and
+        lay them out; None where nothing bundles."""
+        if bundle_allgather is None or rank == 0:
+            feat_matrix = cls._pack_columns(cols, ds.feature_info)
+            groups = fast_feature_bundling(
+                feat_matrix, used_mappers, config.max_conflict_rate,
+                config.data_random_seed, config.sparse_threshold,
+                max_group_bins=256)
+        else:
+            groups = None      # rank 0's proposal arrives below
+        if bundle_allgather is not None:
+            # every eligible rank reaches this collective (the gates at
+            # the call depend only on the shared mappers)
+            proposals = bundle_allgather(
+                [[int(f) for f in grp] for grp in groups]
+                if groups is not None else None)
+            groups = [[int(f) for f in grp] for grp in proposals[0]]
+        if len(groups) < len(ds.used_features):
+            return build_bundle_info(groups, ds.feature_info.num_bins)
+        return None
 
     @staticmethod
     def _build_feature_info(mappers: Sequence[BinMapper]) -> FeatureInfo:

@@ -18,6 +18,13 @@ subsystem:
   trace, whoever started it, holds the spans on the device's clock
   (device time is read there, by ``jax.named_scope`` name: the block
   program's scopes carry the ``tree.*`` / ``obj.*`` span names).
+  A span's **self time** is its duration less what its direct children
+  on the same thread took: what lies under none of their names.
+* **Compile record** — while telemetry is enabled, every program JAX
+  traces, lowers or compiles is a span (``compile.trace`` /
+  ``compile.lower`` / ``compile.backend``, attribute ``fun_name``)
+  under whatever span is open on the compiling thread, and a row of the
+  summary's ``programs`` table (see :func:`_compile_end`).
 * **Counters / gauges** — ``counter_add("retry.dispatch.retries")``,
   ``gauge_set("hbm_bytes", n)``.  Counters accumulate (floats allowed:
   backoff seconds ride the same channel), gauges overwrite.
@@ -27,13 +34,13 @@ subsystem:
 Sinks:
 
 * an in-memory **run summary** queryable as a plain dict
-  (:func:`summary`): per-span count/total/max seconds, counters,
-  gauges, event counts;
+  (:func:`summary`): per-span count/total/max/self seconds, the
+  ``programs`` table, counters, gauges, event counts;
 * a **JSONL event trace**, enabled via ``LGBM_TPU_TRACE=<path>`` or the
   ``telemetry_output`` config parameter.  Every record carries ``ts``
   (wall-clock start, epoch seconds), ``kind`` (``span`` | ``counter`` |
   ``gauge`` | ``event``), ``name``, and ``rank``; span records add
-  ``dur_s`` (>= 0), ``depth``, and ``parent`` — spans are written on
+  ``dur_s`` (>= 0), ``self_s``, ``depth``, and ``parent`` — spans are written on
   CLOSE, so a parent's record follows its children's;
 * **per-rank files** in multi-host runs (the trace path gains a
   ``.rank<k>`` suffix, decided lazily at first write so enabling before
@@ -43,10 +50,12 @@ Sinks:
 
 Disabled telemetry is a guard-checked no-op — one module-attribute read
 per call site — so instrumentation stays compiled into every path,
-including per-iteration training loops and per-feature bin finding.
+including per-iteration training loops and per-feature bin finding, and
+nothing is registered with ``jax.monitoring`` until :func:`enable`.
 """
 from __future__ import annotations
 
+import importlib
 import json
 import os
 import sys
@@ -68,7 +77,7 @@ def _named_rlock(name: str):
 
 
 _lock = _named_rlock("telemetry")
-_tls = threading.local()            # per-thread span stack
+_tls = threading.local()            # per-thread stack of open spans
 
 # -- state (module-level flags keep the disabled path one attribute read)
 _enabled = False
@@ -76,7 +85,23 @@ _trace_requested: Optional[str] = None   # path asked for; file opens lazily
 _trace_file: Optional[IO[str]] = None
 _trace_open_path: Optional[str] = None
 
-_spans: Dict[str, list] = {}        # name -> [count, total_s, max_s]
+_spans: Dict[str, list] = {}        # name -> [count, total_s, max_s, self_s]
+# fun_name -> {"count", "trace_s", "lower_s", "backend_s"}: see _compile_end
+_programs: Dict[str, Dict[str, float]] = {}
+_PROGRAMS_MAX = 256                 # names; the rest share "(other)"
+# jax.monitoring's names of the three compile phases -> (span, column)
+_COMPILE_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": ("compile.trace", "trace_s"),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration":
+        ("compile.lower", "lower_s"),
+    "/jax/core/compile/backend_compile_duration":
+        ("compile.backend", "backend_s"),
+}
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "compile.cache_hits",
+    "/jax/compilation_cache/cache_misses": "compile.cache_misses",
+}
+_listening = False                  # the listeners below are registered
 _counters: Dict[str, float] = {}
 _gauges: Dict[str, Any] = {}
 _events: Dict[str, int] = {}
@@ -172,6 +197,7 @@ def enable(trace_path: Optional[str] = None) -> None:
         _enabled = True
         if trace_path:
             _trace_requested = trace_path
+    _listen()
 
 
 def disable() -> None:
@@ -200,12 +226,14 @@ def reset() -> None:
         _clk_off = None
         _rank_override = None
         _spans.clear()
+        _programs.clear()
         _counters.clear()
         _gauges.clear()
         _events.clear()
         _sections.clear()
         if getattr(_tls, "stack", None):
             _tls.stack = []
+    _unlisten()
     from . import flight_recorder
     flight_recorder.reset()
     from . import profiler
@@ -319,26 +347,42 @@ class _NoopSpan:
 _NOOP_SPAN = _NoopSpan()
 
 
+def _program_row(fun: str) -> Dict[str, float]:
+    """The ``programs`` table's row of ``fun``, made where it is new;
+    caller holds ``_lock``."""
+    row = _programs.get(fun)
+    if row is None:
+        if len(_programs) >= _PROGRAMS_MAX:
+            fun = "(other)"
+        row = _programs.setdefault(fun, {
+            "count": 0, "trace_s": 0.0, "lower_s": 0.0, "backend_s": 0.0})
+    return row
+
+
 class _Span:
-    __slots__ = ("name", "attrs", "t0", "ts", "depth", "ann")
+    __slots__ = ("name", "attrs", "t0", "ts", "depth", "ann", "children_s")
 
     def __init__(self, name: str, attrs: Dict[str, Any]):
         self.name = name
         self.attrs = attrs
 
-    def __enter__(self):
+    def _open(self) -> None:
+        """Onto this thread's stack, and onto the profiler's clock,
+        whoever started the trace: a TraceAnnotation costs tens of
+        nanoseconds while no profiler session is live.  A process that
+        never imported jax has no session to annotate and compiles
+        nothing."""
         stack = getattr(_tls, "stack", None)
         if stack is None:
             stack = _tls.stack = []
         self.depth = len(stack)
-        stack.append(self.name)
-        # the span on the profiler's clock, whoever started the trace:
-        # a TraceAnnotation costs tens of nanoseconds while no profiler
-        # session is live.  A process that never imported jax has no
-        # session to annotate
+        self.children_s = 0.0
+        stack.append(self)
         self.ann = None
         jx = sys.modules.get("jax")
         if jx is not None:
+            if not _listening:
+                _listen()
             try:
                 ann = jx.profiler.TraceAnnotation(self.name)
                 ann.__enter__()
@@ -347,12 +391,8 @@ class _Span:
             # profiler hiccup must not take the training span down
             except Exception:           # noqa: BLE001
                 pass
-        self.ts = time.time()
-        self.t0 = time.perf_counter()
-        return self.attrs
 
-    def __exit__(self, *exc):
-        dur = time.perf_counter() - self.t0
+    def _leave(self, exc) -> None:
         if self.ann is not None:
             try:
                 self.ann.__exit__(*exc)
@@ -360,31 +400,61 @@ class _Span:
             except Exception:           # noqa: BLE001
                 pass
             self.ann = None
-        stack = _tls.stack
-        parent = ""
-        if stack and stack[-1] is self.name:
+
+    def _close(self, dur: float, exc=(None, None, None),
+               column: Optional[str] = None) -> None:
+        """Off the stack (with whatever a compile phase that never
+        ended left above), the parent credited, the summary and the
+        trace written.  ``column``: see :func:`_compile_end`."""
+        self._leave(exc)
+        stack = getattr(_tls, "stack", None) or []
+        if any(f is self for f in stack):
+            while stack[-1] is not self:
+                stack.pop()._leave(exc)
             stack.pop()
-            parent = stack[-1] if stack else ""
+        parent = ""
+        if stack:
+            stack[-1].children_s += dur
+            parent = stack[-1].name
+        self_s = max(dur - self.children_s, 0.0)
         rank, _ = _rank_world()
         with _lock:
             agg = _spans.get(self.name)
             if agg is None:
-                agg = _spans[self.name] = [0, 0.0, 0.0]
+                agg = _spans[self.name] = [0, 0.0, 0.0, 0.0]
             agg[0] += 1
             agg[1] += dur
             if dur > agg[2]:
                 agg[2] = dur
+            agg[3] += self_s
+            if column is not None:
+                _program_row(self.attrs["fun_name"])[column] += dur
             sink = _sink
             if sink is not None:
                 sink.span(self.name, dur)
             if _trace_requested:
                 rec = {"ts": self.ts, "kind": "span", "name": self.name,
-                       "rank": rank, "dur_s": dur, "depth": self.depth,
-                       "parent": parent}
+                       "rank": rank, "dur_s": dur, "self_s": self_s,
+                       "depth": self.depth, "parent": parent}
                 if self.attrs:
                     rec.update(self.attrs)
                 _trace_write(rec)
+
+    def __enter__(self):
+        self._open()
+        self.ts = time.time()
+        self.t0 = time.perf_counter()
+        return self.attrs
+
+    def __exit__(self, *exc):
+        self._close(time.perf_counter() - self.t0, exc)
         return False
+
+
+class _Phase(_Span):
+    """A compile phase as JAX reports it; ``nested``: inside another
+    phase on its thread."""
+    __slots__ = ("nested",)
 
 
 def span(name: str, **attrs):
@@ -394,6 +464,91 @@ def span(name: str, **attrs):
     if not _enabled:
         return _NOOP_SPAN
     return _Span(name, attrs)
+
+
+# ---------------------------------------------------------------------------
+# the compile record
+# ---------------------------------------------------------------------------
+def _listen() -> None:
+    """Register the three listeners with ``jax.monitoring``, once, and
+    only where jax is already imported (``_rank_world``'s rule: this
+    module never imports it); :func:`enable` before ``import jax``
+    leaves it to the first span that finds jax."""
+    global _listening
+    if _listening or not _enabled or "jax" not in sys.modules:
+        return
+    with _lock:
+        if _listening:
+            return
+        mon = importlib.import_module("jax.monitoring")
+        mon.register_scalar_listener(_compile_start)
+        mon.register_event_duration_secs_listener(_compile_end)
+        mon.register_event_listener(_cache_event)
+        _listening = True
+
+
+def _unlisten() -> None:
+    global _listening
+    with _lock:
+        if not _listening:
+            return
+        mon = sys.modules["jax.monitoring"]
+        mon.unregister_scalar_listener(_compile_start)
+        mon.unregister_event_duration_listener(_compile_end)
+        mon.unregister_event_listener(_cache_event)
+        _listening = False
+
+
+def _open_phase(name: str, ts: float) -> "_Phase":
+    phase = _Phase(name, {})
+    phase.nested = any(isinstance(f, _Phase)
+                       for f in getattr(_tls, "stack", None) or ())
+    phase._open()
+    phase.ts = ts
+    return phase
+
+
+def _compile_start(event: str, start_time: float, **kw) -> None:
+    """JAX's ``record_scalar`` at the entry of a compile phase: open a
+    span on the compiling thread."""
+    if _enabled and event in _COMPILE_PHASES:
+        _open_phase(_COMPILE_PHASES[event][0], start_time)
+
+
+def _compile_end(event: str, dur: float, **kw) -> None:
+    """JAX's duration at the exit of a compile phase: close the span
+    :func:`_compile_start` opened, a child of whatever is open beneath
+    it on this thread.
+
+    The ``programs`` table holds a row per program: the ``fun_name`` of
+    a phase that runs inside no other phase on its thread (lowering
+    says ``jit(f)`` where tracing says ``f``: the row is ``f``).  Its
+    ``trace_s`` / ``lower_s`` / ``backend_s`` are those phases whole: a
+    jit traced inside the trace or the lowering of another is part of
+    the program it is traced into and has no row, so no second stands
+    in two rows.  ``count`` is XLA compilations, nested ones too."""
+    if not _enabled or event not in _COMPILE_PHASES:
+        return
+    name, column = _COMPILE_PHASES[event]
+    phase = next((f for f in reversed(getattr(_tls, "stack", None) or ())
+                  if isinstance(f, _Phase) and f.name == name), None)
+    if phase is None:
+        # enabled in the middle of the phase: a leaf, under what is open
+        phase = _open_phase(name, time.time() - dur)
+    fun = str(kw.get("fun_name", "?"))
+    if fun.startswith("jit(") and fun.endswith(")"):
+        fun = fun[4:-1]
+    phase.attrs["fun_name"] = fun
+    if column == "backend_s":
+        with _lock:
+            _program_row(fun)["count"] += 1
+    phase._close(float(dur), column=None if phase.nested else column)
+
+
+def _cache_event(event: str, **kw) -> None:
+    name = _CACHE_EVENTS.get(event)
+    if name is not None:
+        counter_add(name)
 
 
 # ---------------------------------------------------------------------------
@@ -476,8 +631,10 @@ def summary() -> Dict[str, Any]:
         out = {
             "rank": rank,
             "process_count": world,
-            "spans": {k: {"count": v[0], "total_s": v[1], "max_s": v[2]}
+            "spans": {k: {"count": v[0], "total_s": v[1], "max_s": v[2],
+                          "self_s": v[3]}
                       for k, v in _spans.items()},
+            "programs": {k: dict(v) for k, v in _programs.items()},
             "counters": dict(_counters),
             "gauges": dict(_gauges),
             "events": dict(_events),
@@ -496,8 +653,8 @@ def merged_summary(allgather) -> Dict[str, Any]:
     """Every rank's summary merged into one dict (identical on all
     ranks — ``allgather`` is the host-collective seam, normally
     ``io.distributed.jax_process_allgather``).  ``ranks`` keeps each
-    rank's full summary; ``counters``/``events`` sum and ``spans``
-    combine across ranks.  The per-rank ``flight_recorder`` sections
+    rank's full summary; ``counters``/``events``/``programs`` sum and
+    ``spans`` combine across ranks.  The per-rank ``flight_recorder`` sections
     are cross-checked here: a schedule desync lands in
     ``flight_recorder_check`` naming the first diverging site+rank."""
     locals_ = allgather(summary())
@@ -505,6 +662,7 @@ def merged_summary(allgather) -> Dict[str, Any]:
         "process_count": len(locals_),
         "ranks": locals_,
         "spans": {},
+        "programs": {},
         "counters": {},
         "events": {},
     }
@@ -515,10 +673,15 @@ def merged_summary(allgather) -> Dict[str, Any]:
             merged["events"][k] = merged["events"].get(k, 0) + v
         for k, v in s.get("spans", {}).items():
             agg = merged["spans"].setdefault(
-                k, {"count": 0, "total_s": 0.0, "max_s": 0.0})
+                k, {"count": 0, "total_s": 0.0, "max_s": 0.0, "self_s": 0.0})
             agg["count"] += v["count"]
             agg["total_s"] += v["total_s"]
             agg["max_s"] = max(agg["max_s"], v["max_s"])
+            agg["self_s"] += v.get("self_s", 0.0)
+        for k, v in s.get("programs", {}).items():
+            row = merged["programs"].setdefault(k, dict.fromkeys(v, 0))
+            for col, n in v.items():
+                row[col] += n
     from . import flight_recorder
     check = flight_recorder.cross_check_summaries(locals_)
     if check is not None:

@@ -53,10 +53,10 @@ LOCKS: Tuple[Dict, ...] = (
     {"name": "telemetry", "module": "lightgbm_tpu/obs/telemetry.py",
      "cls": None, "attr": "_lock", "kind": "rlock",
      "guards": ("_enabled", "_trace_requested", "_trace_file",
-                "_trace_open_path", "_spans", "_counters", "_gauges",
-                "_events", "_sections", "_held"),
+                "_trace_open_path", "_spans", "_programs", "_counters",
+                "_gauges", "_events", "_sections", "_held"),
      # "Caller holds _lock" is these helpers' documented contract
-     "assume_held": ("_trace_write",)},
+     "assume_held": ("_trace_write", "_program_row")},
     # MetricsRegistry is the telemetry SINK: leaf-level by design —
     # taken inside the telemetry lock on the write path (see ORDER)
     {"name": "metrics_registry", "module": "lightgbm_tpu/obs/ops_plane.py",

@@ -10,8 +10,10 @@ Reads trace files written via ``LGBM_TPU_TRACE=<path>`` or the
 ``telemetry_output`` config parameter (multi-host runs write one
 ``<path>.rank<k>`` file per rank — pass them all to merge).  Prints:
 
-* per-span phase breakdown (count, total seconds, share of the summed
-  span time at that nesting depth, max single duration),
+* per-span phase breakdown (count, total seconds, self seconds — the
+  span's time less its child spans': what lies under none of their
+  names —, share of the summed span time at that nesting depth, max
+  single duration),
 * counters (retry attempts/backoff, snapshot bytes, compile counts...),
 * one-shot events (faults fired, early stopping).
 
@@ -79,7 +81,8 @@ def health_block(events, counters, state=None, ranks=None,
 
 
 def report(records, out=sys.stdout):
-    spans = defaultdict(lambda: [0, 0.0, 0.0, 0])   # count,total,max,min_depth
+    # count, total, max, min_depth, self
+    spans = defaultdict(lambda: [0, 0.0, 0.0, 0, 0.0])
     counters = {}
     events = defaultdict(int)
     ranks = set()
@@ -93,6 +96,8 @@ def report(records, out=sys.stdout):
             agg[2] = max(agg[2], r.get("dur_s", 0.0))
             agg[3] = min(agg[3], r.get("depth", 0)) if agg[0] > 1 \
                 else r.get("depth", 0)
+            # a trace from before spans carried it: all of the span
+            agg[4] += r.get("self_s", r.get("dur_s", 0.0))
         elif kind == "counter":
             counters[r["name"]] = r.get("value", 0)
         elif kind == "event":
@@ -102,14 +107,14 @@ def report(records, out=sys.stdout):
     print(f"ranks: {sorted(ranks)}    depth-0 span time: {wall:.3f}s",
           file=out)
     print(f"\n{'phase':<28s} {'count':>7s} {'total_s':>10s} "
-          f"{'share':>7s} {'max_s':>9s}", file=out)
-    print("-" * 64, file=out)
-    for name, (cnt, total, mx, depth) in sorted(
+          f"{'self_s':>10s} {'share':>7s} {'max_s':>9s}", file=out)
+    print("-" * 75, file=out)
+    for name, (cnt, total, mx, depth, self_s) in sorted(
             spans.items(), key=lambda kv: -kv[1][1]):
         share = f"{100.0 * total / wall:5.1f}%" if depth == 0 else "     -"
         indent = "  " * depth
         print(f"{indent + name:<28s} {cnt:>7d} {total:>10.3f} "
-              f"{share:>7s} {mx:>9.3f}", file=out)
+              f"{self_s:>10.3f} {share:>7s} {mx:>9.3f}", file=out)
     if counters:
         print("\ncounters:", file=out)
         for name in sorted(counters):
@@ -163,6 +168,31 @@ def collective_skew_block(sk, out=sys.stdout):
                   file=out)
 
 
+def programs_block(programs, out=sys.stdout, top=12):
+    """The compile record: what JAX traced, lowered and compiled, by
+    program name, the dearest first; the rest as one line."""
+    if not programs:
+        return
+    cols = ("trace_s", "lower_s", "backend_s")
+    rows = sorted(programs.items(),
+                  key=lambda kv: -sum(kv[1][c] for c in cols))
+    print(f"\n{'program':<28s} {'compiled':>8s} {'trace_s':>9s} "
+          f"{'lower_s':>9s} {'backend_s':>10s}", file=out)
+    print("-" * 68, file=out)
+    rest = {"count": 0, **dict.fromkeys(cols, 0.0)}
+    for i, (name, v) in enumerate(rows):
+        if i < top:
+            print(f"{name:<28s} {v['count']:>8d} {v['trace_s']:>9.3f} "
+                  f"{v['lower_s']:>9.3f} {v['backend_s']:>10.3f}", file=out)
+        else:
+            for c in rest:
+                rest[c] += v[c]
+    if len(rows) > top:
+        print(f"{f'({len(rows) - top} more)':<28s} {rest['count']:>8d} "
+              f"{rest['trace_s']:>9.3f} {rest['lower_s']:>9.3f} "
+              f"{rest['backend_s']:>10.3f}", file=out)
+
+
 def report_summary(s, out=sys.stdout):
     """Host-side span table from a summary dict, then the device-time
     attribution section when the run was profiled."""
@@ -170,12 +200,14 @@ def report_summary(s, out=sys.stdout):
     total = sum(v.get("total_s", 0.0) for v in spans.values()) or 1.0
     print(f"summary: rank {s.get('rank', '?')} / "
           f"{s.get('process_count', '?')} process(es)", file=out)
-    print(f"\n{'span':<28s} {'count':>7s} {'total_s':>10s} {'max_s':>9s}",
-          file=out)
-    print("-" * 58, file=out)
+    print(f"\n{'span':<28s} {'count':>7s} {'total_s':>10s} {'self_s':>10s} "
+          f"{'max_s':>9s}", file=out)
+    print("-" * 69, file=out)
     for name, v in sorted(spans.items(), key=lambda kv: -kv[1]["total_s"]):
         print(f"{name:<28s} {v['count']:>7d} {v['total_s']:>10.3f} "
-              f"{v['max_s']:>9.3f}", file=out)
+              f"{v.get('self_s', v['total_s']):>10.3f} {v['max_s']:>9.3f}",
+              file=out)
+    programs_block(s.get("programs"), out=out)
     # Health section: a single-rank summary carries its own `health`
     # state; a merged multi-rank summary carries the per-rank lift
     # (telemetry.merged_summary) — both render here
