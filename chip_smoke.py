@@ -171,14 +171,19 @@ def say_tiling(summary) -> None:
     gauges: ``hist.tiling.<cols>`` = ``<feat_tile>x<row tile>``, the
     largest share of padded features, ``hist.feature_pad_pct``, the
     waves' slot counts, ``hist.wave_slots`` = ``<staged waves>|<tail>``,
-    and the int32 partials a call sums its rows in, ``hist.row_chunks``
-    (1 here: 4 at the 53.1M rows of ``criteo-67-b63-c32.train``)."""
+    the staged waves whose route runs inside their histogram call,
+    ``hist.fused_waves`` (``1,2,3,4,5,6,7`` here and on the 13.28M-row
+    cells, ``-`` on ``-dp4`` / ``-c32``), and the int32 partials a call
+    sums its rows in, ``hist.row_chunks`` (1 here: 4 at the 53.1M rows
+    of ``criteo-67-b63-c32.train``)."""
     tiling = {k: v for k, v in sorted(summary["gauges"].items())
               if k.startswith("hist.")}
     say(f"histogram grids: {tiling}")
     check(any(k.startswith("hist.tiling.") for k in tiling),
           "no hist.tiling.<cols> gauge: no histogram kernel was traced")
     check("hist.wave_slots" in tiling, "no hist.wave_slots gauge")
+    say(f"fused route+histogram waves: {tiling.get('hist.fused_waves')}")
+    check("hist.fused_waves" in tiling, "no hist.fused_waves gauge")
     check(tiling.get("hist.row_chunks") == 1,
           f"hist.row_chunks is {tiling.get('hist.row_chunks')!r}, not 1")
 

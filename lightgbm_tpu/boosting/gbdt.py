@@ -626,10 +626,14 @@ class GBDT:
         ``"<feat_tile>x<row tile>"``, ``hist.feature_pad_pct``, the
         largest share of all-zero features a wave contracts,
         ``hist.wave_slots``, the staged waves' slot counts and the
-        tail's (``"8,8,8,8,8,16,32,64|128"`` at 255 leaves), and
+        tail's (``"8,8,8,8,8,16,32,64|128"`` at 255 leaves),
+        ``hist.fused_waves``, the staged waves whose route runs inside
+        their histogram call (``"1,2,3,4,5,6,7"``; ``"-"``: none), by the
+        rule ``build_tree`` takes (``wave_backend_plan``), and
         ``hist.row_chunks``, the int32 partials a call sums ``rows`` in
         (1 but for a quantized mode past the rows one cell holds)."""
-        from ..learner.serial import shard_row_chunks, stage_plan
+        from ..learner.serial import (shard_row_chunks, stage_plan,
+                                      wave_backend_plan)
         from ..obs import gauge_set
         from ..ops.pallas_histogram import DEFAULT_ROW_TILE
         from ..ops.vmem import (bin_stride, col_layout, hist_tiling,
@@ -649,6 +653,14 @@ class GBDT:
             gauge_set(f"hist.tiling.{cols}", f"{feat_tile}x{T}")
             F_widest = max(F_widest, F_grid)
         gauge_set("hist.feature_pad_pct", 100.0 * (F_widest - F) / F)
+        waves, _, _ = wave_backend_plan(
+            self.growth.num_leaves, self.growth.wave_size,
+            num_groups=F, max_bins=dd.group_max_bins, mode=hist_mode,
+            n_rows=int(rows), serial=self.mesh_ctx is None,
+            any_cat=dd.has_categorical)
+        gauge_set("hist.fused_waves", ",".join(
+            str(i) for i, w in enumerate(waves) if w.choice == "fused")
+            or "-")
         gauge_set("hist.row_chunks",
                   shard_row_chunks(int(rows)) if is_quantized(hist_mode)
                   else 1)

@@ -58,6 +58,7 @@ from ..ops.pallas_histogram import (DEFAULT_ROW_TILE, INT8_ROW_LIMIT,
 from ..ops.pallas_route import (route_rows_pallas, route_rows_values_pallas,
                                 route_rows_xla)
 from ..ops.split import SplitParams, SplitResult, find_best_splits
+from ..ops.vmem import round_up as _round_up
 
 NEG_INF = -1e30
 
@@ -308,16 +309,30 @@ def stage_plan(L: int, wave_size: int = 0):
     Leaf-wise mode (``wave_size=1``) splits one leaf per wave, so
     everything runs in a narrow while loop instead.
     """
+    plan, _, A_tail = _stage_walk(L, wave_size)
+    return plan, A_tail
+
+
+def _stage_walk(L: int, wave_size: int):
+    """The worst case :func:`stage_plan` walks, the leaves doubling up
+    to ``L``: ``-> (plan, route_leaves, A_tail)``.  ``route_leaves``:
+    the most leaves a tree's rows lie in when each unrolled wave routes
+    them, the leaves there were when the wave before selected its
+    splits (1 for the root wave, which has none to apply; 1, 1, 2, 4,
+    ..., 64 at 255 leaves).  Only a leaf below that holds rows or a
+    pending split, so a route table this wide is the whole table.  The
+    ``while`` tail's is ``L``."""
     if wave_size == 1:
-        return [], 8
+        return [], [], 8
     A_tail = min(_round8(max(1, L // 2)), 128)
-    plan = []
+    plan, route_leaves = [], []
     leaves, handed = 1, 1       # the root wave histograms the one leaf
     while leaves < L and len(plan) < 32:
         plan.append(min(_round8(handed), A_tail))
+        route_leaves.append(handed)
         handed = leaves         # a smaller child for every leaf it splits
         leaves += min(leaves, A_tail)
-    return plan, A_tail
+    return plan, route_leaves, A_tail
 
 
 def _empty_best(L: int, B: int) -> SplitResult:
@@ -349,15 +364,55 @@ def _pallas_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+class Wave(NamedTuple):
+    """One unrolled wave of :func:`wave_backend_plan`."""
+    slots: int          # the wave's histogram slots (``stage_plan``)
+    route_leaves: int   # the most leaves its rows lie in when it routes
+    choice: str         # "fused", or the backend: route, then histogram
+
+
 def wave_backend_plan(L: int, wave_size: int = 0, backend: str = "pallas",
-                      fused_ok: bool = True):
-    """Per-wave kernel choice for a stage plan: ``-> (choices, tail)``
-    with entries "fused" / "<backend>", the same in every wave.  Pure
-    mirror of the dispatch :func:`build_tree` applies, exposed so tests
-    can pin the selection without tracing a tree build."""
-    plan, A_tail = stage_plan(L, wave_size)
-    choice = "fused" if uses_pallas(backend) and fused_ok else backend
-    return [choice] * len(plan), choice
+                      *, num_groups: int, max_bins: int, mode: str,
+                      n_rows: int, serial: bool = True,
+                      any_cat: bool = False):
+    """The waves of a tree of ``L`` leaves over ``n_rows`` rows and each
+    one's histogram call: ``-> (waves, A_tail, tail)``, a :class:`Wave`
+    for each wave :func:`build_tree` unrolls (none off the Pallas path,
+    nor below ``_COMPILE_LEAN_ROWS`` rows), the ``while`` tail's slots
+    and its call.  ``"fused"``: the wave's route runs inside its
+    histogram call (``hist_route_pallas``); ``backend``: the route
+    kernel, then the histogram call.  A wave is fused where it has a
+    route to apply (not the root wave), the learner is the serial one
+    (``serial``: no strategy, no exchange), the backend the Pallas one,
+    and ``fused_config_ok`` admits the whole feature set in one tile at
+    that wave's slots with its route's leaves.  Static, from shapes:
+    :func:`build_tree` takes its dispatch from here and
+    ``GBDT._record_tiling`` its gauge ``hist.fused_waves``."""
+    if not uses_pallas(backend):
+        # staged waves only pay off on the Pallas path (MXU cost ∝
+        # slots); the scatter backend compiles one while-loop body
+        # instead (8 unrolled stages × shard_map × 3 learners is minutes
+        # of XLA-CPU compile time)
+        return [], _round8(max(1, L // 2)), backend
+    plan, route_leaves, A_tail = _stage_walk(L, wave_size)
+    # compile-lean: on small datasets the staged unrolled waves buy
+    # nothing (MXU cost ∝ slots×n is trivial) but multiply HLO size ~7x
+    # — and XLA compile time, not FLOPs, dominates small-data cold
+    # starts (~30 s vs ~1.5 s of device work for 100 iterations).  One
+    # full-width while-loop body compiles once and runs the same wave
+    # sequence.
+    if n_rows <= _COMPILE_LEAN_ROWS and wave_size != 1:
+        plan = []
+    n_pad = _round_up(n_rows, DEFAULT_ROW_TILE)
+
+    def choice(slots, leaves):
+        ok = serial and fused_config_ok(
+            num_groups, max_bins, L, mode, n_pad, _INT8_ROW_LIMIT,
+            slots=slots, route_leaves=leaves, any_cat=any_cat)
+        return "fused" if ok else backend
+    waves = [Wave(A, leaves, backend if i == 0 else choice(A, leaves))
+             for i, (A, leaves) in enumerate(zip(plan, route_leaves))]
+    return waves, A_tail, choice(A_tail, L)
 
 
 def resolve_backend(data: DeviceData, num_leaf_slots: int,
@@ -732,12 +787,14 @@ def apply_hist_wave(hist_state, new_h, act_small, act_parent, act_sibling,
 def make_fused_fn(data: DeviceData, grad, hess, hist_mode: str,
                   bins_t: jnp.ndarray,
                   scales: Optional[jnp.ndarray] = None):
-    """Fused route+hist closure ``(leaf2, best, sel, new_id, active) ->
-    (new_h, leaf2_new)`` — one bins stream per wave instead of two."""
+    """Fused route+hist closure ``(leaf2, best, sel, new_id, active,
+    route_leaves) -> (new_h, leaf2_new)`` — one bins stream per wave
+    instead of two; ``route_leaves`` (static) from
+    :func:`wave_backend_plan`."""
     vals, scales = _pack(grad, hess, hist_mode, scales)
     interp = _pallas_interpret()
 
-    def fused(leaf2, best: SplitResult, sel, new_id, active):
+    def fused(leaf2, best: SplitResult, sel, new_id, active, route_leaves):
         with jax.named_scope("tree.hist"):
             h, leaf2_new = hist_route_pallas(
                 bins_t, vals, leaf2, active,
@@ -747,7 +804,7 @@ def make_fused_fn(data: DeviceData, grad, hess, hist_mode: str,
                 data.feat_group, data.feat_offset, data.num_bins, scales,
                 num_features=data.num_groups, max_bins=data.group_max_bins,
                 mode=hist_mode, any_cat=data.has_categorical,
-                interpret=interp)
+                interpret=interp, route_leaves=route_leaves)
         return h, leaf2_new
     return fused
 
@@ -893,42 +950,29 @@ def build_tree(data: DeviceData,
     if uses_pallas(backend) and bins_t is None:
         bins_t = transpose_bins(data.bins)
 
-    # staged waves only pay off on the Pallas path (MXU cost ∝ slots);
-    # the scatter backend compiles one while-loop body instead (8 unrolled
-    # stages × shard_map × 3 learners is minutes of XLA-CPU compile time)
-    if uses_pallas(backend):
-        plan, A_tail = stage_plan(L, params.wave_size)
-        # compile-lean: on small datasets the staged unrolled waves buy
-        # nothing (MXU cost ∝ slots×n is trivial) but multiply HLO size
-        # ~7x — and XLA compile time, not FLOPs, dominates small-data
-        # cold starts (~30 s vs ~1.5 s of device work for 100
-        # iterations).  One full-width while-loop body compiles once and
-        # runs the same wave sequence.
-        if n <= _COMPILE_LEAN_ROWS and params.wave_size != 1:
-            plan = []
-    else:
-        plan, A_tail = [], _round8(max(1, L // 2))
+    # the staged waves and each one's histogram call; fused
+    # route+hist, judged a wave at a time: one bins stream for a wave
+    # whose own call holds every stored column in one tile (serial
+    # Pallas path); the others route, then histogram
+    waves, A_tail, tail_choice = wave_backend_plan(
+        L, params.wave_size, backend, num_groups=data.num_groups,
+        max_bins=data.group_max_bins, mode=mode, n_rows=n,
+        serial=strategy is None and psum_fn is None,
+        any_cat=data.has_categorical)
     wave_cap = params.wave_size if params.wave_size > 0 else L
     # the final route can emit per-row leaf values (gather-free score
     # update) on the serial Pallas path and, a shard's own rows, on the
     # data-parallel one (the leaf values are the same on every shard) —
     # captured BEFORE the serial strategy closure is assigned below
     emit_values = emits_row_values(strategy is None, backend)
-    # fused route+hist: one bins stream per wave (serial Pallas path with
-    # every stored column in a single kernel tile);
-    # LGBM_TPU_NO_FUSED=1 forces the unfused path (A/B debugging)
-    import os as _os
-    fused = (strategy is None and psum_fn is None and uses_pallas(backend)
-             and not _os.environ.get("LGBM_TPU_NO_FUSED")
-             and fused_config_ok(bins_t.shape[0], data.group_max_bins, L,
-                                 mode, bins_t.shape[1], _INT8_ROW_LIMIT))
-    fused_fn = (make_fused_fn(data, grad, hess, mode, bins_t, scales)
-                if fused else None)
-    if strategy is None and not fused:
+    if strategy is None:
         strategy = make_serial_strategy(data, grad, hess, params,
                                         feature_mask, psum_fn=psum_fn,
                                         backend=backend, bins_t=bins_t,
                                         hist_mode=hist_mode, scales=scales)
+    fused_fn = (make_fused_fn(data, grad, hess, mode, bins_t, scales)
+                if "fused" in (*(w.choice for w in waves), tail_choice)
+                else None)
     route_fn = make_route_fn(data, backend, bins_t)
 
     def scan_changed(hist_state, new_h, s, lsg, lsh, lc):
@@ -936,19 +980,20 @@ def build_tree(data: DeviceData,
                               s.act_small, s.act_parent, s.act_sibling,
                               lsg, lsh, lc)
 
-    A0 = plan[0] if plan else A_tail
+    A0 = waves[0].slots if waves else A_tail
     with jax.named_scope("tree.init"):
         state = _init_state(data, grad, hess, params, bag_mask, psum_fn,
                             backend, bins_t, num_hist_features, A0, mode,
                             scales)
 
-    def body(s: _WaveState, A_out: int) -> _WaveState:
+    def body(s: _WaveState, A_out: int, choice: str,
+             leaves: int) -> _WaveState:
         # --- 0-3: apply last wave's pending splits to the rows, then
         # histogram the active leaves, subtract siblings, rescan.  The
         # fused kernel does the route inside the histogram's bins stream.
-        if fused:
+        if choice == "fused":
             new_h, leaf2 = fused_fn(s.leaf2, s.best, s.pend_sel,
-                                    s.pend_new, s.act_small)
+                                    s.pend_new, s.act_small, leaves)
             hist_state, ids, res = scan_changed(
                 s.hist_state, new_h, s, s.leaf_sum_grad, s.leaf_sum_hess,
                 s.leaf_count)
@@ -964,15 +1009,16 @@ def build_tree(data: DeviceData,
                                params, wave_cap)
 
     # --- staged unrolled waves (slot counts track the growing tree) -----
-    for i, A_in in enumerate(plan):
-        A_out = plan[i + 1] if i + 1 < len(plan) else A_tail
-        state = body(state, A_out)
+    for i, w in enumerate(waves):
+        A_out = waves[i + 1].slots if i + 1 < len(waves) else A_tail
+        state = body(state, A_out, w.choice, w.route_leaves)
 
     # --- while-loop tail at fixed slot count -----------------------------
     def cond(s: _WaveState):
         return (~s.done) & (s.nl < L)
 
-    final = jax.lax.while_loop(cond, lambda s: body(s, A_tail), state)
+    final = jax.lax.while_loop(
+        cond, lambda s: body(s, A_tail, tail_choice, L), state)
     # apply the last wave's pending splits before reading row_leaf; on the
     # Pallas path the same pass emits each row's leaf value (in place of
     # the score update's lv[row_leaf] gather)

@@ -72,7 +72,7 @@ from jax.experimental.pallas import tpu as pltpu
 # caps) is SHARED across every Pallas kernel — ops/vmem.py is its home
 # (and what tools/memcheck's MEM004 keys on); the old underscore names
 # stay bound here for the kernels and tests that grew up on them
-from .vmem import (feat_tile_cap as _feat_tile_cap, hist_cell_ok,
+from .vmem import (VMEM_BUDGET_BYTES, cell_vmem_bytes, hist_cell_ok,
                    hist_tiling, round_up as _round_up)
 
 LANE = 128
@@ -786,87 +786,35 @@ def _hist_route_kernel(active_ref, bins_ref, vals_ref, leaf2_ref, rtabs_ref,
                        tab_prec, any_cat: bool = True):
     """Apply the previous wave's pending splits to the leaf vectors, then
     histogram the active leaves — both from ONE VMEM-resident bins tile.
-    The route logic matches ``ops/pallas_route.py`` (same table layout)."""
-    from .pallas_route import (_T_GROUP, _T_THR, _T_DL, _T_ISCAT, _T_SEL,
-                               _T_NEWID, _T_OFF, _T_NB, _T_DB, _T_MT,
-                               _T_NANB)
-    from ..io.binning import MISSING_NAN, MISSING_ZERO
+    The route is ``ops/pallas_route.py``'s (its table, selection and
+    decision); the split feature's value is read from the same int32
+    tile the one-hot is built from, by an int32 masked sublane sum
+    (exact: bins < 256), so no f32 copy of the tile is made."""
+    from .pallas_route import _route_apply, _route_select
     rt = pl.program_id(0)
 
     @pl.when(rt == 0)
     def _():
         out_ref[:] = jnp.zeros_like(out_ref)
 
-    binsf32 = bins_ref[:].astype(jnp.int32).astype(jnp.float32)  # [G, T]
-    G_pad, T = binsf32.shape
-    L_pad = rtabs_ref.shape[1]
+    bins = bins_ref[:].astype(jnp.int32)                      # [G, T]
+    G_pad, T = bins.shape
 
     # ---- route (previous wave's pending splits) -----------------------
     leaf = leaf2_ref[0:1, :]
-    iota_l = jax.lax.broadcasted_iota(jnp.int32, (L_pad, T), 0)
-    from .pallas_route import selection_dtype
-    sel_dt = selection_dtype(tab_prec)
-    ohL = (iota_l == leaf).astype(sel_dt)
-    # tab_prec (pallas_route.table_precision): bf16-exact configs use the
-    # single default pass; ids past 256 need HIGHEST (the cat dot's 0/1
-    # operands are exact at default precision)
-    sel16 = jnp.dot(rtabs_ref[:].astype(sel_dt), ohL,
-                    preferred_element_type=jnp.float32,
-                    precision=tab_prec)
-    g_row = sel16[_T_GROUP:_T_GROUP + 1, :]
-    thr = sel16[_T_THR:_T_THR + 1, :]
-    dl = sel16[_T_DL:_T_DL + 1, :]
-    iscat = sel16[_T_ISCAT:_T_ISCAT + 1, :]
-    selm = sel16[_T_SEL:_T_SEL + 1, :]
-    new_id = sel16[_T_NEWID:_T_NEWID + 1, :]
-    off = sel16[_T_OFF:_T_OFF + 1, :]
-    nb = sel16[_T_NB:_T_NB + 1, :]
-    db = sel16[_T_DB:_T_DB + 1, :]
-    mt = sel16[_T_MT:_T_MT + 1, :]
-    nanb = sel16[_T_NANB:_T_NANB + 1, :]
-
-    iota_g = jax.lax.broadcasted_iota(
-        jnp.int32, (G_pad, T), 0).astype(jnp.float32)
-    ohG = jnp.where(iota_g == g_row, 1.0, 0.0)
-    c = jnp.sum(ohG * binsf32, axis=0, keepdims=True)
-
-    one = jnp.ones_like(c)
-    zero = jnp.zeros_like(c)
-    rank = c - off
-    gt_db = jnp.where(rank >= db, one, zero)
-    in_range = jnp.where((rank >= 0) & (rank < nb - 1), one, zero)
-    b_bundled = jnp.where(in_range > 0.5, rank + gt_db, db)
-    b = jnp.where(off < -0.5, c, b_bundled)
-    is_missing = jnp.where(
-        ((mt == float(MISSING_NAN)) & (b == nanb))
-        | ((mt == float(MISSING_ZERO)) & (b == db)), one, zero)
-    le_thr = jnp.where(b <= thr, one, zero)
-    num_left = jnp.where(is_missing > 0.5, dl, le_thr)
-    if any_cat:
-        catrow = jnp.dot(cat_ref[:].astype(sel_dt), ohL,
-                         preferred_element_type=jnp.float32)
-        iota_b = jax.lax.broadcasted_iota(
-            jnp.int32, (Bcat, T), 0).astype(jnp.float32)
-        cat_left = jnp.sum(jnp.where(iota_b == b, catrow, 0.0), axis=0,
-                           keepdims=True)
-        go_left = jnp.where(iscat > 0.5, cat_left, num_left)
-    else:
-        go_left = num_left
-    in_tree = jnp.where(leaf >= 0, one, zero)
-    moved = selm * (one - jnp.minimum(go_left, one)) * in_tree
-    nid = new_id.astype(jnp.int32)
-    rl = jnp.where(moved > 0.5, nid, leaf)
-    hl_old = leaf2_ref[1:2, :]
-    hl = jnp.where(hl_old >= 0, rl, hl_old)
-    leaf2_out_ref[0:1, :] = rl
-    leaf2_out_ref[1:2, :] = hl
+    ohL, sel_dt, sp = _route_select(leaf, rtabs_ref, tab_prec)
+    iota_g = jax.lax.broadcasted_iota(jnp.int32, (G_pad, T), 0)
+    c = jnp.sum(jnp.where(iota_g == sp.group.astype(jnp.int32), bins, 0),
+                axis=0, keepdims=True).astype(jnp.float32)    # [1, T]
+    _, hl = _route_apply(c, sp, leaf, ohL, sel_dt, leaf2_ref, cat_ref,
+                         leaf2_out_ref, B=Bcat, any_cat=any_cat)
 
     # ---- histogram with the routed in-bag leaves ----------------------
     # rows-on-lanes throughout: mask [A_pad, T] straight off the routed
     # leaf row (no [1,T]->[T,1] relayout), vw [cols, T], lane contraction
     quant = vals_ref.dtype == jnp.int8
     cdt = jnp.int8 if quant else jnp.bfloat16
-    oh = _onehot_bins(bins_ref[:].astype(jnp.int32), B, cdt)
+    oh = _onehot_bins(bins, B, cdt)
     m = active_ref[:] == hl                                   # [A_pad, T]
     vals = vals_ref[:]                                        # [C, T]
     vw = _weighted_cols(m, vals, n_cols, pad_cols, cdt)       # [cols, T]
@@ -875,28 +823,40 @@ def _hist_route_kernel(active_ref, bins_ref, vals_ref, leaf2_ref, rtabs_ref,
         preferred_element_type=jnp.int32 if quant else jnp.float32)
 
 
+def route_lanes(route_leaves: int) -> int:
+    """Lanes of the fused kernel's route table for a wave whose rows lie
+    in at most ``route_leaves`` leaves."""
+    return _round_up(max(route_leaves, 8), LANE)
+
+
 def fused_config_ok(num_groups: int, max_bins: int, num_leaves: int,
-                    mode: str, n_rows: int = 0,
-                    row_limit: int = INT8_ROW_LIMIT) -> bool:
-    """Fusion needs the whole feature set in one tile (the route reads the
-    split feature's column, which may live in any tile) plus the usual
-    kernel bounds; its one int32 accumulator also needs the ``n_rows``
-    of a quantized call to be one row chunk (:func:`row_chunks`)."""
+                    mode: str, n_rows: int, row_limit: int, *,
+                    slots: int, route_leaves: int,
+                    any_cat: bool = False) -> bool:
+    """Whether one wave may take the fused route+histogram kernel: the
+    whole feature set in one tile at that wave's ``slots`` (the route
+    reads the split feature's column, which may live in any tile), with
+    the route's residents counted for a table of ``route_leaves``
+    leaves carrying ids up to ``num_leaves``, at the 1,024-row tile
+    ``hist_tiling`` goes down to; the usual kernel bounds; and, its
+    accumulator being one int32 sum, the ``n_rows`` of a quantized call
+    one row chunk (:func:`row_chunks`)."""
     if not pallas_config_ok(max_bins, num_leaves, mode):
         return False
     if is_quantized(mode) and n_rows > row_limit:
         return False
     B = bin_stride(max_bins)
-    C, _, cols = _col_layout(min(max(1, num_leaves // 2), 128), mode)
-    # feasibility at the 1024-row fallback tile (the kernel halves its
-    # row tile per-config until the whole feature set fits)
-    return num_groups <= _feat_tile_cap(B, cols, 1024, C, mode)
+    C, _, cols = _col_layout(slots, mode)
+    return cell_vmem_bytes(num_groups, B, cols, 1024, C, mode,
+                           route_lanes=route_lanes(route_leaves),
+                           id_lanes=route_lanes(num_leaves),
+                           any_cat=any_cat) <= VMEM_BUDGET_BYTES
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("num_features", "max_bins", "mode", "row_tile",
-                     "interpret", "any_cat"))
+                     "interpret", "any_cat", "route_leaves"))
 def hist_route_pallas(bins_t, vals, leaf2, active,
                       feature, threshold, default_left, is_categorical,
                       cat_mask, sel, new_id, missing_types, nan_bins,
@@ -904,45 +864,55 @@ def hist_route_pallas(bins_t, vals, leaf2, active,
                       scales=None,
                       *, num_features: int, max_bins: int,
                       mode: str = "hilo", row_tile: int = DEFAULT_ROW_TILE,
+                      route_leaves: int,
                       interpret: bool = False, any_cat: bool = True):
     """Fused previous-wave routing + active-leaf histograms.
 
     -> ``(hist [A, F, B, 3] f32, leaf2_new [2, n_pad] i32)``.  Same
     contracts as :func:`hist_active_pallas` +
     ``ops.pallas_route.route_rows_pallas`` composed (route first).
-    Requires ``fused_config_ok``.
+    ``route_leaves``: the most leaves the rows lie in when this wave's
+    route runs (``learner/serial.py`` ``wave_backend_plan``); the table
+    and the leaf one-hot are that wide, the split tables' leaves past it
+    being neither a row's nor selected.  Its precision follows the ids
+    it carries, up to ``L``, not its width.  Requires
+    ``fused_config_ok``.
     """
-    from .pallas_route import _T_ROWS, _leaf_tables
+    from .pallas_route import _T_ROWS, _leaf_tables, table_precision
     F_pad, n_pad = bins_t.shape
     C = vals.shape[0]
     A = active.shape[0]
     B = bin_stride(max_bins)
 
+    Lr = route_leaves
+    L_pad, id_lanes = route_lanes(Lr), route_lanes(feature.shape[0])
+    feature, threshold, default_left, is_categorical, cat_mask, sel, \
+        new_id = (x[:Lr] for x in (feature, threshold, default_left,
+                                   is_categorical, cat_mask, sel, new_id))
     _, A_pad, cols = _col_layout(A, mode)
-    # the fused kernel holds ALL stored columns in one tile: the largest
-    # row tile at which that cell fits the VMEM budget
+    # the fused kernel holds ALL stored columns in one tile, and the
+    # route's residents beside them: the row tile of least modelled time
+    # at which that cell fits the VMEM budget
     T, _, _ = hist_tiling(F_pad, n_pad, B, cols, C, mode, row_tile,
-                          whole=True)
+                          whole=True, route_lanes=L_pad,
+                          id_lanes=id_lanes, any_cat=any_cat)
     assert n_pad % T == 0 and leaf2.shape == (2, n_pad)
     pad_cols = cols - C * A_pad
-    L = feature.shape[0]
-    L_pad = _round_up(max(L, 8), LANE)
     Bcat = cat_mask.shape[1]
 
     rtabs = _leaf_tables(feature, threshold, default_left, is_categorical,
                          sel, new_id, missing_types, nan_bins, default_bins,
                          feat_group, feat_offset, num_bins_arr, L_pad)
     cat = jnp.zeros((Bcat, L_pad), jnp.float32)
-    cat = cat.at[:, :L].set(cat_mask.T.astype(jnp.float32))
+    cat = cat.at[:, :Lr].set(cat_mask.T.astype(jnp.float32))
     act = jnp.full((A_pad, 1), -2, jnp.int32)
     act = jax.lax.dynamic_update_slice(
         act, active.astype(jnp.int32)[:, None], (0, 0))
 
-    from .pallas_route import table_precision
     out, leaf2_new = pl.pallas_call(
         functools.partial(_hist_route_kernel, n_cols=C, B=B, Bcat=Bcat,
                           pad_cols=pad_cols, any_cat=any_cat,
-                          tab_prec=table_precision(L_pad, F_pad)),
+                          tab_prec=table_precision(id_lanes, F_pad)),
         grid=(n_pad // T,),
         in_specs=[
             pl.BlockSpec((A_pad, 1), lambda r: (0, 0),

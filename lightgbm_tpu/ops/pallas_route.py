@@ -34,6 +34,7 @@ route costs ~1 stream pass instead of a chain of gathers.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -43,6 +44,7 @@ from jax.experimental.pallas import tpu as pltpu
 from ..io.binning import MISSING_NAN, MISSING_ZERO
 
 from .pallas_histogram import DEFAULT_ROW_TILE
+from .vmem import selection_bytes
 
 LANE = 128
 
@@ -60,14 +62,17 @@ def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
-def table_precision(L_pad: int, num_groups: int):
+def table_precision(id_lanes: int, num_groups: int):
     """MXU precision for the per-leaf table selection dot.
 
     The table rows carry integers (leaf ids < L, group ids < G, bin ids
     < 256).  bf16 holds integers exactly up to 256, so when every value
     fits, the default single-pass bf16 dot is exact and 6x cheaper than
-    HIGHEST (f32-via-bf16x6); larger configs keep HIGHEST."""
-    if L_pad <= 256 and num_groups <= 256:
+    HIGHEST (f32-via-bf16x6); larger configs keep HIGHEST.  ``id_lanes``
+    is ``round_up(L, 128)`` whatever the table's width: a narrow table
+    still hands out new ids up to ``L``.  The VMEM model sizes the
+    one-hot by the same rule (``ops/vmem.py`` ``selection_bytes``)."""
+    if selection_bytes(id_lanes, num_groups) == 2:
         return jax.lax.Precision.DEFAULT
     return jax.lax.Precision.HIGHEST
 
@@ -89,12 +94,28 @@ def _route_kernel(bins_ref, leaf2_ref, tabs_ref, cat_ref, out_ref, *,
                 tab_prec=tab_prec, any_cat=any_cat)
 
 
-def _route_body(bins_ref, leaf2_ref, tabs_ref, cat_ref, out_ref, *, B: int,
-                tab_prec=jax.lax.Precision.HIGHEST, any_cat: bool = True):
-    leaf = leaf2_ref[0:1, :]                                  # [1, T] i32
+class _RowSplit(NamedTuple):
+    """Each row's leaf's split, selected from the table rows: ``[1, T]``
+    f32 rows of :func:`_route_select`."""
+    group: jnp.ndarray
+    thr: jnp.ndarray
+    dl: jnp.ndarray
+    iscat: jnp.ndarray
+    selm: jnp.ndarray
+    new_id: jnp.ndarray
+    off: jnp.ndarray
+    nb: jnp.ndarray
+    db: jnp.ndarray
+    mt: jnp.ndarray
+    nanb: jnp.ndarray
+
+
+def _route_select(leaf, tabs_ref, tab_prec):
+    """``leaf [1, T] -> (ohL, sel_dt, split)``: the leaf one-hot and each
+    row's leaf's split, ALL per-leaf split data fetched by one small
+    matmul ``tabs[16, L_pad] @ ohL -> [16, T]``."""
     T = leaf.shape[1]
     L_pad = tabs_ref.shape[1]
-    G_pad = bins_ref.shape[0]
 
     iota_l = jax.lax.broadcasted_iota(jnp.int32, (L_pad, T), 0)
     sel_dt = selection_dtype(tab_prec)
@@ -107,40 +128,34 @@ def _route_body(bins_ref, leaf2_ref, tabs_ref, cat_ref, out_ref, *, B: int,
     sel16 = jnp.dot(tabs_ref[:].astype(sel_dt), ohL,
                     preferred_element_type=jnp.float32,
                     precision=tab_prec)                       # [16, T]
-    g_row = sel16[_T_GROUP:_T_GROUP + 1, :]
-    thr = sel16[_T_THR:_T_THR + 1, :]
-    dl = sel16[_T_DL:_T_DL + 1, :]
-    iscat = sel16[_T_ISCAT:_T_ISCAT + 1, :]
-    selm = sel16[_T_SEL:_T_SEL + 1, :]
-    new_id = sel16[_T_NEWID:_T_NEWID + 1, :]
-    off = sel16[_T_OFF:_T_OFF + 1, :]
-    nb = sel16[_T_NB:_T_NB + 1, :]
-    db = sel16[_T_DB:_T_DB + 1, :]
-    mt = sel16[_T_MT:_T_MT + 1, :]
-    nanb = sel16[_T_NANB:_T_NANB + 1, :]
+    return ohL, sel_dt, _RowSplit(*(
+        sel16[k:k + 1, :] for k in (_T_GROUP, _T_THR, _T_DL, _T_ISCAT,
+                                    _T_SEL, _T_NEWID, _T_OFF, _T_NB,
+                                    _T_DB, _T_MT, _T_NANB)))
 
-    binsf = bins_ref[:].astype(jnp.int32).astype(jnp.float32)  # [G, T]
-    iota_g = jax.lax.broadcasted_iota(
-        jnp.int32, (G_pad, T), 0).astype(jnp.float32)
-    ohG = jnp.where(iota_g == g_row, 1.0, 0.0)                # [G, T]
-    c = jnp.sum(ohG * binsf, axis=0, keepdims=True)           # [1, T]
 
+def _route_apply(c, sp: _RowSplit, leaf, ohL, sel_dt, leaf2_ref, cat_ref,
+                 out_ref, *, B: int, any_cat: bool = True):
+    """``c [1, T]`` f32, each row's stored value at its split feature's
+    group column ``->`` both routed leaf vectors, written to ``out_ref``
+    and returned ``(row_leaf', hist_leaf')``."""
+    T = leaf.shape[1]
     # EFB inverse mapping: stored column value -> feature bin
     one = jnp.ones_like(c)
     zero = jnp.zeros_like(c)
-    rank = c - off
-    gt_db = jnp.where(rank >= db, one, zero)
-    in_range = jnp.where((rank >= 0) & (rank < nb - 1), one, zero)
-    b_bundled = jnp.where(in_range > 0.5, rank + gt_db, db)
-    b = jnp.where(off < -0.5, c, b_bundled)                   # [1, T]
+    rank = c - sp.off
+    gt_db = jnp.where(rank >= sp.db, one, zero)
+    in_range = jnp.where((rank >= 0) & (rank < sp.nb - 1), one, zero)
+    b_bundled = jnp.where(in_range > 0.5, rank + gt_db, sp.db)
+    b = jnp.where(sp.off < -0.5, c, b_bundled)                # [1, T]
 
     # all masks ride as f32 0/1 values (Mosaic rejects bool-valued selects)
     is_missing = jnp.where(
-        ((mt == float(MISSING_NAN)) & (b == nanb))
-        | ((mt == float(MISSING_ZERO)) & (b == db)), one, zero)
+        ((sp.mt == float(MISSING_NAN)) & (b == sp.nanb))
+        | ((sp.mt == float(MISSING_ZERO)) & (b == sp.db)), one, zero)
 
-    le_thr = jnp.where(b <= thr, one, zero)
-    num_left = jnp.where(is_missing > 0.5, dl, le_thr)
+    le_thr = jnp.where(b <= sp.thr, one, zero)
+    num_left = jnp.where(is_missing > 0.5, sp.dl, le_thr)
     if any_cat:
         catrow = jnp.dot(cat_ref[:].astype(sel_dt), ohL,
                          preferred_element_type=jnp.float32)  # [B, T]
@@ -149,20 +164,37 @@ def _route_body(bins_ref, leaf2_ref, tabs_ref, cat_ref, out_ref, *, B: int,
         cat_left = jnp.sum(
             jnp.where(iota_b == b, catrow, 0.0), axis=0,
             keepdims=True)                                    # [1, T]
-        go_left = jnp.where(iscat > 0.5, cat_left, num_left)
+        go_left = jnp.where(sp.iscat > 0.5, cat_left, num_left)
     else:
         # no categorical features in the dataset: skip the [B, L] @
         # [L, T] membership dot + bin one-hot reduction entirely
         go_left = num_left
     in_tree = jnp.where(leaf >= 0, one, zero)
-    moved = selm * (one - jnp.minimum(go_left, one)) * in_tree
-    nid = new_id.astype(jnp.int32)
+    moved = sp.selm * (one - jnp.minimum(go_left, one)) * in_tree
+    nid = sp.new_id.astype(jnp.int32)
 
     rl = jnp.where(moved > 0.5, nid, leaf)                    # row_leaf'
     hl = leaf2_ref[1:2, :]
     out_ref[0:1, :] = rl
-    out_ref[1:2, :] = jnp.where(hl >= 0, rl, hl)              # hist_leaf'
-    return rl
+    hl = jnp.where(hl >= 0, rl, hl)                           # hist_leaf'
+    out_ref[1:2, :] = hl
+    return rl, hl
+
+
+def _route_body(bins_ref, leaf2_ref, tabs_ref, cat_ref, out_ref, *, B: int,
+                tab_prec=jax.lax.Precision.HIGHEST, any_cat: bool = True):
+    leaf = leaf2_ref[0:1, :]                                  # [1, T] i32
+    ohL, sel_dt, sp = _route_select(leaf, tabs_ref, tab_prec)
+    T = leaf.shape[1]
+    G_pad = bins_ref.shape[0]
+
+    binsf = bins_ref[:].astype(jnp.int32).astype(jnp.float32)  # [G, T]
+    iota_g = jax.lax.broadcasted_iota(
+        jnp.int32, (G_pad, T), 0).astype(jnp.float32)
+    ohG = jnp.where(iota_g == sp.group, 1.0, 0.0)             # [G, T]
+    c = jnp.sum(ohG * binsf, axis=0, keepdims=True)           # [1, T]
+    return _route_apply(c, sp, leaf, ohL, sel_dt, leaf2_ref, cat_ref,
+                        out_ref, B=B, any_cat=any_cat)[0]
 
 
 def _route_values_kernel(bins_ref, leaf2_ref, tabs_ref, cat_ref, out_ref,

@@ -108,8 +108,40 @@ def operand_bytes(mode: str) -> int:
     return 1 if is_quantized(mode) else 2
 
 
+def selection_bytes(id_lanes: int, num_groups: int) -> int:
+    """Element size of the route's leaf one-hot and tables on the MXU:
+    bf16 where every integer the tables carry is exact in it (leaf ids
+    and groups up to 256), f32 past that (`ops/pallas_route.py`
+    ``table_precision`` takes its precision from this).  ``id_lanes``:
+    the tree's leaf ids rounded up to lanes, ``round_up(num_leaves,
+    128)``, since a split's new id may be any of them, however narrow
+    the table that carries it."""
+    return 2 if id_lanes <= 256 and num_groups <= 256 else 4
+
+
+def route_vmem_bytes(T: int, L_pad: int, B: int, num_groups: int,
+                     id_lanes: int, any_cat: bool = False) -> int:
+    """What the route adds to a fused route+histogram cell
+    (``ops/pallas_histogram.py`` ``_hist_route_kernel``): the ``[2, T]``
+    int32 leaf vectors in and out, each double-buffered; the ``[16,
+    L_pad]`` split table and the ``[B, L_pad]`` categorical table (f32,
+    double-buffered; ``B`` the group stride, at least the features'); the
+    ``[L_pad, T]`` leaf one-hot at :func:`selection_bytes` of the
+    tree's ``id_lanes``; the ``[16,
+    T]`` f32 selection; with categorical features the ``[B, T]`` f32
+    membership rows."""
+    return (2 * 2 * (2 * T * 4)
+            + 2 * (16 + B) * L_pad * 4
+            + L_pad * T * selection_bytes(max(L_pad, id_lanes),
+                                          num_groups)
+            + 16 * T * 4
+            + (B * T * 4 if any_cat else 0))
+
+
 def cell_vmem_bytes(ft: int, B: int, cols: int, T: int, C: int,
-                    mode: str, seeded: bool = False) -> int:
+                    mode: str, seeded: bool = False,
+                    route_lanes: int = 0, id_lanes: int = 0,
+                    any_cat: bool = False) -> int:
     """VMEM footprint of one (feature-tile, row-tile) histogram grid
     cell, each resident at the element size ``mode`` gives it: the
     4-byte accumulator (f32, int32 on a quantized mode), the one-hot
@@ -119,14 +151,20 @@ def cell_vmem_bytes(ft: int, B: int, cols: int, T: int, C: int,
     accumulator IN, double-buffered like every blocked operand — two
     more accumulator-sized blocks (the v5e compiler refused the
     28 x 63-bin x 128-slot seeded cell at 16.76 MB of scoped VMEM
-    before this was counted)."""
+    before this was counted).
+    ``route_lanes``: the fused route+histogram cell, whose route table
+    is ``route_lanes`` leaves wide and carries ids up to ``id_lanes``,
+    also holds the route's residents (:func:`route_vmem_bytes`; ``ft``
+    is then the whole feature set)."""
     acc = ft * B * cols * 4          # accumulator (out block)
     e = operand_bytes(mode)
     return (acc + (2 * acc if seeded else 0)
             + ft * B * T * e         # one-hot
             + T * cols * e           # vw
             + 2 * ft * T             # bins tile, double-buffered
-            + 2 * T * C * 4)         # vals, double-buffered
+            + 2 * T * C * 4          # vals, double-buffered
+            + (route_vmem_bytes(T, route_lanes, B, ft, id_lanes, any_cat)
+               if route_lanes else 0))
 
 
 def feat_tile_cap(B: int, cols: int, T: int, C: int, mode: str,
@@ -159,7 +197,9 @@ def row_tiles(n_pad: int, requested: int) -> list[int]:
 
 def hist_tiling(F_pad: int, n_pad: int, B: int, cols: int, C: int,
                 mode: str, requested: int, seeded: bool = False,
-                whole: bool = False) -> tuple[int, int, int]:
+                whole: bool = False, route_lanes: int = 0,
+                id_lanes: int = 0,
+                any_cat: bool = False) -> tuple[int, int, int]:
     """``-> (T, feat_tile, F_grid)``: the grid of one histogram kernel
     call, chosen for the least modelled time (:func:`hist_call_fs`)
     over the cells the VMEM model admits.
@@ -170,10 +210,11 @@ def hist_tiling(F_pad: int, n_pad: int, B: int, cols: int, C: int,
     multiple of 8 up to :func:`feat_tile_cap`, the feature count padded
     to a whole number of tiles (``F_grid``).  ``whole``: the whole set
     or nothing (the fused route+histogram kernel reads any feature's
-    column from the one tile).  Ties go to the larger row tile, then
-    the larger feature tile.  Where no cell fits (a config the
-    feasibility predicates turn away) the smallest one is returned and
-    the compiler is left to refuse it.
+    column from the one tile; ``route_lanes`` / ``id_lanes`` /
+    ``any_cat`` count its route, :func:`cell_vmem_bytes`).  Ties go to
+    the larger row tile, then the larger feature tile.  Where no cell
+    fits (a config the feasibility predicates turn away) the smallest
+    one is returned and the compiler is left to refuse it.
 
     Shared by the wide and fused kernels and the wide kernel's
     raw-layout twin, so a fold's carry can never disagree with the
@@ -181,8 +222,9 @@ def hist_tiling(F_pad: int, n_pad: int, B: int, cols: int, C: int,
     tiles = row_tiles(n_pad, requested)
     grids = []
     for T in tiles:
-        if cell_vmem_bytes(F_pad, B, cols, T, C, mode, seeded) \
-                <= VMEM_BUDGET_BYTES:
+        if cell_vmem_bytes(F_pad, B, cols, T, C, mode, seeded,
+                           route_lanes, id_lanes,
+                           any_cat) <= VMEM_BUDGET_BYTES:
             feat_tiles = [F_pad]
         elif whole:
             feat_tiles = []

@@ -142,12 +142,45 @@ def test_auto_on_a_tpu_is_the_wide_kernel_in_every_wave(monkeypatch, leaves):
     plan, A_tail = stage_plan(leaves)
     assert (plan[-2:], A_tail) == (([32, 64], 128) if leaves == 255
                                    else ([8, 8], 16))
-    for fused_ok, want in ((True, "fused"), (False, "pallas")):
-        choices, tail = wave_backend_plan(leaves, backend="pallas",
-                                          fused_ok=fused_ok)
-        assert choices == [want] * len(plan) and tail == want
+    shape = dict(num_groups=28, max_bins=63, mode="int8h", n_rows=1 << 20)
+    for serial, want in ((True, "fused"), (False, "pallas")):
+        waves, A, tail = wave_backend_plan(leaves, backend="pallas",
+                                           serial=serial, **shape)
+        assert [w.slots for w in waves] == plan and A == A_tail
+        assert [w.choice for w in waves] == \
+            ["pallas"] + [want] * (len(plan) - 1)
+        assert tail == want
     # leaf-wise growth: every wave is the 8-slot tail
-    assert wave_backend_plan(leaves, wave_size=1) == ([], "fused")
+    assert wave_backend_plan(leaves, wave_size=1, **shape) == \
+        ([], 8, "fused")
+
+
+_CRITEO = dict(num_groups=67, max_bins=63, mode="int8h")
+
+
+@pytest.mark.parametrize("shape,serial,want,tail", [
+    # the one-chip cells: every wave with a route to apply holds the 67
+    # features in one tile at its own columns; the tail's 512 do not
+    (dict(_CRITEO, n_rows=13_281_280), True, ["pallas"] + ["fused"] * 7,
+     "pallas"),
+    # -c32: four row chunks, and the fused accumulator is one int32 sum
+    (dict(_CRITEO, n_rows=53_125_120), True, ["pallas"] * 8, "pallas"),
+    # -dp4: the exchange sits between the histogram and the scan
+    (dict(_CRITEO, n_rows=13_281_280), False, ["pallas"] * 8, "pallas"),
+    # chip_smoke.py's width: fused wherever a wave has a route, the tail
+    # too, as under the tree-wide gate
+    (dict(num_groups=28, max_bins=63, mode="int8h", n_rows=1 << 20), True,
+     ["pallas"] + ["fused"] * 7, "fused"),
+])
+def test_wave_backend_plan_judges_each_wave(shape, serial, want, tail):
+    """The fused route+histogram call is chosen a wave at a time from
+    static shapes (the wave's slots, the leaves its route reads, the
+    rows against one chunk, the exchange), and the root wave, which has
+    no split to apply, keeps the wide call."""
+    waves, A_tail, got = wave_backend_plan(255, serial=serial, **shape)
+    assert ([w.choice for w in waves], got) == (want, tail)
+    assert [w.route_leaves for w in waves] == [1, 1, 2, 4, 8, 16, 32, 64]
+    assert ([w.slots for w in waves], A_tail) == stage_plan(255)
 
 
 @pytest.mark.parametrize(
@@ -297,3 +330,80 @@ def test_hist_fold_logs_each_substitution_once(caplog):
     assert len(msgs) == 1, msgs
     assert msgs[0].startswith("streamed histogram fold: scatter (carried "
                               "f32 fold) (resolved backend pallas)")
+
+
+@pytest.mark.parametrize("F,leaves,fused_calls,tail,grown", [
+    # the one-chip cells' width: one tile at 128 and 256 columns, not at
+    # the tail's 512
+    (67, 255, [1, 2, 4, 8, 16, 32, 64], "pallas", 128),
+    # a table narrower than the ids it hands out: wave 9 routes rows to
+    # the new ids 256..383 from a 256-lane table, so the table's
+    # precision must follow the 511 leaves' ids, not its lanes
+    # (the tail fused too, its table all 512 lanes)
+    (28, 511, [1, 2, 4, 8, 16, 32, 64, 128, 256], "fused", 384),
+], ids=["67x255", "28x511"])
+def test_fused_waves_grow_the_unfused_tree(monkeypatch, F, leaves,
+                                           fused_calls, tail, grown):
+    """Where the per-wave gate admits the unrolled waves, int8h, bagged
+    rows and a feature mask, the tree whose routes run inside the
+    histogram calls is the tree of the route kernel and the wide call,
+    bit for bit: the sums are exact integers and a routed leaf is the
+    route kernel's.  The unfused side is reached by the gate refusing
+    every wave."""
+    from lightgbm_tpu.learner import serial
+    monkeypatch.setattr(serial, "_COMPILE_LEAN_ROWS", 0)
+    rng = np.random.RandomState(7)
+    n = 4096
+    X = rng.rand(n, F).astype(np.float32)
+    y = (np.sin(6 * X[:, 0]) + X[:, 1] * X[:, 2] + X[:, F - 1]
+         + 0.1 * rng.randn(n)).astype(np.float32)
+    dd = to_device(BinnedDataset.from_raw(
+        X, Config.from_params({"max_bin": 63})))
+    p = GrowthParams(num_leaves=leaves, split=SplitParams(
+        min_data_in_leaf=2, min_sum_hessian_in_leaf=0.0))
+    bag = jnp.asarray(rng.rand(n) < 0.8)
+    fmask = jnp.asarray(np.arange(F) % 5 != 3)
+    waves, _, got_tail = wave_backend_plan(
+        leaves, num_groups=F, max_bins=63, mode="int8h", n_rows=n,
+        any_cat=False)
+    assert [w.choice for w in waves] == \
+        ["pallas"] + ["fused"] * len(fused_calls)
+    assert got_tail == tail
+
+    calls, final = [], []
+    fused_call = serial.hist_route_pallas
+    values_call = serial.route_rows_values_pallas
+
+    def counted(*a, **k):
+        calls.append(k["route_leaves"])
+        return fused_call(*a, **k)
+
+    def kept(*a, **k):
+        out = values_call(*a, **k)
+        final.append(out[0])
+        return out
+    monkeypatch.setattr(serial, "hist_route_pallas", counted)
+    monkeypatch.setattr(serial, "route_rows_values_pallas", kept)
+
+    def grow():
+        return build_tree(dd, jnp.asarray(-(y - y.mean()), jnp.float32),
+                          jnp.ones(n, jnp.float32), p, bag_mask=bag,
+                          feature_mask=fmask, hist_backend="pallas",
+                          hist_mode="int8h")
+    fused = grow()
+    fused_calls = fused_calls + ([leaves] if tail == "fused" else [])
+    assert calls == fused_calls
+    monkeypatch.setattr(serial, "fused_config_ok", lambda *a, **k: False)
+    unfused = grow()
+    assert len(calls) == len(fused_calls)
+    assert int(fused.num_leaves) > grown
+    for name in BuiltTree._fields:
+        a, b = getattr(fused, name), getattr(unfused, name)
+        for x, z in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(z),
+                                          err_msg=name)
+    # both leaf vectors after the final route: every row's leaf, and the
+    # in-bag rows' (bagged-out rows parked at -1)
+    np.testing.assert_array_equal(np.asarray(final[0]),
+                                  np.asarray(final[1]))
+    assert (np.asarray(final[0][1, :n]) == -1).sum() == int((~bag).sum())

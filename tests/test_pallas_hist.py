@@ -197,7 +197,8 @@ def test_tiling_gauges_are_the_kernels_grids(monkeypatch):
     """`GBDT._record_tiling`'s gauges against what `hist_tiling` returns
     for the arguments the kernels themselves hand it while a tree of the
     cells' plan is traced (staged waves, then the tail: a data set this
-    small would otherwise trace the tail alone).  Traced, not compiled:
+    small would otherwise trace the tail alone), and `hist.fused_waves`
+    against the waves the trace hands the fused call.  Traced, not compiled:
     the grid is chosen while the call is traced."""
     import lightgbm_tpu as lgb
     from lightgbm_tpu import obs
@@ -215,6 +216,10 @@ def test_tiling_gauges_are_the_kernels_grids(monkeypatch):
 
     rule = ph.hist_tiling
     monkeypatch.setattr(ph, "hist_tiling", recording)
+    fused_calls = []
+    fused = serial.hist_route_pallas
+    monkeypatch.setattr(serial, "hist_route_pallas", lambda *a, **k: (
+        fused_calls.append(k["route_leaves"]) or fused(*a, **k)))
     rng = np.random.RandomState(5)
     X = rng.normal(size=(5000, 67)).astype(np.float32)
     y = (X[:, 0] > 0).astype(np.float32)
@@ -241,6 +246,9 @@ def test_tiling_gauges_are_the_kernels_grids(monkeypatch):
               if k.startswith("hist.tiling.")}
     assert tiling == {128: "67x2048", 256: "67x1024", 512: "24x2048"}
     assert seen == tiling
+    # the waves the trace fused are the gauge's: 1-7, the tail not
+    assert gauges["hist.fused_waves"] == "1,2,3,4,5,6,7"
+    assert fused_calls == [1, 2, 4, 8, 16, 32, 64]
 
 
 @pytest.mark.parametrize("mode,max_bins,F,A", [

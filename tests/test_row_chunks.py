@@ -154,11 +154,14 @@ def test_a_resident_shard_keeps_its_mode_whatever_its_size():
 
 
 def test_the_fused_kernel_keeps_to_one_chunk():
-    assert ph.fused_config_ok(28, 63, 255, "int8h")
-    assert ph.fused_config_ok(28, 63, 255, "int8h", 13_281_280)
-    assert not ph.fused_config_ok(28, 63, 255, "int8h", 53_125_120)
-    assert ph.fused_config_ok(28, 63, 255, "hhilo", 53_125_120) \
-        == ph.fused_config_ok(28, 63, 255, "hhilo")
+    def ok(mode, n_rows):       # the 255-leaf tail's wave
+        return ph.fused_config_ok(28, 63, 255, mode, n_rows,
+                                  ph.INT8_ROW_LIMIT, slots=128,
+                                  route_leaves=255)
+    assert ok("int8h", 0)
+    assert ok("int8h", 13_281_280)
+    assert not ok("int8h", 53_125_120)
+    assert ok("hhilo", 53_125_120) == ok("hhilo", 0)
 
 
 def test_a_seeded_call_refuses_more_rows_than_its_accumulator_sums():

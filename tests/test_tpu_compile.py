@@ -234,20 +234,84 @@ def test_seeded_wide_fold_compiles_at_deep_waves(one_chip, on_tpu, slots):
 @pytest.mark.parametrize("features,mode,slots", [
     (F, "hilo", 32),
     (F, "int8h", 32),
-    (60, "int8h", 128),     # the widest set the gate admits at 63 bins
-    (60, "int8h", 8),       # since PR 34 (43 before), at 1,024 / 2,048 rows
+    (56, "int8h", 128),     # the widest set the tail's gate admits at 63
+    (56, "int8h", 8),       # bins once the route counts (60 before PR 40)
 ])
 def test_fused_hist_route_kernel_compiles(one_chip, features, mode, slots):
-    from lightgbm_tpu.ops.pallas_histogram import (fused_config_ok,
+    from lightgbm_tpu.ops.pallas_histogram import (INT8_ROW_LIMIT,
+                                                   fused_config_ok,
                                                    hist_route_pallas)
-    assert fused_config_ok(features, MAX_BIN, LEAVES, mode)
+    assert fused_config_ok(features, MAX_BIN, LEAVES, mode, N,
+                           INT8_ROW_LIMIT, slots=128, route_leaves=LEAVES)
     s = _shapes(one_chip)
     bins_t, vals, _, active, scales = _hist_args(s, N, mode, slots,
                                                  features)
     text = _compiled_text(hist_route_pallas.lower(
         bins_t, vals, s((2, N), jnp.int32), active,
         *_split_tables(s, features), scales, num_features=features,
-        max_bins=MAX_BIN, mode=mode, any_cat=False))
+        max_bins=MAX_BIN, mode=mode, any_cat=False, route_leaves=LEAVES))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("features", [60, 67])
+def test_the_fused_tail_compiles_past_the_models_cap(one_chip, features):
+    """The VMEM model is an upper bound: it counts every resident of the
+    fused cell as live at once, and refuses the 255-leaf tail's cell
+    (128 slots, 512 columns, 1,024 rows) past 56 features.  The chip's
+    compiler takes it at 60 (the parent's cap, before the route was
+    counted) and at the cells' 67 (at least to 88 by hand, PR 40).  What
+    fusing those tails would gain is not measured: the tail keeps the
+    unfused pair there."""
+    from lightgbm_tpu.ops.pallas_histogram import (INT8_ROW_LIMIT,
+                                                   fused_config_ok,
+                                                   hist_route_pallas)
+    assert not fused_config_ok(features, MAX_BIN, LEAVES, "int8h", N,
+                               INT8_ROW_LIMIT, slots=128,
+                               route_leaves=LEAVES)
+    s = _shapes(one_chip)
+    bins_t, vals, _, active, scales = _hist_args(s, N, "int8h", 128,
+                                                 features)
+    text = _compiled_text(hist_route_pallas.lower(
+        bins_t, vals, s((2, N), jnp.int32), active,
+        *_split_tables(s, features), scales, num_features=features,
+        max_bins=MAX_BIN, mode="int8h", any_cat=False, route_leaves=LEAVES))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("slots,route_leaves,grid", [
+    (8, 8, (2048, 67, 67)),         # wave 4 (waves 1-3 alike)
+    (16, 16, (2048, 67, 67)),       # wave 5
+    (32, 32, (2048, 67, 67)),       # wave 6
+    (64, 64, (1024, 67, 67)),       # wave 7
+])
+def test_fused_hist_route_kernel_compiles_at_the_cells_shape(
+        one_chip, slots, route_leaves, grid):
+    """The one-chip cells' fused waves as `build_tree` makes them:
+    `[67, 13,281,280]`, int8h, 63 bins, the route table as wide as the
+    leaves the wave's rows can lie in, on the wide call's own grids
+    (6,485 cells of 2,048 rows at 128 columns, 1,024 rows at 256).  The
+    chip's compiler takes them under its default scoped-VMEM limit."""
+    from lightgbm_tpu.ops.pallas_histogram import (INT8_ROW_LIMIT,
+                                                   fused_config_ok,
+                                                   hist_route_pallas,
+                                                   route_lanes)
+    from lightgbm_tpu.ops.vmem import col_layout, hist_tiling
+    assert fused_config_ok(F_CRITEO, MAX_BIN, LEAVES, "int8h", N_CRITEO,
+                           INT8_ROW_LIMIT, slots=slots,
+                           route_leaves=route_leaves)
+    C, _, cols = col_layout(slots, "int8h")
+    assert hist_tiling(F_CRITEO, N_CRITEO, bin_stride(MAX_BIN), cols, C,
+                       "int8h", 2048, whole=True,
+                       route_lanes=route_lanes(route_leaves),
+                       id_lanes=route_lanes(LEAVES)) == grid
+    s = _shapes(one_chip)
+    bins_t, vals, _, active, scales = _hist_args(s, N_CRITEO, "int8h",
+                                                 slots, F_CRITEO)
+    text = _compiled_text(hist_route_pallas.lower(
+        bins_t, vals, s((2, N_CRITEO), jnp.int32), active,
+        *_split_tables(s, F_CRITEO), scales, num_features=F_CRITEO,
+        max_bins=MAX_BIN, mode="int8h", any_cat=False,
+        route_leaves=route_leaves))
     assert "tpu_custom_call" in text
 
 

@@ -183,23 +183,91 @@ def test_cell_bytes_by_element_size(mode, bytes_128x2048):
 
 
 @pytest.mark.parametrize("features,max_bin,leaves,mode,ok", [
-    (67, 63, 255, "int8h", False),  # the cells: 60 features fit, not 67
+    (67, 63, 255, "int8h", False),  # the cells: 56 features fit, not 67
     (28, 63, 255, "int8h", True),   # chip_smoke.py's width: as before
     (28, 63, 255, "hilo", True),
     (67, 63, 255, "hilo", False),
-    (60, 63, 255, "int8h", True),   # 43 before the one-hot's own byte
-    (61, 63, 255, "int8h", False),
-    (43, 63, 255, "hhilo", True),   # the bf16 cap: where it was
-    (44, 63, 255, "hhilo", False),
+    (56, 63, 255, "int8h", True),   # 60 before the route was counted
+    (57, 63, 255, "int8h", False),
+    (40, 63, 255, "hhilo", True),   # the bf16 cap: 43 before
+    (41, 63, 255, "hhilo", False),
 ])
 def test_fused_gate_by_element_size(features, max_bin, leaves, mode, ok):
-    """`fused_config_ok` is judged at the tail's columns at 1,024 rows.
-    The shapes the cells and the tests train at keep the decision they
-    had; between the bf16 cap and the int8 cap a quantized config now
-    takes the fused kernel (compiled for the described chip in
-    `tests/test_tpu_compile.py`)."""
-    from lightgbm_tpu.ops.pallas_histogram import fused_config_ok
-    assert fused_config_ok(features, max_bin, leaves, mode) == ok
+    """`fused_config_ok` judged at the tail's columns at 1,024 rows,
+    the route's residents counted for a table of all 255 leaves (0.77
+    MB there): the widths the tests train at keep the decision they
+    had, the caps moved down by what the route holds."""
+    from lightgbm_tpu.ops.pallas_histogram import (INT8_ROW_LIMIT,
+                                                   fused_config_ok)
+    assert fused_config_ok(features, max_bin, leaves, mode, 0,
+                           INT8_ROW_LIMIT, slots=128,
+                           route_leaves=leaves) == ok
+
+
+@pytest.mark.parametrize("slots,route_leaves,cols,grid", [
+    (8, 1, 128, (2048, 67, 67)),        # waves 1-4: the wide call's grid
+    (32, 32, 128, (2048, 67, 67)),      # wave 6
+    (64, 64, 256, (1024, 67, 67)),      # wave 7
+    (128, 255, 512, None),              # the tail: 67 do not fit
+])
+def test_fused_gate_by_wave(slots, route_leaves, cols, grid):
+    """Judged a wave at a time, the cells' 67 features fit one fused
+    tile at 128 columns x 2,048 rows and at 256 x 1,024, the grids the
+    wide call takes there, and not at the tail's 512."""
+    from lightgbm_tpu.ops.pallas_histogram import (INT8_ROW_LIMIT,
+                                                   fused_config_ok,
+                                                   route_lanes)
+    C, _, got_cols = col_layout(slots, "int8h")
+    assert got_cols == cols
+    ok = fused_config_ok(67, 63, 255, "int8h", 13_281_280, INT8_ROW_LIMIT,
+                         slots=slots, route_leaves=route_leaves)
+    assert ok == (grid is not None)
+    if ok:
+        lanes = route_lanes(route_leaves)
+        assert hist_tiling(67, 13_281_280, 64, cols, C, "int8h", ROW_TILE,
+                           whole=True, route_lanes=lanes,
+                           id_lanes=256) == grid
+        assert hist_tiling(67, 13_281_280, 64, cols, C, "int8h",
+                           ROW_TILE) == grid
+
+
+def test_fused_cell_fits_the_budget_at_the_cells_grid():
+    """The fused cell at `67x2048`, 128 columns, int8h: the wide cell's
+    11.58 MB and the route's 0.80 MB (leaf vectors in and out, the two
+    tables at 128 lanes, the bf16 leaf one-hot, the selection) under
+    `VMEM_BUDGET_BYTES`."""
+    wide = cell_vmem_bytes(67, 64, 128, 2048, 4, "int8h")
+    fused = cell_vmem_bytes(67, 64, 128, 2048, 4, "int8h", route_lanes=128,
+                            id_lanes=256)
+    assert fused - wide == vmem.route_vmem_bytes(2048, 128, 64, 67, 256) \
+        == 802_816
+    assert fused == 12_382_208 <= VMEM_BUDGET_BYTES
+    assert vmem.selection_bytes(256, 67) == 2
+
+
+@pytest.mark.parametrize("leaves,route_leaves,elem", [
+    (255, 64, 2),       # the cells: ids < 256, bf16-exact
+    (255, 255, 2),
+    (511, 256, 4),      # wave 9 hands out ids 256..383 from 256 lanes
+    (511, 128, 4),
+    (1024, 255, 4),
+])
+def test_route_precision_follows_the_ids_not_the_lanes(leaves,
+                                                       route_leaves, elem):
+    """A fused wave's table is as wide as the leaves its rows can lie
+    in, but its new-id row carries ids up to the tree's ``num_leaves``:
+    the selection's element size, and so its MXU precision, is taken
+    from those ids (bf16 holds integers up to 256 alone)."""
+    from lightgbm_tpu.ops.pallas_histogram import route_lanes
+    from lightgbm_tpu.ops.pallas_route import table_precision
+    id_lanes = route_lanes(leaves)
+    assert vmem.selection_bytes(id_lanes, 28) == elem
+    assert (table_precision(id_lanes, 28) == jax.lax.Precision.DEFAULT) \
+        == (elem == 2)
+    lanes = route_lanes(route_leaves)
+    assert vmem.route_vmem_bytes(1024, lanes, 64, 28, id_lanes) \
+        - vmem.route_vmem_bytes(1024, lanes, 64, 28, 128) \
+        == (elem - 2) * lanes * 1024
 
 
 @pytest.mark.parametrize("max_bin,slots,mode,ok", [
@@ -220,14 +288,18 @@ def test_seeded_fold_gate_by_element_size(max_bin, slots, mode, ok):
 
 
 def test_booster_sets_the_tiling_gauges(monkeypatch):
-    """`hist.tiling.<cols>`, `hist.feature_pad_pct`, `hist.row_chunks` beside
-    `gbdt.hist_backend`, as `chip_smoke.py` prints them: every wave's
-    grid of a 255-leaf tree at the Criteo width, from the kernels' own
-    rule."""
+    """`hist.tiling.<cols>`, `hist.feature_pad_pct`, `hist.row_chunks`,
+    `hist.fused_waves` beside `gbdt.hist_backend`, as `chip_smoke.py`
+    prints them: every wave's grid of a 255-leaf tree at the Criteo
+    width, and the waves that take the fused call, from the kernels'
+    own rule."""
     import numpy as np
     import lightgbm_tpu as lgb
     from lightgbm_tpu import obs
+    from lightgbm_tpu.learner import serial
     monkeypatch.setenv("LGBM_TPU_HIST_BACKEND", "pallas")
+    # the staged waves at any rows, as at the cells' (no tree is built)
+    monkeypatch.setattr(serial, "_COMPILE_LEAN_ROWS", 0)
     rng = np.random.RandomState(0)
     X = rng.normal(size=(3000, 67)).astype(np.float32)
     y = (X[:, 0] > 0).astype(np.float32)
@@ -246,6 +318,7 @@ def test_booster_sets_the_tiling_gauges(monkeypatch):
     assert gauges["gbdt.hist_backend"] == "pallas"
     want = {"hist.feature_pad_pct": 100.0 * (72 - 67) / 67,
             "hist.wave_slots": "8,8,8,8,8,16,32,64|128",
+            "hist.fused_waves": "1,2,3,4,5,6,7",
             "hist.row_chunks": 1}
     for A in (8, 16, 32, 64, 128):
         C, _, cols = col_layout(A, "int8h")
@@ -255,3 +328,34 @@ def test_booster_sets_the_tiling_gauges(monkeypatch):
     assert want["hist.tiling.128"] == "67x2048"
     assert want["hist.tiling.256"] == "67x1024"
     assert want["hist.tiling.512"] == "24x2048"
+
+
+@pytest.mark.parametrize("learner,want", [("serial", "1,2,3,4,5,6,7"),
+                                          ("data", "-")])
+def test_fused_waves_gauge_by_learner(monkeypatch, learner, want):
+    """`hist.fused_waves` at the Criteo width: the serial learner fuses
+    waves 1-7; the data-parallel one exchanges each wave's histogram
+    before its scan, so none of its waves is fused (`-dp4`)."""
+    import numpy as np
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu import obs
+    from lightgbm_tpu.learner import serial
+    monkeypatch.setenv("LGBM_TPU_HIST_BACKEND", "pallas")
+    monkeypatch.setattr(serial, "_COMPILE_LEAN_ROWS", 0)
+    rng = np.random.RandomState(1)
+    X = rng.normal(size=(4096, 67)).astype(np.float32)
+    y = (X[:, 0] > 0).astype(np.float32)
+    was_on = obs.enabled()
+    obs.reset()
+    obs.enable()
+    try:
+        lgb.Booster({"objective": "binary", "num_leaves": 255,
+                     "max_bin": 63, "hist_mode": "int8h", "verbose": -1,
+                     "tree_learner": learner},
+                    lgb.Dataset(X, label=y, params={"max_bin": 63}))
+        gauges = obs.summary()["gauges"]
+    finally:
+        if not was_on:
+            obs.disable()
+        obs.reset()
+    assert gauges["hist.fused_waves"] == want

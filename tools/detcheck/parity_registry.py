@@ -52,11 +52,6 @@ PROGRAM_PAIRS: Tuple[Dict, ...] = (
      "programs": ("fused scan-block training loop",
                   "legacy eager per-iteration loop"),
      "test": "tests/test_block_valid.py"},
-    {"name": "fused-block-vs-per-iteration-serial",
-     "env": "LGBM_TPU_NO_FUSED",
-     "programs": ("fused 32-iteration serial block",
-                  "per-iteration serial dispatches"),
-     "test": "tests/test_block_valid.py"},
     {"name": "split-cache-vs-full-rescan",
      "env": "LGBM_TPU_SPLIT_CACHE",
      "programs": ("incremental per-leaf split cache (O(new children))",
